@@ -16,11 +16,11 @@ import os
 import runpy
 import sys
 
-# Cluster workers spawned from a CPU-forced test context must stay on CPU
-# instead of dialing the one shared (possibly busy) real chip.
-from flink_tpu.utils.platform import honor_jax_platforms
+# every subcommand that compiles (run, sql, repl, worker) shares one
+# persistent compile cache; placed here, before any backend use
+from flink_tpu.utils.platform import configure_compile_cache
 
-honor_jax_platforms()
+configure_compile_cache()
 
 
 def _cmd_run(args) -> int:

@@ -604,8 +604,6 @@ def _make_jit_step(tab: TransitionTable, m_cap: int, e_cap: int):
     import jax
     import jax.numpy as jnp
 
-    from jax.experimental import enable_x64
-
     key = (_table_key(tab), m_cap, e_cap)
     with _jit_lock:
         fn = _jit_cache.get(key)
@@ -628,7 +626,7 @@ def _make_jit_step(tab: TransitionTable, m_cap: int, e_cap: int):
         flags = jnp.stack([overflow_e, overflow_m, jnp.any(dup)])
         return new_block, mm, cand["ev"], cand["eln"], flags
 
-    with enable_x64():
+    with jax.enable_x64():
         jitted = jax.jit(step)
     with _jit_lock:
         while len(_jit_cache) >= _JIT_CACHE_MAX:   # bounded: FIFO evict
@@ -641,11 +639,11 @@ def step_jit(tab: TransitionTable, m_cap: int, block, inputs
              ) -> Tuple[StepResult, int]:
     """One event step via the jitted kernel; falls back to
     :func:`step_numpy` when the dispatch flags dup/overflow."""
-    from jax.experimental import enable_x64
+    import jax
 
     e_cap = block[4].shape[2]
     fn = _make_jit_step(tab, m_cap, e_cap)
-    with enable_x64():
+    with jax.enable_x64():
         new_block, mm, c_ev, c_eln, flags = fn(*block, *inputs)
         flags = np.asarray(flags)
         if flags.any():

@@ -38,6 +38,7 @@ worker count — the assignment is re-computed, state is per-subtask).
 
 from __future__ import annotations
 
+import glob
 import importlib
 import json
 import os
@@ -994,6 +995,15 @@ class _Pending:
         self.enumerators = enumerators
 
 
+def local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from the device nodes the
+    TPU runtime opens (``/dev/vfio/<n>`` on v5e, ``/dev/accel<n>`` on older
+    generations) — without initialising JAX: the coordinator must never
+    take the chip its worker needs."""
+    return (len(glob.glob("/dev/vfio/[0-9]*"))
+            + len(glob.glob("/dev/accel[0-9]*")))
+
+
 class ProcessCluster:
     """Coordinator: spawns workers, drives deploy/checkpoint/shutdown, and
     assembles results (the Dispatcher + JobMaster + CheckpointCoordinator
@@ -1305,6 +1315,7 @@ class ProcessCluster:
         result in memory/checkpoints by design."""
         from flink_tpu.observability import tracing as tracing_mod
 
+        self._check_one_process_per_chip()
         restore = self._ha_takeover(restore)
         original_restore = restore
         if self.tracing:
@@ -1323,6 +1334,25 @@ class ProcessCluster:
             self._ha_shutdown()
             # self._trace_journal/last_trace keep serving afterwards
             tracing_mod.release_after_execution(j, owned)
+
+    def _check_one_process_per_chip(self) -> None:
+        """A JAX process claims every chip of its host and a chip belongs
+        to one process at a time, so a second spawned worker is refused at
+        its first backend use ("The TPU is already in use by process
+        ...").  Fail the deploy instead; workers are not pinned to chips
+        of their own."""
+        if not self.spawn or self.n_workers <= 1:
+            return
+        if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
+            return
+        chips = local_tpu_chips()
+        if chips:
+            raise RuntimeError(
+                f"cannot deploy {self.n_workers} worker processes on a host "
+                f"with {chips} TPU chip(s): one process holds all of a "
+                f"host's chips.  Use --workers 1 (with env.set_mesh() to "
+                f"spread keyed state over the chips), or set "
+                f"JAX_PLATFORMS=cpu for host-only workers.")
 
     # -- coordinator HA -----------------------------------------------------
     @classmethod

@@ -15,11 +15,12 @@ Checkpoint layout: ``{uid: {"subtasks": [per-subtask snapshot, ...]}}`` plus
 ``__job__`` metadata.  On restore with the same parallelism each subtask gets
 its own snapshot back; sources replay from their recorded offsets.
 
-NOTE on devices: subtasks are threads, and concurrent jit dispatch from many
-threads onto ONE physical TPU chip can crash the device client — run the
-MiniCluster on the CPU platform (tests do: ``jax_platforms=cpu``) or give
-each subtask its own device; single-chip TPU work belongs on the
-single-threaded LocalExecutor / the sharded ``parallel`` path.
+NOTE on devices: subtasks are threads of ONE process, so on a chip host they
+share the chip (a chip belongs to one process at a time).  Concurrent jit
+dispatch from several task threads onto one TPU chip works: ``chip_smoke.py``
+runs this cluster at parallelism 2 on a v5e chip, both window subtasks
+dispatching every batch and downloading their state at each checkpoint.
+Tests run it on the CPU platform (``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations

@@ -139,7 +139,6 @@ class Graph:
                         update_fn: Callable):
         """Edge-sharded superstep: pad edges to D-divisible, shard_map the
         local segment-combine, merge partials with the matching collective."""
-        from flink_tpu.parallel.mesh import shard_map_compat
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         D = mesh.devices.size
@@ -181,8 +180,8 @@ class Graph:
         in_specs = (P(), espec, espec, espec) + ((espec,) if w is not None
                                                  else ())
 
-        @partial(shard_map_compat, mesh=mesh, in_specs=in_specs,
-                 out_specs=P())
+        @partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                 out_specs=P(), check_vma=False)
         def local_combine(values, src_l, dst_l, valid_l, *w_l):
             msgs = message_fn(values[src_l], w_l[0] if w_l else None)
             # broadcast the edge mask over any trailing value dims (vector

@@ -5,6 +5,9 @@ cached by source hash) and exposes typed wrappers.  If no compiler is
 available the pure-Python fallbacks in :mod:`flink_tpu.native.fallback` are
 used transparently — same API, slower, and compression falls back to zlib
 (method byte 2 in the block format, see :mod:`flink_tpu.native.codec`).
+That fallback serves CLI subcommands off the hot path; ``chip_smoke.py``
+and ``bench.py`` check :func:`native_available` and fail when the build
+did (``build_error``), so the numpy mirror never stands in unnoticed.
 
 This is the TPU-native equivalent of the reference's native-performance
 components (SURVEY §2.6): Cython fast coders, JNI LZ4 buffer compression,

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_tpu.observability import tracing
-from flink_tpu.parallel.mesh import KG_AXIS, shard_map_compat
+from flink_tpu.parallel.mesh import KG_AXIS
 
 
 def bucket_plan(dest: jnp.ndarray, num_shards: int, cap: int):
@@ -116,7 +116,8 @@ def make_all_to_all_exchange(mesh: Mesh, num_leaves: int, cap: int):
 
     in_specs = (P(KG_AXIS),) + (P(KG_AXIS),) * num_leaves
     out_specs = ((P(KG_AXIS),) * num_leaves, P(KG_AXIS), P(KG_AXIS))
-    fn = shard_map_compat(_exchange, mesh, in_specs, out_specs)
+    fn = jax.shard_map(_exchange, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

@@ -23,29 +23,18 @@ from flink_tpu.core import keygroups
 KG_AXIS = "kg"
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: newer jax spells the replication
-    check ``check_vma``, 0.4.x spells it ``check_rep`` (and hosts shard_map
-    under ``jax.experimental``).  One shim so every exchange/runtime call
-    site stays version-agnostic."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def make_mesh(n_devices: Optional[int] = None,
               devices: Optional[Sequence] = None) -> Mesh:
     """1-D mesh over the key-group axis (data parallelism over keyed state)."""
     if devices is None:
         devices = jax.devices()
         if n_devices is not None:
+            if n_devices > len(devices):
+                # a silently smaller mesh would finish green with every
+                # shard on the first chip
+                raise ValueError(
+                    f"make_mesh(n_devices={n_devices}): only {len(devices)} "
+                    f"{devices[0].platform} device(s) are visible")
             devices = devices[:n_devices]
     return Mesh(np.asarray(devices), (KG_AXIS,))
 

@@ -226,8 +226,8 @@ class MeshWindowAggOperator(WindowAggOperator):
                     P(KG_AXIS), P(KG_AXIS), P(KG_AXIS)) \
             + (P(KG_AXIS),) * nv
         out_specs = ((state_spec,) * len(leaves), state_spec)
-        from flink_tpu.parallel.mesh import shard_map_compat
-        fn = shard_map_compat(step, self.mesh, in_specs, out_specs)
+        fn = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return fn(leaves, counts, *batch)
 
     def _values_tree(self, flat_values):
@@ -283,8 +283,8 @@ class MeshWindowAggOperator(WindowAggOperator):
                     P(KG_AXIS), P(KG_AXIS), P(KG_AXIS)) \
             + (P(KG_AXIS),) * nv
         out_specs = ((state_spec,) * len(dleaves), state_spec)
-        from flink_tpu.parallel.mesh import shard_map_compat
-        fn = shard_map_compat(step, self.mesh, in_specs, out_specs)
+        fn = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return fn(dleaves, dcounts, *batch)
 
     @partial(jax.jit, static_argnums=(0,))
@@ -293,9 +293,8 @@ class MeshWindowAggOperator(WindowAggOperator):
         routing (bucket plan, sticky capacity) is host-computed from the
         resolved slots, so the probe runs once up front and the slots ride
         back with the scalar miss count."""
-        from flink_tpu.state.device_keyindex import probe_impl
-        _name, probe = probe_impl(int(tab[0].shape[0]))
-        slot = probe(*tab, key_lo, key_hi, start)
+        from flink_tpu.state.device_keyindex import lax_probe
+        slot = lax_probe(*tab, key_lo, key_hi, start)
         valid = jnp.arange(slot.shape[0], dtype=jnp.int32) < b
         miss = valid & (slot < 0)
         return slot, jnp.sum(miss, dtype=jnp.int32)
@@ -575,8 +574,8 @@ class MeshSessionWindowOperator(SessionWindowOperator):
         nv = len(batch) - 2
         in_specs = (P(KG_AXIS), P(KG_AXIS)) + (P(KG_AXIS),) * nv
         out_specs = (P(KG_AXIS),) * self.spec.num_leaves
-        from flink_tpu.parallel.mesh import shard_map_compat
-        fn = shard_map_compat(step, self.mesh, in_specs, out_specs)
+        fn = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return fn(*batch)
 
     def _values_tree(self, flat_values):

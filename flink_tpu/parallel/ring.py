@@ -18,7 +18,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-from flink_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_tpu.parallel.mesh import KG_AXIS
@@ -57,7 +56,8 @@ def make_ring_combine(mesh: Mesh, combine_leaves: Callable,
         return _ring_fold(leaves, combine_leaves, axis, D)
 
     specs = tuple(P(axis) for _ in range(num_leaves))
-    fn = shard_map_compat(ring, mesh, specs, specs)
+    fn = jax.shard_map(ring, mesh=mesh, in_specs=specs, out_specs=specs,
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -68,7 +68,8 @@ def make_ring_all_reduce_sum(mesh: Mesh, axis: str = KG_AXIS):
     def allreduce(x):
         return jax.lax.psum(x, axis)
 
-    fn = shard_map_compat(allreduce, mesh, P(axis), P(axis))
+    fn = jax.shard_map(allreduce, mesh=mesh, in_specs=P(axis),
+                       out_specs=P(axis), check_vma=False)
     return jax.jit(fn)
 
 
@@ -93,4 +94,5 @@ def sharded_pane_window_total(mesh: Mesh, combine_leaves: Callable,
         return _ring_fold(local, combine_leaves, axis, D)
 
     specs = tuple(P(axis) for _ in range(num_leaves))
-    return jax.jit(shard_map_compat(body, mesh, specs, specs))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=specs,
+                                 out_specs=specs, check_vma=False))

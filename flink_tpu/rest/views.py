@@ -108,7 +108,8 @@ def flamegraph_svg(tree: Dict[str, Any], width: int = 1000,
             return
         depth_max = max(depth_max, depth)
         w = x1 - x0
-        name = _esc(node.get("name", ""))
+        raw = str(node.get("name", ""))
+        name = _esc(raw)
         pct = 100.0 * node.get("value", 0) / total
         hue = 20 + (hash(name) % 20)
         rects.append(
@@ -118,7 +119,9 @@ def flamegraph_svg(tree: Dict[str, Any], width: int = 1000,
             f'><title>{name} — {node.get("value", 0)} samples '
             f'({pct:.1f}%)</title></rect>')
         if w > 40:
-            shown = name if len(name) * 6 < w else name[: int(w / 6)] + "…"
+            # truncate BEFORE escaping: a cut through "&lt;" is not XML
+            shown = (name if len(raw) * 6 < w
+                     else _esc(raw[: int(w / 6)]) + "…")
             # style (not attribute): survives the dashboard's
             # `#flame text{fill:#fff}` ID-selector rule
             rects.append(

@@ -19,11 +19,8 @@ This module holds the device half of that split:
   vectorized pass (no random access, no insert — the wall is the probe
   walk + fold, not the hash), so slot ids agree with the host KeyIndex by
   construction.
-- :func:`lax_probe` — the pure-lax vectorized probe loop (the portable,
-  bit-identical fallback; tier-1 runs it under ``JAX_PLATFORMS=cpu``).
-- :func:`pallas_probe` — an optional Pallas TPU kernel behind
-  :func:`pallas_probe_available` (TPU backend + importable pallas + table
-  fits VMEM); same arithmetic, same results.
+- :func:`lax_probe` — the vectorized probe loop, the only probe: a
+  ``while_loop`` of XLA gathers from the HBM-resident table.
 - :class:`DeviceKeyIndex` — the host-side owner: a numpy occupancy shadow
   decides insert buckets (the device table is only ever written by our
   scatters, so shadow and table cannot diverge), ``ensure_loaded`` bulk-
@@ -41,7 +38,6 @@ from __future__ import annotations
 
 import os
 import threading
-from functools import partial
 from typing import Optional, Tuple
 
 import numpy as np
@@ -108,199 +104,6 @@ def lax_probe(tab_lo, tab_hi, tab_slot1, key_lo, key_hi, start):
     slot0 = jnp.full(start.shape, MISS, jnp.int32)
     _p, _i, slot = lax.while_loop(cond, body, (pending0, start, slot0))
     return slot
-
-
-#: Pallas opt-out (set FLINK_TPU_DEVICE_PROBE_PALLAS=0 to pin the lax path
-#: on TPU); the capability check below gates it on by default when legal
-_PALLAS_ENV = "FLINK_TPU_DEVICE_PROBE_PALLAS"
-
-#: VMEM budget for the whole table (3 int32 planes) — beyond this the
-#: blocks would not fit next to the batch tiles and the lax path (XLA
-#: gather from HBM) is the right tool anyway
-_PALLAS_VMEM_TABLE_BYTES = 8 << 20
-
-
-def pallas_probe_available(capacity: int) -> bool:
-    """True iff the Pallas TPU probe kernel is usable here: TPU backend,
-    importable pallas, table planes fit the VMEM budget, not opted out."""
-    if os.environ.get(_PALLAS_ENV, "1") in ("0", "off", "false"):
-        return False
-    try:
-        import jax
-        if jax.default_backend() != "tpu":
-            return False
-        from jax.experimental import pallas as pl          # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu   # noqa: F401
-    except Exception:  # noqa: BLE001 — any import/backend issue: fall back
-        return False
-    return capacity * 12 <= _PALLAS_VMEM_TABLE_BYTES
-
-
-def pallas_probe(tab_lo, tab_hi, tab_slot1, key_lo, key_hi, start):
-    """Pallas TPU probe kernel: table planes pinned whole in VMEM, batch
-    processed as one block, the same probe arithmetic as :func:`lax_probe`
-    (bit-identical results).  Only called when
-    :func:`pallas_probe_available` said yes."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    cap = int(tab_slot1.shape[0])
-
-    def kernel(lo_ref, hi_ref, s1_ref, klo_ref, khi_ref, st_ref, out_ref):
-        t_lo = lo_ref[:]
-        t_hi = hi_ref[:]
-        t_s1 = s1_ref[:]
-        klo = klo_ref[:]
-        khi = khi_ref[:]
-        idx = st_ref[:]
-        maskv = jnp.int32(cap - 1)
-
-        def cond(state):
-            pending, _i, _s = state
-            return jnp.any(pending)
-
-        def body(state):
-            pending, i, s = state
-            b_s = t_s1[i]
-            empty = b_s == 0
-            hit = (~empty) & (t_lo[i] == klo) & (t_hi[i] == khi)
-            s = jnp.where(pending & hit, b_s - 1, s)
-            pending = pending & ~(hit | empty)
-            i = jnp.where(pending, (i + 1) & maskv, i)
-            return pending, i, s
-
-        pending0 = jnp.ones(idx.shape, bool)
-        slot0 = jnp.full(idx.shape, MISS, jnp.int32)
-        _p, _i, slot = jax.lax.while_loop(cond, body,
-                                          (pending0, idx, slot0))
-        out_ref[:] = slot
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(start.shape, jnp.int32),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 6,
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-    )(tab_lo, tab_hi, tab_slot1, key_lo, key_hi, start)
-
-
-def probe_impl(capacity: int):
-    """(name, fn) for this table capacity: the Pallas kernel when capable,
-    else the pure-lax fallback — chosen at trace time, so the consuming
-    jitted step bakes the right path in."""
-    if pallas_probe_available(capacity):
-        return "pallas", pallas_probe
-    return "lax", lax_probe
-
-
-# ---------------------------------------------------------------------------
-# fused probe + scatter fold (the Pallas path beyond the probe, ISSUE-11)
-# ---------------------------------------------------------------------------
-
-#: Pallas fused probe+fold opt-out (FLINK_TPU_FUSED_PALLAS=0 pins the
-#: probe-then-XLA-scatter path on TPU); the capability check gates it on
-_FUSED_PALLAS_ENV = "FLINK_TPU_FUSED_PALLAS"
-
-#: VMEM budget for table planes PLUS the flat delta planes: the fused
-#: kernel pins both whole, so it serves small-state jobs (the probe-only
-#: kernel plus an XLA scatter is the right tool past this)
-_PALLAS_VMEM_FUSED_BYTES = 12 << 20
-
-
-def pallas_probe_fold_available(capacity: int, flat_state: int,
-                                kinds) -> bool:
-    """True iff the fused Pallas probe+scatter-fold kernel is usable: TPU
-    backend + importable pallas (the probe's own gate), a single scalar
-    ``add`` accumulator leaf (the dominant sum-over-floats shape — the C
-    pass fast-paths exactly the same case), and table + flat f64/i32 delta
-    planes together inside the VMEM budget.  Same check/override pattern
-    as ``pallas_probe``."""
-    if os.environ.get(_FUSED_PALLAS_ENV, "1") in ("0", "off", "false"):
-        return False
-    if kinds is None or tuple(kinds) != ("add",):
-        return False
-    if not pallas_probe_available(capacity):
-        return False
-    return capacity * 12 + flat_state * 12 <= _PALLAS_VMEM_FUSED_BYTES
-
-
-def pallas_probe_fold(tab_lo, tab_hi, tab_slot1, key_lo, key_hi, start,
-                      pane_slots, b, vals, dsum, dcnt, pane_mod: int):
-    """Fused Pallas TPU kernel: probe + delta scatter-fold in ONE kernel —
-    the round trip through HBM between the probe's slot output and the
-    fold's gather/scatter input disappears.  ``dsum``/``dcnt`` are the
-    FLAT ``[K*P]`` delta planes, aliased in-place; ``b`` is the valid-row
-    count as an int32[1] plane (rows past it, and probe misses, fold
-    nothing).  Returns (slot, new_dsum, new_dcnt) with arithmetic
-    identical to ``probe`` + ``ops.scatter.scatter_fold_counts`` — the
-    lax path the tier-1 digests pin."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    cap = int(tab_slot1.shape[0])
-    Bp = int(start.shape[0])
-
-    def kernel(lo_ref, hi_ref, s1_ref, klo_ref, khi_ref, st_ref, ps_ref,
-               b_ref, v_ref, sum_ref, cnt_ref, slot_ref, osum_ref,
-               ocnt_ref):
-        t_lo = lo_ref[:]
-        t_hi = hi_ref[:]
-        t_s1 = s1_ref[:]
-        klo = klo_ref[:]
-        khi = khi_ref[:]
-        idx = st_ref[:]
-        maskv = jnp.int32(cap - 1)
-
-        def cond(state):
-            pending, _i, _s = state
-            return jnp.any(pending)
-
-        def pbody(state):
-            pending, i, s = state
-            b_s = t_s1[i]
-            empty = b_s == 0
-            hit = (~empty) & (t_lo[i] == klo) & (t_hi[i] == khi)
-            s = jnp.where(pending & hit, b_s - 1, s)
-            pending = pending & ~(hit | empty)
-            i = jnp.where(pending, (i + 1) & maskv, i)
-            return pending, i, s
-
-        pending0 = jnp.ones(idx.shape, bool)
-        slot0 = jnp.full(idx.shape, MISS, jnp.int32)
-        _p, _i, slot = jax.lax.while_loop(cond, pbody,
-                                          (pending0, idx, slot0))
-        slot_ref[:] = slot
-        osum_ref[:] = sum_ref[:]
-        ocnt_ref[:] = cnt_ref[:]
-        bb = b_ref[0]
-        ps = ps_ref[:]
-        vv = v_ref[:]
-        flat = slot * jnp.int32(pane_mod) + ps
-
-        def fbody(k, carry):
-            @pl.when((k < bb) & (slot[k] >= 0))
-            def _fold():
-                f = flat[k]
-                osum_ref[f] = osum_ref[f] + vv[k].astype(osum_ref.dtype)
-                ocnt_ref[f] = ocnt_ref[f] + 1
-
-            return carry
-
-        jax.lax.fori_loop(0, Bp, fbody, 0)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                   jax.ShapeDtypeStruct(dsum.shape, dsum.dtype),
-                   jax.ShapeDtypeStruct(dcnt.shape, dcnt.dtype)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 11,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 3,
-        input_output_aliases={9: 1, 10: 2},
-    )(tab_lo, tab_hi, tab_slot1, key_lo, key_hi, start, pane_slots, b,
-      vals, dsum, dcnt)
 
 
 # ---------------------------------------------------------------------------
@@ -549,11 +352,10 @@ def _measure_device_probe() -> bool:
     ki.lookup_or_insert(np.arange(n_keys, dtype=np.int64))
     dki = DeviceKeyIndex(initial_capacity=2 * n_keys)
     dki.ensure_loaded(ki)
-    _name, probe = probe_impl(dki.capacity)
 
     @jax.jit
     def step(tab_lo, tab_hi, tab_s1, dsum, dcnt, klo, khi, start, vals):
-        slot = probe(tab_lo, tab_hi, tab_s1, klo, khi, start)
+        slot = lax_probe(tab_lo, tab_hi, tab_s1, klo, khi, start)
         hit = slot >= 0
         ids = jnp.where(hit, slot, jnp.int32(np.iinfo(np.int32).max))
         new_sum = dsum.at[ids].add(vals.astype(dsum.dtype), mode="drop")
@@ -563,8 +365,7 @@ def _measure_device_probe() -> bool:
 
     # the real delta fold accumulates in f64 (the mirror's precision) —
     # measure the same thing; enable_x64 scopes the wide dtype per-trace
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         dsum = jnp.zeros(n_keys, jnp.float64)
         dcnt = jnp.zeros(n_keys, jnp.int32)
         dev_best = float("inf")

@@ -142,6 +142,18 @@ def test_checkpoint_drilldown_table(job):
     assert any(c.isdigit() for c in body.split("</td><td>")[3])
 
 
+def test_flamegraph_svg_truncated_labels_stay_well_formed():
+    """A label cut to its frame's width must be cut before it is escaped:
+    a cut through ``&lt;`` is not XML."""
+    from flink_tpu.rest.views import flamegraph_svg
+
+    tree = {"name": "root", "value": 10, "children": [
+        {"name": "<lambda> (a&b.py:1) " + "x" * 60, "value": 1,
+         "children": []}]}
+    for width in range(100, 1200, 7):
+        ET.fromstring(flamegraph_svg(tree, width=width))
+
+
 def test_flamegraph_svg_renders_samples(job):
     base, _plan = job
     body, ctype = _get_text(base + "/flamegraph.svg")

@@ -23,8 +23,7 @@ from flink_tpu.core.batch import RecordBatch, Watermark
 from flink_tpu.core.functions import RuntimeContext, SumAggregator
 from flink_tpu.operators.window_agg import WindowAggOperator
 from flink_tpu.state.keyindex import KeyIndex
-from flink_tpu.state.device_keyindex import (DeviceKeyIndex, lax_probe,
-                                             probe_impl)
+from flink_tpu.state.device_keyindex import DeviceKeyIndex, lax_probe
 from flink_tpu.windowing.assigners import TumblingEventTimeWindows
 
 
@@ -134,13 +133,6 @@ def test_incremental_insert_and_sticky_growth(rng):
     assert all(c & (c - 1) == 0 for c in cap_seen)
     assert cap_seen == sorted(cap_seen)
     assert ki.num_keys <= dki.capacity // 2  # load factor <= 0.5 held
-
-
-def test_probe_impl_is_lax_on_cpu():
-    """Tier-1 runs under JAX_PLATFORMS=cpu: the Pallas kernel must stay
-    behind its capability check and the pure-lax fallback must serve."""
-    name, fn = probe_impl(1 << 16)
-    assert name == "lax" and fn is lax_probe
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Transport-adaptive device sync (``WindowAggOperator(device_sync=...)``).
 
-On taxed transports (tunneled devices where executing a dispatched update
-step costs the host tens of CPU-ms per uploaded MB) the host emit tier
+On taxed transports (links where executing a dispatched update step
+costs the host tens of CPU-ms per uploaded MB) the host emit tier
 defers per-batch device syncs and refreshes the replica at sync points
 instead (``utils/transport.py``).  These tests pin the contract:
 

@@ -444,17 +444,6 @@ def test_count_trigger_pins_unfused():
     op.close()
 
 
-def test_pallas_fold_gate_off_on_cpu():
-    from flink_tpu.state.device_keyindex import pallas_probe_fold_available
-
-    assert not pallas_probe_fold_available(1 << 12, 1 << 14, ("add",)), \
-        "fused Pallas kernel must be gated off on the CPU backend"
-    # non-single-add shapes are ineligible everywhere
-    assert not pallas_probe_fold_available(1 << 12, 1 << 14,
-                                           ("add", "min"))
-    assert not pallas_probe_fold_available(1 << 12, 1 << 14, None)
-
-
 def test_fused_scan_phase_and_span_names():
     """The --profile/tracing contract under fusion: scan-lane time lands
     in a 'fused_scan' phase whose hot_stage spans ride the journal with
