@@ -66,7 +66,7 @@ def _cmd_run(args) -> int:
     if callable(main):
         main(env)
     if getattr(env, "_last_executor", None) is not None or \
-            getattr(env, "_last_cluster", None) is not None:
+            env.last_cluster is not None:
         # the script executed itself: don't run the job a second time
         print("job executed by script")
         return 0

@@ -27,6 +27,7 @@ import numpy as np
 from flink_tpu.core import keygroups
 from flink_tpu.core.batch import (CheckpointBarrier, EndOfInput, RecordBatch,
                                   StreamElement)
+from flink_tpu.observability import tracing
 from flink_tpu.testing import chaos
 
 
@@ -113,11 +114,17 @@ class LocalChannel:
                 time.sleep(0.01)
         with self._not_full:
             if len(self._q) >= self.capacity and not self._closed:
+                # a span only when the put waits: the time blocked here
+                # is the producer's backpressure proper
                 t0 = time.monotonic_ns()
-                while len(self._q) >= self.capacity and not self._closed:
-                    if not self._not_full.wait(timeout=timeout_s):
-                        self.backpressured_ns += time.monotonic_ns() - t0
-                        return False
+                with tracing.span("exchange.put_wait", cat="exchange",
+                                  channel=self.name):
+                    while len(self._q) >= self.capacity \
+                            and not self._closed:
+                        if not self._not_full.wait(timeout=timeout_s):
+                            self.backpressured_ns += \
+                                time.monotonic_ns() - t0
+                            return False
                 self.backpressured_ns += time.monotonic_ns() - t0
             if self._closed:
                 return False
@@ -236,21 +243,30 @@ class OutputDispatcher:
                 f"forward edge cannot fan out to {n} channels")
 
     def _emit_hash(self, batch: RecordBatch) -> None:
-        kg = batch.key_groups
-        if kg is None and self.key_column is not None:
-            # the keying operator lives at the consumer chain head; the
-            # producer-side partitioner derives key groups from the key
-            # column itself (KeyGroupStreamPartitioner's key selector)
-            keys = np.asarray(batch.column(self.key_column))
-            kg = keygroups.assign_to_key_group(keygroups.hash_keys(keys),
-                                               self.max_parallelism)
-        if kg is None:
-            raise ValueError("hash edge requires key_groups on the batch "
-                             "(key_by upstream)")
-        n = len(self.channels)
-        # KeyGroupRangeAssignment.computeOperatorIndexForKeyGroup
-        target = (np.asarray(kg, np.int64) * n) // self.max_parallelism
+        # the producer's own work (the key-group hash here, one `select`
+        # per target below) in spans of its own, apart from the puts, which
+        # may block on credit.  Each target's part is put as soon as it is
+        # cut: a consumer must not wait for the parts of the others.
+        with tracing.span("exchange.partition", cat="exchange",
+                          records=len(batch)):
+            kg = batch.key_groups
+            if kg is None and self.key_column is not None:
+                # the keying operator lives at the consumer chain head; the
+                # producer-side partitioner derives key groups from the key
+                # column itself (KeyGroupStreamPartitioner's key selector)
+                keys = np.asarray(batch.column(self.key_column))
+                kg = keygroups.assign_to_key_group(
+                    keygroups.hash_keys(keys), self.max_parallelism)
+            if kg is None:
+                raise ValueError("hash edge requires key_groups on the "
+                                 "batch (key_by upstream)")
+            n = len(self.channels)
+            # KeyGroupRangeAssignment.computeOperatorIndexForKeyGroup
+            target = (np.asarray(kg, np.int64) * n) // self.max_parallelism
         for t in range(n):
-            sel = target == t
-            if sel.any():
-                self.channels[t].put(batch.select(sel))
+            with tracing.span("exchange.partition", cat="exchange",
+                              target=t):
+                sel = target == t
+                part = batch.select(sel) if sel.any() else None
+            if part is not None:
+                self.channels[t].put(part)

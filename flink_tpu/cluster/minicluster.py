@@ -332,7 +332,11 @@ class MiniCluster(TaskListener):
                             subtask=subtask_index)
             p.acks[(vertex_uid, subtask_index)] = snapshot
             if len(p.acks) >= p.expected:
-                self._complete_checkpoint(p)
+                # on the LAST acker's task thread: until this returns,
+                # that task processes nothing
+                with tracing.span("checkpoint.complete", cat="checkpoint",
+                                  checkpoint=checkpoint_id):
+                    self._complete_checkpoint(p)
 
     def decline_checkpoint(self, checkpoint_id: int, vertex_uid: str,
                            subtask_index: int, error: str) -> None:
@@ -442,7 +446,10 @@ class MiniCluster(TaskListener):
             self._lock.release()
             try:
                 try:
-                    self.checkpoint_storage.store(p.checkpoint_id, store_tree)
+                    with tracing.span("checkpoint.store", cat="checkpoint",
+                                      checkpoint=p.checkpoint_id):
+                        self.checkpoint_storage.store(p.checkpoint_id,
+                                                      store_tree)
                 except Exception as e:  # noqa: BLE001
                     store_error = f"{type(e).__name__}: {e}"
                 else:
@@ -1064,6 +1071,13 @@ class MiniCluster(TaskListener):
                               "parallelism": v.parallelism}
                              for v in plan.vertices],
                 "edges": edges}
+
+    def tasks(self) -> List[SubtaskBase]:
+        """The subtasks of the current deployment (a copy of the list;
+        empty before deploy, the last deployment's after the job ended).
+        Their counters (``busy_ns``, ``records_in``, ...) and ``operator``
+        are monitoring-grade reads from any thread."""
+        return list(self._tasks)
 
     def job_status(self) -> Dict[str, Any]:
         """REST-facing job view (jobs/<id> handler backing)."""

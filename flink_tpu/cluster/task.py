@@ -369,7 +369,8 @@ class SourceSubtask(SubtaskBase):
                 time.sleep(0.002)  # paused: commands/cancel only
                 continue
             try:
-                el = next(it)
+                with tracing.span("source.next", cat="source"):
+                    el = next(it)
             except StopIteration:
                 break
             self._emitted += 1
@@ -389,7 +390,9 @@ class SourceSubtask(SubtaskBase):
                                               subtask_index=self.subtask_index,
                                               source=self.vertex_uid)])
                 t0 = time.monotonic_ns()
-                out = self.operator.process_batch(el)
+                with tracing.span("task.process_batch", cat="task",
+                                  records=len(el)):
+                    out = self.operator.process_batch(el)
                 self.busy_ns += time.monotonic_ns() - t0
                 self._emit(out)
             elif isinstance(el, Watermark):
@@ -564,6 +567,9 @@ class Subtask(SubtaskBase):
                                           for _ in range(len(self.inputs))]
         self._align_queued = 0                 # elements across channels
         self._align_timer: Optional[MonotoneElapsed] = None
+        #: the open `checkpoint.align` span: first barrier to last barrier
+        #: of an alignment that has to wait for other channels
+        self._align_span = None
         #: announcement timer: a barrier QUEUED behind a backlog starts the
         #: clock before the consumer ever drains to it (Flink's priority
         #: barrier announcement); inherited by the alignment timer
@@ -654,7 +660,8 @@ class Subtask(SubtaskBase):
                 t0 = time.monotonic_ns()
                 for i, ch in enumerate(self.inputs):
                     if not self._ended[i] and not self._is_blocked(i):
-                        el = ch.poll(timeout_s=0.01)
+                        with tracing.span("task.input_wait", cat="task"):
+                            el = ch.poll(timeout_s=0.01)
                         if el is not None:
                             self.idle_ns += time.monotonic_ns() - t0
                             self._handle(i, el)
@@ -743,6 +750,11 @@ class Subtask(SubtaskBase):
                     and (self.alignment_timeout_ms == 0
                          or self._force_escalate):
                 self._escalate()   # pure unaligned / announced overtake
+            elif first and not self._alignment_complete():
+                self._align_span = tracing.span(
+                    "checkpoint.align", cat="checkpoint", checkpoint=cid,
+                    task=self.vertex_uid, subtask=self.subtask_index)
+                self._align_span.__enter__()
             self._maybe_complete_alignment()
         elif isinstance(el, EndOfInput):
             self._ended[i] = True
@@ -912,6 +924,7 @@ class Subtask(SubtaskBase):
         barrier = self._pending_barrier
         if barrier is None or self._overtaken:
             return
+        self._end_align_span()      # the aligned wait ends at the overtake
         cid = barrier.checkpoint_id
         from flink_tpu.operators.base import snapshot_scope
         try:
@@ -993,6 +1006,7 @@ class Subtask(SubtaskBase):
         barrier = self._pending_barrier
         if barrier is None:
             return
+        self._end_align_span()
         cid = barrier.checkpoint_id
         was_overtaken = self._overtaken
         self._pending_barrier = None
@@ -1051,11 +1065,13 @@ class Subtask(SubtaskBase):
                 self._emit_status_change(self._valve.record_activity(i))
                 self.records_in += len(el)
                 t0 = time.monotonic_ns()
-                if getattr(self.operator, "is_two_input", False):
-                    out = self.operator.process_batch2(
-                        el, self.input_logical[i])
-                else:
-                    out = self.operator.process_batch(el)
+                with tracing.span("task.process_batch", cat="task",
+                                  records=len(el)):
+                    if getattr(self.operator, "is_two_input", False):
+                        out = self.operator.process_batch2(
+                            el, self.input_logical[i])
+                    else:
+                        out = self.operator.process_batch(el)
                 self.busy_ns += time.monotonic_ns() - t0
                 self._emit(out)
         elif isinstance(el, LatencyMarker):
@@ -1076,12 +1092,21 @@ class Subtask(SubtaskBase):
         else:
             self._emit([el])
 
+    def _alignment_complete(self) -> bool:
+        return all(self._ended[j] or j in self._barriered
+                   for j in range(len(self.inputs)))
+
+    def _end_align_span(self) -> None:
+        span, self._align_span = self._align_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
     def _maybe_complete_alignment(self) -> None:
         if self._pending_barrier is None:
             return
-        if not all(self._ended[j] or j in self._barriered
-                   for j in range(len(self.inputs))):
+        if not self._alignment_complete():
             return
+        self._end_align_span()
         barrier = self._pending_barrier
         self._take_checkpoint(barrier)
         self._barriered.clear()

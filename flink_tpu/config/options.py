@@ -203,10 +203,18 @@ class MetricOptions:
         "hop) latency histograms exported by the reporters and the REST "
         "latency panel.")
     TRACING_ENABLED = key("metrics.tracing.enabled").bool_type().default_value(
-        False, "Install the per-process span journal at deploy: hot-stage "
-        "phases, checkpoint lifecycle, device-health/paging/exchange/CEP "
-        "events record structured spans, exported as Chrome trace-event "
-        "JSON (REST /jobs/<id>/trace, Perfetto-viewable).")
+        False, "Install the per-process span journal at deploy; it records "
+        "every span the runtime emits, exported as Chrome trace-event JSON "
+        "(REST /jobs/<id>/trace, Perfetto-viewable): source.next, "
+        "exchange.partition / .put_wait, task.input_wait / .process_batch, "
+        "window_agg.probe / .probe_mirror / .mirror / .stage / .device_step, "
+        "window_agg.fire with .fire_dispatch / .fire_d2h / .fire_assemble, "
+        "checkpoint.trigger / .barrier / .align / .alignment / .snapshot / "
+        ".ack with window_agg.snapshot / .snapshot_d2h / .snapshot_assemble, "
+        "sink.invoke, and the device_health.*, paging.*, mesh.exchange, "
+        "cep.vectorized_drain, rescale.* and queryable.* events.  The same "
+        "spans reach a running jax.profiler session whether or not this key "
+        "is set.")
     TRACING_BUFFER = key("metrics.tracing.buffer-size").int_type().default_value(
         65536, "Span-journal ring capacity; once full new spans are "
         "dropped and counted (bounded memory, loud truncation).")

@@ -176,6 +176,14 @@ class StreamExecutionEnvironment:
         self._last_cluster = cluster
         return cluster.execute(plan, restore=restore, timeout_s=timeout_s)
 
+    @property
+    def last_cluster(self):
+        """The MiniCluster of the newest :meth:`execute_cluster` call, set
+        before the job starts (``None`` before any call): the way to the
+        running job's checkpoint trigger, status and :meth:`MiniCluster.tasks`
+        from another thread."""
+        return getattr(self, "_last_cluster", None)
+
 
 def _identity_operator_factory(name: str):
     from flink_tpu.operators.base import StreamOperator

@@ -1,11 +1,19 @@
-"""Structured span journal: a lock-free per-process tracing ring.
+"""The program's one span layer: :func:`span` / :func:`instant` /
+:func:`complete` are the only way the runtime records a span, and every
+span goes to two sinks.
 
-Analog of the reference's (FLIP-165 era) always-on runtime observability,
-in the spirit of Dapper: instrumentation sites call :func:`span` /
-:func:`instant` and pay **one module-attribute read plus a None check**
-when tracing is off — the journal is a module singleton installed with
-:func:`install` and every emit helper early-outs on ``_JOURNAL is None``,
-so the hot paths can afford unconditional instrumentation.
+- **The profiler's clock.**  Each span is a ``jax.profiler.TraceAnnotation``
+  (TraceMe) of the same name, its keyword arguments the event's stats, so
+  it lands on a ``/host:`` line of the ``.xplane.pb`` on the clock of
+  ``XLA Ops`` whenever a profiler session is running in the process —
+  whoever started it.  TraceMe is inert without a session (well under a
+  microsecond), so the hot paths afford unconditional instrumentation and
+  there is nothing to switch on.
+- **The span journal**, a lock-free per-process ring, when one is
+  installed with :func:`install` (``metrics.tracing.enabled``): the
+  operator's own view, served as Chrome trace JSON by the REST API and
+  merged across worker processes.  With no journal installed the emit
+  helpers allocate no journal entry.
 
 Design points:
 
@@ -26,8 +34,9 @@ Design points:
   load directly (``ph: "X"`` complete spans, ``ph: "i"`` instants,
   metadata events naming processes/threads).
 
-This module imports only the standard library (plus the clock seam), so
-every runtime layer can import it without cycles or import cost.
+Beyond the standard library this module imports the clock seam and
+``jax.profiler`` (which every runtime layer loads anyway), so any layer can
+import it without cycles.
 """
 
 from __future__ import annotations
@@ -36,6 +45,8 @@ import itertools
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from flink_tpu.utils import clock
 
@@ -235,17 +246,19 @@ def release_after_execution(journal: Optional[SpanJournal],
 
 
 class _SpanCtx:
-    """``with span("name", cat=...):`` — records one complete span on
-    exit; a no-op (no clock reads) when tracing is off at entry."""
+    """A span while a journal is installed: one complete journal entry on
+    exit, and the profiler's annotation of the same name around it."""
 
-    __slots__ = ("_name", "_cat", "_args", "_t0", "_j")
+    __slots__ = ("_name", "_cat", "_args", "_t0", "_j", "_traceme")
 
-    def __init__(self, name: str, cat: str, args: Optional[dict]):
+    def __init__(self, name: str, cat: str, args: dict):
         self._name = name
         self._cat = cat
-        self._args = args
+        self._args = args or None
+        self._traceme = TraceAnnotation(name, **args)
 
     def __enter__(self):
+        self._traceme.__enter__()
         self._j = _JOURNAL
         if self._j is not None:
             self._t0 = time.perf_counter_ns()
@@ -257,30 +270,46 @@ class _SpanCtx:
             t1 = time.perf_counter_ns()
             j.record("X", self._t0, t1 - self._t0, self._name, self._cat,
                      self._args)
+        self._traceme.__exit__(*exc)
         return False
 
 
-def span(name: str, cat: str = "runtime", **args) -> _SpanCtx:
-    """Begin/end span context manager (``ph: "X"`` complete event)."""
-    return _SpanCtx(name, cat, args or None)
+def span(name: str, cat: str = "runtime", **args):
+    """Begin/end span context manager: a profiler annotation ``name`` with
+    ``args`` as its stats and, with a journal installed, a ``ph: "X"``
+    journal entry under ``cat``.  Spans on one thread nest; a span that
+    outlives its block (``checkpoint.align``) is entered and exited by
+    hand, on one thread, outside any span opened after it."""
+    if _JOURNAL is None:
+        return TraceAnnotation(name, **args)    # inert without a session
+    return _SpanCtx(name, cat, args)
 
 
 def instant(name: str, cat: str = "runtime", **args) -> None:
-    """Point-in-time event (``ph: "i"``)."""
+    """Point-in-time event: ``ph: "i"`` in the journal, a zero-length
+    annotation in a profiler session."""
     j = _JOURNAL
     if j is not None:
         j.record("i", time.perf_counter_ns(), 0, name, cat, args or None)
+    if TraceAnnotation.is_enabled():
+        with TraceAnnotation(name, **args):
+            pass
 
 
 def complete(name: str, start_ns: int, end_ns: int,
              cat: str = "runtime", **args) -> None:
     """Complete span with explicit ``perf_counter_ns`` endpoints — for
-    sites that already timed themselves (phase timers, checkpoint
-    trigger→complete)."""
+    sites that already timed themselves and for spans that cross threads
+    (checkpoint trigger→complete).  The profiler cannot be handed a past
+    interval: it gets a zero-length annotation where the span ENDS, with
+    the length as ``dur_ns``."""
+    dur = max(0, end_ns - start_ns)
     j = _JOURNAL
     if j is not None:
-        j.record("X", start_ns, max(0, end_ns - start_ns), name, cat,
-                 args or None)
+        j.record("X", start_ns, dur, name, cat, args or None)
+    if TraceAnnotation.is_enabled():
+        with TraceAnnotation(name, dur_ns=dur, **args):
+            pass
 
 
 # ---------------------------------------------------------------------------

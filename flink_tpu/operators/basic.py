@@ -23,6 +23,7 @@ from flink_tpu.core import keygroups
 from flink_tpu.core.batch import RecordBatch, StreamElement, Watermark
 from flink_tpu.core.functions import AggregateFunction, RuntimeContext
 from flink_tpu.core.watermarks import WatermarkGenerator
+from flink_tpu.observability import tracing
 from flink_tpu.operators.base import StreamOperator
 from flink_tpu.ops.scatter import segment_running_fold
 from flink_tpu.state.keyindex import make_key_index
@@ -310,7 +311,15 @@ class SinkOperator(StreamOperator):
             self.sink.open(ctx)
 
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
-        self.sink.write_batch(batch)
+        # a window fire's rows carry its `window_end` column: the span
+        # shares that identifier with the fire's own spans upstream
+        end = batch.columns.get("window_end")
+        cause = {"window_end": int(end[0])} if (
+            len(batch) and isinstance(end, np.ndarray)
+            and end.dtype.kind == "i") else {}
+        with tracing.span("sink.invoke", cat="sink", records=len(batch),
+                          **cause):
+            self.sink.write_batch(batch)
         return []
 
     def process_watermark(self, watermark: Watermark) -> List[StreamElement]:

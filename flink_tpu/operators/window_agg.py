@@ -50,6 +50,7 @@ out-of-range slot ids, dropped by XLA scatter), state grows by doubling
 
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -140,6 +141,16 @@ def _x64():
     in 32-bit mode — ``enable_x64`` widens dtypes for exactly the delta
     steps (allocation, fold, pull, clear) and nothing else."""
     return jax.enable_x64()
+
+
+@jax.jit
+def _snapshot_read_step(state, pane_slots):
+    """A checkpoint's device read of one ``[K, P, ...]`` state array: its
+    live pane columns.  What an eager ``jnp.take`` computes, as a program
+    with a name of its own in a device trace (one per array shape and
+    live-pane count, shared by every operator instance)."""
+    with jax.named_scope("pane_gather"):
+        return jnp.take(state, pane_slots, axis=1)
 
 
 class _HotPipeline:
@@ -253,30 +264,41 @@ class _Staging:
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
 
+def phase_span_name(phase: str) -> str:
+    """The span a ``_phase`` emits (profiler trace and journal alike):
+    ``window_agg.<phase>``, but for the dispatch, which has carried its
+    name in profiler traces since before the phases had spans (the
+    benchmark's gap attribution reads it)."""
+    if phase == "device_dispatch":
+        return "window_agg.device_step"
+    return "window_agg." + phase
+
+
 class _PhaseTimer:
-    """Accumulates wall time into a dict entry (bench phase breakdown).
-    When the span journal is installed, each timed region ALSO records a
-    "hot_stage" span under the SAME phase name — ``--profile`` and traces
-    agree on the vocabulary (tests/test_bench_gate scrapes it)."""
+    """Accumulates wall time into a dict entry (``phase_ns``: the bench
+    phase breakdown, tests/test_bench_gate scrapes the vocabulary) and
+    emits the same region as a ``hot_stage`` span through
+    :func:`tracing.span`.  ``args`` name what caused the work
+    (``window_end`` for a fire, ``checkpoint`` for a cut)."""
 
-    __slots__ = ("_d", "_k", "_t0")
+    __slots__ = ("_d", "_k", "_t0", "_span")
 
-    def __init__(self, d: Dict[str, int], key: str):
+    def __init__(self, d: Dict[str, int], key: str,
+                 args: Optional[Dict[str, Any]] = None):
         self._d = d
         self._k = key
+        self._span = tracing.span(phase_span_name(key), cat="hot_stage",
+                                  **(args or {}))
 
     def __enter__(self):
-        import time
+        self._span.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        import time
         t1 = time.perf_counter_ns()
         self._d[self._k] = self._d.get(self._k, 0) + t1 - self._t0
-        j = tracing._JOURNAL       # one attr read + None check when off
-        if j is not None:
-            j.record("X", self._t0, t1 - self._t0, self._k, "hot_stage")
+        self._span.__exit__(*exc)
         return False
 
 
@@ -527,6 +549,9 @@ class WindowAggOperator(StreamOperator):
         #: bytes
         self.phase_ns: Dict[str, int] = {}
         self.phase_bytes: Dict[str, int] = {}
+        #: what caused the work the phases now time (``window_end`` of a
+        #: fire, ``checkpoint`` of a cut): an argument of their spans
+        self._span_args: Optional[Dict[str, Any]] = None
         #: per-shard phase accounting: phase name -> int64[n_shards] ns,
         #: filled when the fused probe runs sharded with a timing buffer
         #: (the mesh runtime's per-shard probe breakdown; empty otherwise)
@@ -907,8 +932,20 @@ class WindowAggOperator(StreamOperator):
 
     # ---------------------------------------------------- host value mirror
     def _phase(self, name: str):
-        """Accumulating timer: ``with self._phase("mirror"): ...``."""
-        return _PhaseTimer(self.phase_ns, name)
+        """Accumulating timer and span: ``with self._phase("mirror"): ...``."""
+        return _PhaseTimer(self.phase_ns, name, self._span_args)
+
+    @contextlib.contextmanager
+    def _caused_by(self, **args):
+        """Per fire / per cut: the phases inside carry ``args`` (those
+        that are not None) on their spans, so one result's spans share an
+        identifier."""
+        prev = self._span_args
+        self._span_args = {k: v for k, v in args.items() if v is not None}
+        try:
+            yield
+        finally:
+            self._span_args = prev
 
     def _try_native_mirror(self) -> None:
         """Bind the C++ WinMirror to the (fresh) key index, if eligible.
@@ -2088,22 +2125,38 @@ class WindowAggOperator(StreamOperator):
     def _update_step(self, leaves, counts, flat_ids, values):
         """One micro-batch fold: lift + scatter-combine. flat_ids ∈ [0, K*P]
         with K*P meaning 'dropped padding row'."""
+        # the named scopes are each stage's name in a device trace: an
+        # `XLA Ops` event's op_name says which of them asked for it
         K, P = counts.shape
-        lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-        flat_leaves = tuple(l.reshape((K * P,) + l.shape[2:]) for l in leaves)
+        with jax.named_scope("lift"):
+            lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
+        with jax.named_scope("state_flatten"):
+            flat_leaves = tuple(l.reshape((K * P,) + l.shape[2:])
+                                for l in leaves)
         if self.kinds is not None:
-            new_flat = scatter_fast(flat_leaves, flat_ids, lifted, self.kinds)
+            new_flat = ()
+            for i, kind in enumerate(self.kinds):
+                with jax.named_scope(f"leaf{i}_scatter_{kind}"):
+                    new_flat += scatter_fast(flat_leaves[i:i + 1], flat_ids,
+                                             lifted[i:i + 1], (kind,))
         else:
-            new_flat = scatter_generic(flat_leaves, flat_ids, lifted,
-                                       self.agg.combine_leaves, K * P)
-        new_leaves = tuple(l.reshape((K, P) + l.shape[1:]) for l in new_flat)
-        ones = jnp.ones(flat_ids.shape, jnp.int32)  # device-side: keeps the
-        # host→device upload to ids+values only
-        new_counts = counts.reshape(K * P).at[flat_ids].add(ones, mode="drop").reshape(K, P)
+            with jax.named_scope("leaf_scatter_generic"):
+                new_flat = scatter_generic(flat_leaves, flat_ids, lifted,
+                                           self.agg.combine_leaves, K * P)
+        with jax.named_scope("state_unflatten"):
+            new_leaves = tuple(l.reshape((K, P) + l.shape[1:])
+                               for l in new_flat)
+        with jax.named_scope("count_fold"):
+            # ones made device-side: keeps the host→device upload to
+            # ids+values only
+            ones = jnp.ones(flat_ids.shape, jnp.int32)
+            new_counts = counts.reshape(K * P).at[flat_ids].add(
+                ones, mode="drop").reshape(K, P)
         # scalar completion token: ready exactly when THIS execution
         # finished — the staging-reuse gate (new_counts itself is donated
         # into the next step, so its own readiness is unobservable)
-        return new_leaves, new_counts, new_counts[0, 0]
+        with jax.named_scope("completion_token"):
+            return new_leaves, new_counts, new_counts[0, 0]
 
     def _fire_core(self, leaves, counts, pane_slots, k_active: int):
         """Shared fire body: slice live rows, gather window panes, combine,
@@ -2111,13 +2164,19 @@ class WindowAggOperator(StreamOperator):
         live — slicing inside the jit lets XLA fuse slice+gather, so fire cost
         scales with live keys, not allocated capacity."""
         if k_active and k_active < counts.shape[0]:
-            leaves = tuple(jax.lax.slice_in_dim(l, 0, k_active, axis=0)
-                           for l in leaves)
-            counts = jax.lax.slice_in_dim(counts, 0, k_active, axis=0)
-        sel = tuple(jnp.take(l, pane_slots, axis=1) for l in leaves)
-        total = jnp.take(counts, pane_slots, axis=1).sum(axis=1)
-        combined = combine_along_axis(sel, self.agg.combine_leaves, axis=1)
-        result = self.agg.get_result(self.spec.unflatten(combined))
+            with jax.named_scope("live_rows"):
+                leaves = tuple(jax.lax.slice_in_dim(l, 0, k_active, axis=0)
+                               for l in leaves)
+                counts = jax.lax.slice_in_dim(counts, 0, k_active, axis=0)
+        with jax.named_scope("pane_gather"):
+            sel = tuple(jnp.take(l, pane_slots, axis=1) for l in leaves)
+        with jax.named_scope("count_total"):
+            total = jnp.take(counts, pane_slots, axis=1).sum(axis=1)
+        with jax.named_scope("pane_combine"):
+            combined = combine_along_axis(sel, self.agg.combine_leaves,
+                                          axis=1)
+        with jax.named_scope("get_result"):
+            result = self.agg.get_result(self.spec.unflatten(combined))
         return total > 0, result
 
     @partial(jax.jit, static_argnums=(0, 4))
@@ -2137,6 +2196,13 @@ class WindowAggOperator(StreamOperator):
         total = jnp.take(counts, pane_slots, axis=1).sum(axis=1)
         combined = combine_along_axis(sel, self.agg.combine_leaves, axis=1)
         return total > 0, combined
+
+    def _pane_slots(self, panes: np.ndarray):
+        """Ring slots of ``panes`` as a device int32 vector.  Cast on the
+        host: ``jnp.asarray(int64_array, jnp.int32)`` runs a program of its
+        own (``jit(convert_element_type)``) for every fire, clear and cut."""
+        return jnp.asarray(
+            (np.asarray(panes, np.int64) % self._P).astype(np.int32))
 
     def _k_active(self) -> int:
         """Static pow2 bound on live key rows (0 = use full capacity).
@@ -2164,27 +2230,34 @@ class WindowAggOperator(StreamOperator):
         the (slow) device->host direction.  The batched analog of the
         reference emitting only non-empty windows
         (``WindowOperator.emitWindowContents:574``)."""
-        sel = tuple(jnp.take(jnp.take(l, idx, axis=0), pane_slots, axis=1)
-                    for l in leaves)
-        combined = combine_along_axis(sel, self.agg.combine_leaves, axis=1)
-        return self.agg.get_result(self.spec.unflatten(combined))
+        with jax.named_scope("emit_rows_pane_gather"):
+            sel = tuple(jnp.take(jnp.take(l, idx, axis=0), pane_slots,
+                                 axis=1) for l in leaves)
+        with jax.named_scope("pane_combine"):
+            combined = combine_along_axis(sel, self.agg.combine_leaves,
+                                          axis=1)
+        with jax.named_scope("get_result"):
+            return self.agg.get_result(self.spec.unflatten(combined))
 
     def _fire_window_gather(self, window_id: int,
                             panes: np.ndarray) -> List[StreamElement]:
         """Mirror-indexed fire (unsharded state): exact emit set from the
         host mirror, one values-only download."""
-        idx = self._mirror_emit_idx(panes)
-        n = idx.size
-        if n == 0:
-            return []
-        cap = _quantize_cap(n)
-        idx_p = np.zeros(cap, np.int32)
-        idx_p[:n] = idx
-        pane_slots = jnp.asarray(panes % self._P, jnp.int32)
-        result = self._fire_gather_step(self._leaves, pane_slots,
-                                        jnp.asarray(idx_p))
-        handle = _fetch_enqueue(jax.tree_util.tree_leaves(result))
-        treedef = jax.tree_util.tree_structure(result)
+        with self._phase("fire_dispatch"):
+            # the emit set from the host mirror, then the gather's launch
+            # and its device->host copies started: nothing waits here
+            idx = self._mirror_emit_idx(panes)
+            n = idx.size
+            if n == 0:
+                return []
+            cap = _quantize_cap(n)
+            idx_p = np.zeros(cap, np.int32)
+            idx_p[:n] = idx
+            pane_slots = self._pane_slots(panes)
+            result = self._fire_gather_step(self._leaves, pane_slots,
+                                            jnp.asarray(idx_p))
+            handle = _fetch_enqueue(jax.tree_util.tree_leaves(result))
+            treedef = jax.tree_util.tree_structure(result)
         if self._pager is not None:
             # rows -> global ids NOW: by the time an async fire drains, a
             # row may have been evicted and reassigned to another key
@@ -2216,14 +2289,20 @@ class WindowAggOperator(StreamOperator):
 
     def _finish_gather_fire(self, window_id: int, idx: np.ndarray, handle,
                             treedef) -> List[StreamElement]:
-        fetched = _fetch_collect(handle)
-        self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + \
-            sum(f.nbytes for f in fetched)
-        n = idx.size
-        picked = jax.tree_util.tree_unflatten(
-            treedef, [r[:n] for r in fetched])
-        return self._rows_for(idx, picked,
-                              self.assigner.window_bounds(window_id))
+        with self._phase("fire_d2h"):
+            # blocks until the device has run every step queued ahead of
+            # the gather, the gather, and the copy to the host
+            fetched = _fetch_collect(handle)
+        nbytes = sum(f.nbytes for f in fetched)
+        self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + nbytes
+        self.phase_bytes["d2h_fire"] = \
+            self.phase_bytes.get("d2h_fire", 0) + nbytes
+        with self._phase("fire_assemble"):
+            n = idx.size
+            picked = jax.tree_util.tree_unflatten(
+                treedef, [r[:n] for r in fetched])
+            return self._rows_for(idx, picked,
+                                  self.assigner.window_bounds(window_id))
 
     def _rows_for(self, idx: np.ndarray, result,
                   window) -> List[StreamElement]:
@@ -2263,11 +2342,13 @@ class WindowAggOperator(StreamOperator):
     @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
     def _clear_panes_step(self, leaves, counts, pane_slots):
         new_leaves = []
-        for l, init in zip(leaves, self.spec.leaf_inits):
-            fill = jnp.broadcast_to(jnp.asarray(init, l.dtype),
-                                    (l.shape[0], pane_slots.shape[0]) + l.shape[2:])
-            new_leaves.append(l.at[:, pane_slots].set(fill))
-        return tuple(new_leaves), counts.at[:, pane_slots].set(0)
+        with jax.named_scope("pane_clear"):
+            for l, init in zip(leaves, self.spec.leaf_inits):
+                fill = jnp.broadcast_to(
+                    jnp.asarray(init, l.dtype),
+                    (l.shape[0], pane_slots.shape[0]) + l.shape[2:])
+                new_leaves.append(l.at[:, pane_slots].set(fill))
+            return tuple(new_leaves), counts.at[:, pane_slots].set(0)
 
     @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
     def _purge_keys_step(self, leaves, counts, key_mask):
@@ -2570,15 +2651,17 @@ class WindowAggOperator(StreamOperator):
         else:
             # ---- pad to pow2 batch size into REUSED staging buffers
             # (static shapes; pads dropped via the out-of-range _PAD_ID)
-            lv, td = flat_values()
-            if staging is None:
-                staging = self._staging_acquire(_next_pow2(B, 64), lv, td)
-            flat_p = staging.flat
-            if not flat_ready:
-                flat_p[:B] = slots.astype(np.int64) * self._P \
-                    + (panes % self._P)
-                flat_p[B:] = _PAD_ID
-            values_p = staging.fill_values(lv, B)
+            with self._phase("stage"):
+                lv, td = flat_values()
+                if staging is None:
+                    staging = self._staging_acquire(_next_pow2(B, 64),
+                                                    lv, td)
+                flat_p = staging.flat
+                if not flat_ready:
+                    flat_p[:B] = slots.astype(np.int64) * self._P \
+                        + (panes % self._P)
+                    flat_p[B:] = _PAD_ID
+                values_p = staging.fill_values(lv, B)
 
             # np (not device) ids: the jit converts at dispatch, and the mesh
             # subclass re-routes them through the all_to_all exchange
@@ -2587,13 +2670,11 @@ class WindowAggOperator(StreamOperator):
             mb = (flat_p.nbytes + sum(a.nbytes for a in
                                       jax.tree_util.tree_leaves(values_p)))
             try:
+                # the phase's span is `window_agg.device_step`: the
+                # hand-off to the lane thread, the jitted call, and its
+                # wait for room in the device's queue
                 with self._phase("device_dispatch"):
-                    # nests the dispatch under this name in profiler
-                    # traces (bench.py --profile); a no-op when none is on
-                    with jax.profiler.TraceAnnotation(
-                            "window_agg.device_step"):
-                        res = self._guarded_update(flat_p, values_p,
-                                                   mb / 1e6)
+                    res = self._guarded_update(flat_p, values_p, mb / 1e6)
             except DeviceQuarantinedError as err:
                 # the device tier wedged mid-batch: migrate to the host
                 # tier and fold THIS batch there — no record is dropped
@@ -2719,7 +2800,7 @@ class WindowAggOperator(StreamOperator):
             def _salvage_gather():
                 if self._pager is not None:
                     return self._paged_snapshot_rows(n, panes)
-                slots = jnp.asarray(panes % self._P, jnp.int32)
+                slots = self._pane_slots(panes)
                 lv = [np.asarray(jnp.take(l, slots, axis=1))[:n]
                       for l in self._leaves]
                 return np.asarray(jnp.take(self._counts, slots,
@@ -2874,7 +2955,7 @@ class WindowAggOperator(StreamOperator):
                     raise DeviceQuarantinedError("re-promotion superseded")
                 self._paged_restore_rows(n, panes, counts, leaves)
         else:
-            slots = jnp.asarray(panes % self._P, jnp.int32)
+            slots = self._pane_slots(panes)
             with self._tier_lock:
                 if epoch != self._tier_epoch:
                     raise DeviceQuarantinedError("re-promotion superseded")
@@ -3024,8 +3105,7 @@ class WindowAggOperator(StreamOperator):
             # clear
             self._device_stale = True
         else:
-            slots = jnp.asarray(np.asarray(expired, np.int64) % self._P,
-                                jnp.int32)
+            slots = self._pane_slots(expired)
             self._leaves, self._counts = self._clear_panes_step(
                 self._leaves, self._counts, slots)
         for ep in expired:
@@ -3067,6 +3147,11 @@ class WindowAggOperator(StreamOperator):
 
     # ------------------------------------------------------------------ fires
     def _fire_window(self, window_id: int) -> List[StreamElement]:
+        with self._caused_by(
+                window_end=int(self.assigner.window_bounds(window_id).end)):
+            return self._fire_window_tier(window_id)
+
+    def _fire_window_tier(self, window_id: int) -> List[StreamElement]:
         if self._degraded and self.emit_tier != "host":
             # quarantined device tier: serve the fire from the host value
             # mirror (zero device ops), the same pane combine the host
@@ -3109,7 +3194,7 @@ class WindowAggOperator(StreamOperator):
                     out = out + self._fire_window_spilled(window_id, panes)
                 return out
         panes = np.arange(first, last + 1, dtype=np.int64)
-        pane_slots = jnp.asarray(panes % self._P, jnp.int32)
+        pane_slots = self._pane_slots(panes)
         mask, result = self._fire_step(self._leaves, self._counts, pane_slots,
                                        self._k_active())
         return self._emit(mask, result, self.assigner.window_bounds(window_id))
@@ -3229,7 +3314,7 @@ class WindowAggOperator(StreamOperator):
             if lo > hi:
                 continue
             panes = np.arange(lo, hi + 1, dtype=np.int64)
-            slots = jnp.asarray(panes % self._P, jnp.int32)
+            slots = self._pane_slots(panes)
             counts_w = np.asarray(
                 jnp.take(self._counts[:ka], slots, axis=1).sum(axis=1),
                 dtype=np.int64)
@@ -3308,9 +3393,11 @@ class WindowAggOperator(StreamOperator):
         if idx.size == 0:
             return []
         res_np = jax.tree_util.tree_map(lambda a: np.asarray(a)[idx], result)
-        self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + \
-            mask_np.nbytes + sum(a.nbytes for a in
-                                 jax.tree_util.tree_leaves(result))
+        nbytes = mask_np.nbytes + sum(a.nbytes for a in
+                                      jax.tree_util.tree_leaves(result))
+        self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + nbytes
+        self.phase_bytes["d2h_fire"] = \
+            self.phase_bytes.get("d2h_fire", 0) + nbytes
         return self._rows_for(idx, res_np, window)
 
     # ------------------------------------------------------------- paging
@@ -3754,7 +3841,7 @@ class WindowAggOperator(StreamOperator):
             # fresh rows are 0..R-1 in gid order: upload via a plain
             # slice-set, so resident row i == global id i after restore
             pager.assign_rows(np.arange(R, dtype=np.int64))
-            slots = jnp.asarray(panes % self._P, jnp.int32)
+            slots = self._pane_slots(panes)
             self._leaves = tuple(
                 l.at[:R, slots].set(jnp.asarray(s[:R]))
                 for l, s in zip(self._leaves, leaves_np))
@@ -3788,6 +3875,11 @@ class WindowAggOperator(StreamOperator):
         return []
 
     def snapshot_state(self) -> Dict[str, Any]:
+        cid = current_checkpoint_id()
+        with self._caused_by(checkpoint=cid):   # None for a final snapshot
+            return self._snapshot_state(cid)
+
+    def _snapshot_state(self, cid: Optional[int]) -> Dict[str, Any]:
         self.flush_pipeline()  # the snapshot must contain in-flight stages
         if self._pending_fires:
             # the runtime must call prepare_snapshot_pre_barrier first (all
@@ -3797,7 +3889,6 @@ class WindowAggOperator(StreamOperator):
                 "snapshot with in-flight async fires: the runtime must call "
                 "prepare_snapshot_pre_barrier() (and forward its elements) "
                 "before snapshot_state()")
-        cid = current_checkpoint_id()
         if self.incremental_state and cid is not None \
                 and snapshot_is_incremental():
             inc = self._incremental_snapshot(cid)
@@ -3830,7 +3921,9 @@ class WindowAggOperator(StreamOperator):
                 # cast down to the device leaf dtypes so the snapshot format
                 # is identical either way
                 with self._phase("snapshot"):
-                    counts, leaves = self._mirror_columns(panes.tolist(), n)
+                    with self._phase("snapshot_assemble"):
+                        counts, leaves = self._mirror_columns(
+                            panes.tolist(), n)
                     snap["leaves"] = leaves
                     snap["counts"] = counts
             elif self._pager is not None:
@@ -3844,15 +3937,22 @@ class WindowAggOperator(StreamOperator):
             else:
                 # snapshot only live keys × live panes (device→host transfer)
                 with self._phase("snapshot"):
-                    slots = jnp.asarray(panes % self._P, jnp.int32)
-                    snap["leaves"] = [
-                        np.asarray(jnp.take(l, slots, axis=1))[:n]
-                        for l in self._leaves]
-                    snap["counts"] = np.asarray(
-                        jnp.take(self._counts, slots, axis=1))[:n]
-                self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + \
-                    snap["counts"].nbytes + \
+                    slots = self._pane_slots(panes)
+                    with self._phase("snapshot_d2h"):
+                        # array by array: launch the read, wait for the
+                        # device (every step queued ahead of it first),
+                        # copy to the host
+                        read = [np.asarray(_snapshot_read_step(a, slots))
+                                for a in (*self._leaves, self._counts)]
+                    with self._phase("snapshot_assemble"):
+                        snap["leaves"] = [a[:n] for a in read[:-1]]
+                        snap["counts"] = read[-1][:n]
+                nbytes = snap["counts"].nbytes + \
                     sum(l.nbytes for l in snap["leaves"])
+                self.phase_bytes["d2h"] = \
+                    self.phase_bytes.get("d2h", 0) + nbytes
+                self.phase_bytes["d2h_snapshot"] = \
+                    self.phase_bytes.get("d2h_snapshot", 0) + nbytes
             from flink_tpu.state.evolution import acc_leaf_schema
             snap["leaf_schema"] = acc_leaf_schema(self.spec)
         if self._pager is not None:
@@ -3947,7 +4047,7 @@ class WindowAggOperator(StreamOperator):
                 self._device_stale = True
             else:
                 self._ensure_alloc()
-                slots = jnp.asarray(panes % self._P, jnp.int32)
+                slots = self._pane_slots(panes)
                 self._leaves = tuple(
                     l.at[:n, slots].set(jnp.asarray(s))
                     for l, s in zip(self._leaves, leaves))

@@ -427,8 +427,10 @@ def test_trace_artifact_smoke(tmp_path):
         artifact = json.load(f)
     assert artifact["displayTimeUnit"] == "ms"
     evs = artifact["traceEvents"]
+    from flink_tpu.operators.window_agg import phase_span_name
     hot = {e["name"] for e in evs if e.get("cat") == "hot_stage"}
-    assert hot and hot <= _operator_phase_names()
+    assert hot and hot <= {phase_span_name(p)
+                           for p in _operator_phase_names()}
     ckpt_names = {e["name"] for e in evs if e.get("cat") == "checkpoint"}
     assert {"checkpoint.trigger", "checkpoint.snapshot",
             "checkpoint"} <= ckpt_names

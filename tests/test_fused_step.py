@@ -446,10 +446,11 @@ def test_count_trigger_pins_unfused():
 
 def test_fused_scan_phase_and_span_names():
     """The --profile/tracing contract under fusion: scan-lane time lands
-    in a 'fused_scan' phase whose hot_stage spans ride the journal with
-    the same name (the test_bench_gate vocabulary scrape sees the literal
-    in window_agg.py)."""
+    in a 'fused_scan' phase whose hot_stage spans ride the journal under
+    the phase's span name (the test_bench_gate vocabulary scrape sees the
+    literal in window_agg.py)."""
     from flink_tpu.observability import tracing
+    from flink_tpu.operators.window_agg import phase_span_name
 
     j = tracing.install(tracing.SpanJournal(capacity=4096))
     try:
@@ -460,4 +461,6 @@ def test_fused_scan_phase_and_span_names():
     assert op.phase_ns.get("fused_scan", 0) > 0, \
         "scan-lane time not attributed to the fused_scan phase"
     names = {s[3] for s in j.snapshot()["spans"] if s[4] == "hot_stage"}
-    assert "fused_scan" in names, "no fused_scan hot_stage spans emitted"
+    assert phase_span_name("fused_scan") == "window_agg.fused_scan"
+    assert "window_agg.fused_scan" in names, \
+        "no fused_scan hot_stage spans emitted"

@@ -561,3 +561,351 @@ def test_process_cluster_merged_timeline(tmp_path):
     ts = [e["ts"] for e in evs if "ts" in e]
     assert ts == sorted(ts)
     json.dumps(trace)                    # Perfetto-loadable = valid JSON
+
+
+# ---------------------------------------------------------------------------
+# the span layer on the profiler's clock (ISSUE-26): one emit path, two
+# sinks — the journal when installed, jax.profiler's TraceMe always
+# ---------------------------------------------------------------------------
+
+class _GatedSource:
+    """Two splits of a keyed stream that park half way — yielding a
+    watermark their timestamps operator swallows, so the source task keeps
+    serving its command queue — until the test releases them: the cut is
+    taken while both splits are live, whatever the machine's speed."""
+
+    bounded = True
+
+    def __init__(self, n=24_000, batch=512):
+        from flink_tpu.connectors.sources import CollectionSource
+
+        ts = np.arange(n, dtype=np.int64) // (n // 1000)      # 0..999 ms
+        self._rows = CollectionSource(
+            columns={"k": (np.arange(n) * 7) % 257,
+                     "v": np.ones(n, np.float32), "ts": ts},
+            timestamp_column="ts", batch_size=batch)
+        self.release = threading.Event()
+
+    def create_splits(self, parallelism):
+        from flink_tpu.connectors.sources import SourceSplit
+
+        return [SourceSplit(self, i, parallelism) for i in range(parallelism)]
+
+    def read_split(self, index, of):
+        from flink_tpu.core.batch import LONG_MIN, Watermark
+
+        batches = list(self._rows.read_split(index, of))
+        yield from batches[:len(batches) // 2]
+        while not self.release.is_set():
+            time.sleep(0.001)
+            yield Watermark(LONG_MIN)
+        yield from batches[len(batches) // 2:]
+
+
+def _window_ops(cluster):
+    from flink_tpu.operators.window_agg import WindowAggOperator
+
+    return [m for t in cluster.tasks()
+            for m in getattr(t.operator, "operators", [t.operator])
+            if isinstance(m, WindowAggOperator)]
+
+
+def _run_keyed_window_job(channel_capacity=4096):
+    """Parallelism 2, device tier, 250 ms tumbling windows over one second
+    of event time, ONE checkpoint taken while both splits are parked (so
+    every window task aligns two live channels) and four fires a subtask.
+    Returns (cluster, result)."""
+    import jax.numpy as jnp
+
+    from flink_tpu.core.functions import SumAggregator
+    from flink_tpu.datastream.api import StreamExecutionEnvironment
+    from flink_tpu.runtime.checkpoint.storage import InMemoryCheckpointStorage
+    from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+
+    env = StreamExecutionEnvironment(parallelism=2)
+    assert env.last_cluster is None
+    source = _GatedSource()
+    (env.from_source(source, name="gated")
+        .assign_timestamps_and_watermarks(0, timestamp_column="ts")
+        .key_by("k").window(TumblingEventTimeWindows.of(250))
+        .aggregate(SumAggregator(jnp.float32), value_column="v",
+                   emit_tier="device")
+        .collect())
+
+    def cut():
+        try:
+            deadline = time.monotonic() + 60.0
+            while time.monotonic() < deadline:
+                cluster = env.last_cluster
+                ops = _window_ops(cluster) if cluster is not None else []
+                if len(ops) == 2 and all(
+                        op.phase_ns.get("device_dispatch") for op in ops):
+                    break
+                time.sleep(0.002)
+            cid = None
+            while cid is None and time.monotonic() < deadline:
+                cid = cluster.trigger_checkpoint()
+                time.sleep(0.002)
+            while time.monotonic() < deadline and cid not in \
+                    cluster.job_status()["completed_checkpoints"]:
+                time.sleep(0.002)
+        finally:
+            source.release.set()
+
+    driver = threading.Thread(target=cut, daemon=True)
+    driver.start()
+    result = env.execute_cluster(
+        "span-job", storage=InMemoryCheckpointStorage(),
+        checkpoint_interval_ms=0, channel_capacity=channel_capacity,
+        timeout_s=120.0)
+    driver.join(timeout=60.0)
+    assert not driver.is_alive()
+    assert result.state == "FINISHED", result.error
+    assert list(result.completed_checkpoints) == [1]
+    return env.last_cluster, result
+
+
+#: span -> (enclosing span on the same thread or None, identifier argument
+#: shared along one cut / one fire or None): the table of
+#: docs/operations.md "Tracing and latency tracking"
+SPAN_TABLE = {
+    "source.next": (None, None),
+    "exchange.partition": (None, None),
+    "task.input_wait": (None, None),
+    "task.process_batch": (None, None),
+    "window_agg.probe": ("task.process_batch", None),
+    "window_agg.stage": ("task.process_batch", None),
+    "window_agg.device_step": ("task.process_batch", None),
+    "window_agg.mirror": (None, None),          # host tier, below
+    "window_agg.probe_mirror": (None, None),    # host tier, native mirror
+    "window_agg.fire": (None, "window_end"),
+    "window_agg.fire_dispatch": ("window_agg.fire", "window_end"),
+    "window_agg.fire_d2h": ("window_agg.fire", "window_end"),
+    "window_agg.fire_assemble": ("window_agg.fire", "window_end"),
+    "checkpoint.align": (None, "checkpoint"),
+    "checkpoint.snapshot": (None, "checkpoint"),
+    "window_agg.snapshot": ("checkpoint.snapshot", "checkpoint"),
+    "window_agg.snapshot_d2h": ("window_agg.snapshot", "checkpoint"),
+    "window_agg.snapshot_assemble": ("window_agg.snapshot", "checkpoint"),
+    # on the thread of whichever task acknowledged last
+    "checkpoint.complete": (None, "checkpoint"),
+    "checkpoint.store": ("checkpoint.complete", "checkpoint"),
+    "sink.invoke": (None, "window_end"),
+}
+
+
+def _host_tier_batches(native):
+    """Two batches through a host-tier operator on the calling thread: the
+    `mirror` phase (numpy mirror) or `probe_mirror` (native mirror)."""
+    import jax.numpy as jnp
+
+    from flink_tpu.core.batch import RecordBatch
+    from flink_tpu.core.functions import RuntimeContext, SumAggregator
+    from flink_tpu.operators.window_agg import WindowAggOperator
+    from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+
+    op = WindowAggOperator(TumblingEventTimeWindows.of(250),
+                           SumAggregator(jnp.float32), key_column="k",
+                           value_column="v", emit_tier="host",
+                           native_emit=native)
+    op.open(RuntimeContext())
+    for i in range(2):
+        op.process_batch(RecordBatch(
+            {"k": np.arange(64, dtype=np.int64), "v": np.ones(64, np.float32)},
+            timestamps=np.full(64, 10 * i, np.int64)))
+    op.close()
+    return set(op.phase_ns)
+
+
+@pytest.fixture(scope="module")
+def profiled_job(tmp_path_factory):
+    """The job under a profiler session somebody else might have started
+    (here: the test): per host thread line of the `.xplane.pb`, the
+    program's spans as (name, start ns, end ns, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracing.uninstall()
+    out = str(tmp_path_factory.mktemp("xplane"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=options)
+    try:
+        _run_keyed_window_job()
+        phases = _host_tier_batches(False) | _host_tier_batches(True)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(f"{out}/plugins/profile/*/*.xplane.pb")
+    assert len(found) == 1
+    threads = []
+    for plane in ProfileData.from_file(found[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            spans = [(ev.name, int(ev.start_ns),
+                      int(ev.start_ns + ev.duration_ns), dict(ev.stats))
+                     for ev in line.events if ev.name in SPAN_TABLE
+                     or ev.name == "exchange.put_wait"]
+            if spans:
+                threads.append(spans)
+    return {"threads": threads, "host_tier_phases": phases}
+
+
+def _enclosing(spans, child, parent_name):
+    return [p for p in spans if p[0] == parent_name
+            and p[1] <= child[1] and child[2] <= p[2]]
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_TABLE))
+def test_span_lands_in_the_profilers_trace(profiled_job, name):
+    parent, ident = SPAN_TABLE[name]
+    if name == "window_agg.probe_mirror" \
+            and "probe_mirror" not in profiled_job["host_tier_phases"]:
+        pytest.skip("the native mirror did not build here")
+    hits = [(spans, s) for spans in profiled_job["threads"]
+            for s in spans if s[0] == name]
+    assert hits, f"no {name!r} event on any host line"
+    for spans, s in hits:
+        assert s[2] >= s[1]
+        if parent is None or not any(t[0] == "task.process_batch"
+                                     for t in spans):
+            continue        # no parent, or the host-tier run's own thread
+        if ident == "checkpoint" and ident not in s[3]:
+            continue        # the task's final snapshot: no cut caused it
+        inside = _enclosing(spans, s, parent)
+        assert inside, f"{name} at {s[1]} is outside every {parent}"
+        if ident is not None:
+            assert inside[0][3][ident] == s[3][ident]
+    if ident is not None:
+        assert any(ident in s[3] for _, s in hits)
+
+
+def test_one_cut_and_one_fire_share_their_identifier(profiled_job):
+    """`checkpoint=` is equal on everything a cut causes on a window task's
+    thread and `window_end=` on everything a fire causes, through to the
+    sink; with channels this deep no put ever blocked, so no
+    `exchange.put_wait` exists."""
+    coordinator = {"checkpoint.complete", "checkpoint.store"}
+    cut_names = {n for n, (_, ident) in SPAN_TABLE.items()
+                 if ident == "checkpoint"} - coordinator
+    for name in coordinator:        # once a cut, on the last acker's thread
+        assert [s[3]["checkpoint"] for spans in profiled_job["threads"]
+                for s in spans if s[0] == name] == [1]
+    fire_names = {n for n, (_, ident) in SPAN_TABLE.items()
+                  if ident == "window_end"}
+    window_threads = [spans for spans in profiled_job["threads"]
+                      if any(s[0] == "window_agg.fire" for s in spans)]
+    assert len(window_threads) == 2
+    for spans in window_threads:
+        cut = {s[0] for s in spans if s[3].get("checkpoint") == 1}
+        assert cut_names <= cut, cut_names - cut
+        ends = sorted({s[3]["window_end"] for s in spans
+                       if s[0] == "window_agg.fire"})
+        assert ends == [250, 500, 750, 1000]
+        for end in ends:
+            fire = [s for s in spans if s[3].get("window_end") == end]
+            assert {s[0] for s in fire} == fire_names
+            # the rows reach the sink after the fire that produced them
+            sink = [s for s in fire if s[0] == "sink.invoke"]
+            whole = [s for s in fire if s[0] == "window_agg.fire"]
+            assert sink[0][1] >= whole[0][2]
+            assert sink[0][3]["records"] > 0
+    assert not any(s[0] == "exchange.put_wait"
+                   for spans in profiled_job["threads"] for s in spans)
+
+
+def test_put_wait_is_a_span_only_when_a_put_blocks():
+    """Channels of capacity 1 in front of window tasks that compile their
+    first steps: the sources block, and only then does the span exist."""
+    j = tracing.install(SpanJournal(1 << 15))
+    cluster, _ = _run_keyed_window_job(channel_capacity=1)
+    waits = [s for s in j.spans() if s[3] == "exchange.put_wait"]
+    blocked_ns = sum(ch.backpressured_ns for t in cluster.tasks()
+                     for out in t.outputs for ch in out.channels)
+    assert waits and all(s[4] == "exchange" for s in waits)
+    assert 0 < sum(s[2] for s in waits) <= blocked_ns
+
+
+def test_no_session_no_journal_costs_no_journal_entry(monkeypatch):
+    """With neither sink open the emit path builds no journal entry (no
+    `_SpanCtx`, no `record`), and the operator's `phase_ns` holds the new
+    phases under their parents: a fire is at least its dispatch, its wait
+    for the device and its row assembly, a snapshot at least its reads and
+    its assembly."""
+    def refuse(*_a, **_k):
+        raise AssertionError("a journal entry was built with no journal")
+
+    monkeypatch.setattr(tracing._SpanCtx, "__init__", refuse)
+    monkeypatch.setattr(SpanJournal, "record", refuse)
+    assert tracing.active() is None
+    cluster, _ = _run_keyed_window_job()
+    ops = _window_ops(cluster)
+    assert len(ops) == 2
+    for op in ops:
+        ns = op.phase_ns
+        for key in ("probe", "stage", "device_dispatch", "fire",
+                    "fire_dispatch", "fire_d2h", "fire_assemble", "snapshot",
+                    "snapshot_d2h", "snapshot_assemble"):
+            assert ns.get(key, 0) > 0, key
+        assert ns["fire"] >= (ns["fire_dispatch"] + ns["fire_d2h"]
+                              + ns["fire_assemble"])
+        assert ns["snapshot"] >= ns["snapshot_d2h"] + ns["snapshot_assemble"]
+        assert 0 < op.phase_bytes["d2h_fire"] < op.phase_bytes["d2h"]
+        assert 0 < op.phase_bytes["d2h_snapshot"] < op.phase_bytes["d2h"]
+        assert op.phase_bytes["d2h"] == (op.phase_bytes["d2h_fire"]
+                                         + op.phase_bytes["d2h_snapshot"])
+
+
+#: journal category of every span of the cells' path (docs/operations.md)
+SPAN_CATEGORIES = {
+    "source.next": "source", "exchange.partition": "exchange",
+    "task.input_wait": "task", "task.process_batch": "task",
+    "window_agg.probe": "hot_stage", "window_agg.stage": "hot_stage",
+    "window_agg.device_step": "hot_stage", "window_agg.fire": "hot_stage",
+    "window_agg.fire_dispatch": "hot_stage",
+    "window_agg.fire_d2h": "hot_stage",
+    "window_agg.fire_assemble": "hot_stage",
+    "window_agg.snapshot": "hot_stage",
+    "window_agg.snapshot_d2h": "hot_stage",
+    "window_agg.snapshot_assemble": "hot_stage",
+    "checkpoint.align": "checkpoint", "checkpoint.snapshot": "checkpoint",
+    "checkpoint.alignment": "checkpoint", "checkpoint.barrier": "checkpoint",
+    "checkpoint.trigger": "checkpoint", "checkpoint.ack": "checkpoint",
+    "checkpoint": "checkpoint", "checkpoint.complete": "checkpoint",
+    "checkpoint.store": "checkpoint", "sink.invoke": "sink",
+}
+
+
+def test_journal_gets_the_same_names_without_a_session():
+    """A journal installed, no profiler session: the names of the
+    profiler's spans arrive in the journal, each under its category, with
+    the same identifiers."""
+    j = tracing.install(SpanJournal(1 << 15))
+    _run_keyed_window_job()
+    spans = j.spans()
+    assert j.dropped == 0
+    seen = {}
+    for _ph, _ts, _dur, name, cat, _tid, _args in spans:
+        seen.setdefault(name, set()).add(cat)
+    for name, cat in SPAN_CATEGORIES.items():
+        assert seen.get(name) == {cat}, (name, seen.get(name))
+    assert "exchange.put_wait" not in seen
+    fires = [s for s in spans if s[3] == "window_agg.fire_d2h"]
+    assert sorted({s[6]["window_end"] for s in fires}) == [250, 500, 750, 1000]
+    cut = [s for s in spans if s[3] == "window_agg.snapshot_d2h" and s[6]]
+    assert [s[6] for s in cut] == [{"checkpoint": 1}] * 2
+
+
+def test_cluster_exposes_its_running_tasks():
+    """`env.last_cluster.tasks()` is the public way to the job's subtasks
+    (the harness and `python -m flink_tpu` read it): a copy of the
+    deployment's list, sources first."""
+    from flink_tpu.cluster.task import SourceSubtask, Subtask
+
+    cluster, _ = _run_keyed_window_job()
+    tasks = cluster.tasks()
+    assert [type(t) for t in tasks] == [SourceSubtask] * 2 + [Subtask] * 2
+    assert tasks is not cluster.tasks() and tasks == cluster.tasks()
+    assert sum(t.records_in for t in tasks[2:]) == 24_000
