@@ -68,10 +68,8 @@ from flink_tpu.observability import tracing
 from flink_tpu.operators.base import (StreamOperator, current_checkpoint_id,
                                       snapshot_is_incremental)
 from flink_tpu.runtime.device_health import DeviceQuarantinedError
-from flink_tpu.ops.scatter import (combine_along_axis,
-                                   gather_row_pane_columns, reset_rows,
-                                   scatter_fast, scatter_fold_counts,
-                                   scatter_generic, set_row_pane_columns)
+from flink_tpu.ops.pane_layout import DROP_ID, KeyGrid, PaneRing
+from flink_tpu.ops.scatter import combine_along_axis
 from flink_tpu.state.keyindex import KeyIndex, ObjectKeyIndex, make_key_index
 from flink_tpu.state.paging import identity_grid
 from flink_tpu.windowing.assigners import GlobalWindows, WindowAssigner
@@ -130,9 +128,9 @@ def _handle_ready(sliced) -> bool:
 from flink_tpu.ops.shapes import next_pow2 as _next_pow2  # noqa: E402
 
 #: flat scatter id for padding rows: INT32_MAX is out of range for any
-#: [K_cap * P] state, so XLA's mode="drop" scatter discards it at EVERY
-#: capacity — unlike K*P, it stays a dropped id across mid-stage key growth
-_PAD_ID = np.int32(np.iinfo(np.int32).max)
+#: K_cap x P state, so the fold discards it at EVERY capacity — unlike K*P,
+#: it stays a dropped id across mid-stage key growth
+_PAD_ID = DROP_ID
 
 
 def _x64():
@@ -143,14 +141,33 @@ def _x64():
     return jax.enable_x64()
 
 
-@jax.jit
-def _snapshot_read_step(state, pane_slots):
-    """A checkpoint's device read of one ``[K, P, ...]`` state array: its
-    live pane columns.  What an eager ``jnp.take`` computes, as a program
-    with a name of its own in a device trace (one per array shape and
-    live-pane count, shared by every operator instance)."""
+@partial(jax.jit, static_argnums=(0,))
+def _snapshot_read_step(layout, state, pane_slot):
+    """A checkpoint's device read of one state array: ONE pane column,
+    ``[K, ...]`` (``pane_slot`` is ``int32[1]``).  A program with a name of
+    its own in a device trace, one per layout and dtype, shared by every
+    operator instance — and by every number of live panes: how many a cut
+    meets follows the job's pace, and a read shaped by that count
+    compiled in the middle of a run the first time the pace changed."""
     with jax.named_scope("pane_gather"):
-        return jnp.take(state, pane_slots, axis=1)
+        return layout.columns(state, pane_slot)[:, 0]
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _grow_keys_step(layout, new_k, state, init):
+    return layout.grow_keys(state, new_k, init)
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _grow_panes_step(layout, new_p, state, init, src_slots, dst_slots):
+    return layout.grow_panes(state, new_p, init, src_slots, dst_slots)
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+def _set_columns_step(layout, state, pane_slots, cols):
+    """A restore's device write of one state array: the first
+    ``cols.shape[0]`` key rows of its pane columns ``pane_slots``."""
+    return layout.set_columns(state, pane_slots, cols)
 
 
 class _HotPipeline:
@@ -587,8 +604,9 @@ class WindowAggOperator(StreamOperator):
             from flink_tpu.state.paging import DevicePager
             self._pager = DevicePager(paging, self.spec, self._K)
         self.key_index: Optional[KeyIndex | ObjectKeyIndex] = None
-        self._leaves = None          # tuple of [K, P, *leaf] device arrays
-        self._counts = None          # int32 [K, P]
+        # K x P cells per array, held as ``self._layout`` says
+        self._leaves = None          # tuple of device arrays, one per leaf
+        self._counts = None          # int32 element counts
         #: sliding count triggers: window id -> int64[<=K] count already
         #: fired per key slot (the CountTrigger count register, which clears
         #: on FIRE — next fire needs n MORE elements)
@@ -645,8 +663,9 @@ class WindowAggOperator(StreamOperator):
         self.device_probe = device_probe
         self._dki = None                      # DeviceKeyIndex when active
         self._devprobe_resolved: Optional[bool] = None
-        self._delta_leaves = None             # mirror-dtype [K, P] arrays
-        self._delta_counts = None             # int32 [K, P]
+        self._delta_leaves = None             # mirror-dtype state twins
+        self._delta_counts = None             # int32, in ``_delta_layout``
+        self._delta_layout = None
         self._delta_panes: set = set()        # panes with unsynced delta
         self._dp_stats = {"probe_hits": 0, "probe_misses": 0,
                           "miss_inserts": 0, "delta_syncs": 0}
@@ -891,15 +910,36 @@ class WindowAggOperator(StreamOperator):
         self._incr_clear()      # a fresh state has no confirmed delta base
 
     # ------------------------------------------------------------------ state
+    @property
+    def _layout(self):
+        """How the state arrays hold their K x P cells
+        (``ops/pane_layout.py``): the pane-major ring on one chip; the
+        key-major grid where the key axis shards over a mesh."""
+        cls = PaneRing if self.sharding is None else KeyGrid
+        return cls(self._K, self._P)
+
+    def _placed(self, arrays):
+        """State arrays committed to the operator's sharding, if any."""
+        if self.sharding is None:
+            return arrays
+        return [jax.device_put(a, self.sharding) for a in arrays]
+
+    def _replace_state(self, step, per_array) -> None:
+        """Every state array ``a`` (leaves, then counts) becomes
+        ``step(a, x)``, ``x`` its entry of ``per_array``."""
+        *leaves, self._counts = self._placed([
+            step(a, x) for a, x in zip((*self._leaves, self._counts),
+                                       per_array)])
+        self._leaves = tuple(leaves)
+
     def _alloc(self, K: int, P: int):
-        leaves = []
-        for init, shape, dtype in zip(self.spec.leaf_inits, self.spec.leaf_shapes,
-                                      self.spec.leaf_dtypes):
-            leaves.append(jnp.broadcast_to(jnp.asarray(init, dtype), (K, P) + tuple(shape)).copy())
-        counts = jnp.zeros((K, P), jnp.int32)
-        if self.sharding is not None:
-            leaves = [jax.device_put(l, self.sharding) for l in leaves]
-            counts = jax.device_put(counts, self.sharding)
+        layout = type(self._layout)(K, P)
+        leaves = [layout.full(init, shape, dtype)
+                  for init, shape, dtype in zip(self.spec.leaf_inits,
+                                                self.spec.leaf_shapes,
+                                                self.spec.leaf_dtypes)]
+        *leaves, counts = self._placed(
+            leaves + [layout.full(0, (), jnp.int32)])
         return tuple(leaves), counts
 
     def _ensure_alloc(self):
@@ -1038,42 +1078,32 @@ class WindowAggOperator(StreamOperator):
     def _drop_delta(self) -> None:
         self._delta_leaves = None
         self._delta_counts = None
+        self._delta_layout = None
         self._delta_panes = set()
 
     def _ensure_delta(self) -> None:
-        """Allocate the device-resident DELTA ring [K, P] in the MIRROR
-        dtypes (f64/i64 — the higher-precision twins, so warm-row folds
-        carry exactly the precision the host mirror fold would have)."""
-        if self._delta_counts is not None \
-                and self._delta_counts.shape == (self._K, self._P):
+        """Allocate the device-resident DELTA ring (K x P cells in the
+        state's layout) in the MIRROR dtypes (f64/i64 — the
+        higher-precision twins, so warm-row folds carry exactly the
+        precision the host mirror fold would have)."""
+        layout = self._layout
+        if self._delta_counts is not None and self._delta_layout == layout:
             return
         with _x64():
-            leaves = []
-            for init, mdt in zip(self.spec.leaf_inits, self._mirror_dtypes):
-                iv = np.asarray(init).astype(mdt)
-                leaves.append(jnp.broadcast_to(
-                    jnp.asarray(iv), (self._K, self._P)).copy())
-            counts = jnp.zeros((self._K, self._P), jnp.int32)
-            if self.sharding is not None:
-                leaves = [jax.device_put(l, self.sharding) for l in leaves]
-                counts = jax.device_put(counts, self.sharding)
+            leaves = [layout.full(np.asarray(init).astype(mdt), (), mdt)
+                      for init, mdt in zip(self.spec.leaf_inits,
+                                           self._mirror_dtypes)]
+            *leaves, counts = self._placed(
+                leaves + [layout.full(0, (), jnp.int32)])
         self._delta_leaves = tuple(leaves)
         self._delta_counts = counts
+        self._delta_layout = layout
         self._delta_panes = set()
 
-    def _delta_fold(self, dleaves, dcounts, flat, lifted):
-        """Traced helper: scatter-combine one batch's (flat id, value)
-        pairs into the delta ring (scatter_fast casts the f32 lifted
-        leaves up to the delta's f64/i64 dtypes)."""
-        K, P = dcounts.shape
-        dflat = tuple(l.reshape(K * P) for l in dleaves)
-        new, ndc = scatter_fold_counts(dflat, dcounts.reshape(K * P),
-                                       flat, lifted, self.kinds)
-        return tuple(l.reshape(K, P) for l in new), ndc.reshape(K, P)
-
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(3, 4, 5, 6))
-    def _probed_update_step(self, tab, b, leaves, counts, dleaves, dcounts,
-                            key_lo, key_hi, start, pane_slots, values):
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5, 6, 7))
+    def _probed_update_step(self, layout, tab, b, leaves, counts, dleaves,
+                            dcounts, key_lo, key_hi, start, pane_slots,
+                            values):
         """Scatter-sync micro-batch with the key probe INSIDE the jitted
         step: probe the device table, fold warm (hit) rows into both the
         device state (device precision) and the delta ring (mirror
@@ -1085,25 +1115,20 @@ class WindowAggOperator(StreamOperator):
         Bp = key_lo.shape[0]
         valid = jnp.arange(Bp, dtype=jnp.int32) < b
         hit = valid & (slot >= 0)
-        K, P = counts.shape
-        flat = jnp.where(hit, slot * P + pane_slots, _PAD_ID)
+        flat = jnp.where(hit, slot * layout.P + pane_slots, _PAD_ID)
         lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-        flat_leaves = tuple(l.reshape((K * P,) + l.shape[2:])
-                            for l in leaves)
-        new_flat = scatter_fast(flat_leaves, flat, lifted, self.kinds)
-        new_leaves = tuple(l.reshape((K, P) + l.shape[1:]) for l in new_flat)
-        ones = jnp.ones(flat.shape, jnp.int32)
-        new_counts = counts.reshape(K * P).at[flat].add(
-            ones, mode="drop").reshape(K, P)
-        ndl, ndc = self._delta_fold(dleaves, dcounts, flat, lifted)
+        new_leaves, new_counts = layout.fold(leaves, counts, flat, lifted,
+                                             self.kinds)
+        # the delta twins are f64/i64: the fold casts the lifted leaves up
+        ndl, ndc = layout.fold(dleaves, dcounts, flat, lifted, self.kinds)
         miss = valid & (slot < 0)
         miss_idx = jnp.nonzero(miss, size=Bp,
                                fill_value=Bp)[0].astype(jnp.int32)
         miss_count = jnp.sum(miss, dtype=jnp.int32)
         return new_leaves, new_counts, ndl, ndc, miss_idx, miss_count
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(3, 4))
-    def _probed_delta_step(self, tab, b, dleaves, dcounts,
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5))
+    def _probed_delta_step(self, layout, tab, b, dleaves, dcounts,
                            key_lo, key_hi, start, pane_slots, values):
         """Deferred-sync twin of :meth:`_probed_update_step`: the mirror is
         authoritative, so warm rows fold into the delta ring ONLY (the
@@ -1113,26 +1138,26 @@ class WindowAggOperator(StreamOperator):
         Bp = key_lo.shape[0]
         valid = jnp.arange(Bp, dtype=jnp.int32) < b
         hit = valid & (slot >= 0)
-        P = dcounts.shape[1]
-        flat = jnp.where(hit, slot * P + pane_slots, _PAD_ID)
+        flat = jnp.where(hit, slot * layout.P + pane_slots, _PAD_ID)
         lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-        ndl, ndc = self._delta_fold(dleaves, dcounts, flat, lifted)
+        ndl, ndc = layout.fold(dleaves, dcounts, flat, lifted, self.kinds)
         miss = valid & (slot < 0)
         miss_idx = jnp.nonzero(miss, size=Bp,
                                fill_value=Bp)[0].astype(jnp.int32)
         miss_count = jnp.sum(miss, dtype=jnp.int32)
         return ndl, ndc, miss_idx, miss_count
 
-    def _fused_scan_body(self, tab, Pn, pad_id, treedef, carry_is_state):
+    def _fused_scan_body(self, tab, layout, pad_id, treedef,
+                         carry_is_state):
         """One scan step of the fused megastep: probe the device table,
         fold warm rows, emit the compact miss list.  Shared by the scatter
         and deferred scan steps; ``carry_is_state`` distinguishes the
         (state, delta) carry from the delta-only carry."""
         from flink_tpu.state.device_keyindex import lax_probe
+        Pn = layout.P
 
-        def fold(flat, lifted, flat_leaves, flat_counts):
-            return scatter_fold_counts(flat_leaves, flat_counts, flat,
-                                       lifted, self.kinds)
+        def fold(flat, lifted, leaves, counts):
+            return layout.fold(leaves, counts, flat, lifted, self.kinds)
 
         def body(carry, xs):
             b, klo, khi, stt, ps = xs[:5]
@@ -1160,71 +1185,57 @@ class WindowAggOperator(StreamOperator):
 
         return body
 
-    @partial(jax.jit, static_argnums=(0, 12), donate_argnums=(2, 3, 4, 5))
-    def _fused_scan_update_step(self, tab, leaves, counts, dleaves, dcounts,
-                                bs, key_lo, key_hi, start, pane_slots,
-                                vplanes, treedef):
+    @partial(jax.jit, static_argnums=(0, 1, 13),
+             donate_argnums=(3, 4, 5, 6))
+    def _fused_scan_update_step(self, layout, tab, leaves, counts, dleaves,
+                                dcounts, bs, key_lo, key_hi, start,
+                                pane_slots, vplanes, treedef):
         """Scatter-sync scan megastep: ONE dispatch advances every staged
         micro-batch — per step, probe + device-state fold (device
         precision) + delta fold (mirror precision) — over donated state
         buffers, so steady-state warm-key super-batches cost exactly one
         dispatch.  Returns the per-step compact miss lists; the scalar
         miss total is the host's only mandatory read-back."""
-        K, Pn = counts.shape
-        fl = tuple(l.reshape((K * Pn,) + l.shape[2:]) for l in leaves)
-        fc = counts.reshape(K * Pn)
-        dl = tuple(l.reshape(K * Pn) for l in dleaves)
-        dc = dcounts.reshape(K * Pn)
-        body = self._fused_scan_body(tab, Pn, _PAD_ID, treedef, True)
-        (fl, fc, dl, dc), (miss_idx, miss_counts) = jax.lax.scan(
-            body, (fl, fc, dl, dc),
-            (bs, key_lo, key_hi, start, pane_slots) + tuple(vplanes))
-        new_leaves = tuple(l.reshape((K, Pn) + l.shape[1:]) for l in fl)
-        new_dl = tuple(l.reshape(K, Pn) for l in dl)
-        return (new_leaves, fc.reshape(K, Pn), new_dl, dc.reshape(K, Pn),
-                miss_idx, miss_counts)
+        body = self._fused_scan_body(tab, layout, _PAD_ID, treedef, True)
+        (leaves, counts, dleaves, dcounts), (miss_idx, miss_counts) = \
+            jax.lax.scan(
+                body, (leaves, counts, dleaves, dcounts),
+                (bs, key_lo, key_hi, start, pane_slots) + tuple(vplanes))
+        return leaves, counts, dleaves, dcounts, miss_idx, miss_counts
 
-    @partial(jax.jit, static_argnums=(0, 10), donate_argnums=(2, 3))
-    def _fused_scan_delta_step(self, tab, dleaves, dcounts, bs, key_lo,
-                               key_hi, start, pane_slots, vplanes, treedef):
+    @partial(jax.jit, static_argnums=(0, 1, 11), donate_argnums=(3, 4))
+    def _fused_scan_delta_step(self, layout, tab, dleaves, dcounts, bs,
+                               key_lo, key_hi, start, pane_slots, vplanes,
+                               treedef):
         """Deferred-sync scan megastep: the mirror is authoritative, so
         warm rows fold into the delta ring ONLY (the device replica
         catches up at device_refresh) — still one dispatch per
         super-batch."""
-        K, Pn = dcounts.shape
-        dl = tuple(l.reshape(K * Pn) for l in dleaves)
-        dc = dcounts.reshape(K * Pn)
-        body = self._fused_scan_body(tab, Pn, _PAD_ID, treedef, False)
-        (dl, dc), (miss_idx, miss_counts) = jax.lax.scan(
-            body, (dl, dc),
+        body = self._fused_scan_body(tab, layout, _PAD_ID, treedef, False)
+        (dleaves, dcounts), (miss_idx, miss_counts) = jax.lax.scan(
+            body, (dleaves, dcounts),
             (bs, key_lo, key_hi, start, pane_slots) + tuple(vplanes))
-        return (tuple(l.reshape(K, Pn) for l in dl), dc.reshape(K, Pn),
-                miss_idx, miss_counts)
+        return dleaves, dcounts, miss_idx, miss_counts
 
-    @partial(jax.jit, static_argnums=(0, 3))
-    def _delta_pull_step(self, dleaves, dcounts, rows: int, pane_slots):
+    @partial(jax.jit, static_argnums=(0, 1, 4))
+    def _delta_pull_step(self, layout, dleaves, dcounts, rows: int,
+                         pane_slots):
         """Bounded d2h pull: the delta columns of the panes about to be
         read (fire/snapshot/verify), first ``rows`` key rows only — the
         download scales with live keys x syncing panes, never the ring."""
-        cnt = jnp.take(dcounts[:rows], pane_slots, axis=1,
-                       mode="fill", fill_value=0)
-        sel = tuple(jnp.take(l[:rows], pane_slots, axis=1,
-                             mode="fill", fill_value=0)
+        cnt = layout.columns(dcounts, pane_slots, rows=rows, fill=0)
+        sel = tuple(layout.columns(l, pane_slots, rows=rows, fill=0)
                     for l in dleaves)
         return cnt, sel
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _delta_clear_step(self, dleaves, dcounts, pane_slots):
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _delta_clear_step(self, layout, dleaves, dcounts, pane_slots):
         """Reset synced (or expired) delta columns back to identity."""
-        new_leaves = []
-        for l, init, mdt in zip(dleaves, self.spec.leaf_inits,
-                                self._mirror_dtypes):
-            iv = np.asarray(init).astype(mdt)
-            fill = jnp.broadcast_to(jnp.asarray(iv),
-                                    (l.shape[0], pane_slots.shape[0]))
-            new_leaves.append(l.at[:, pane_slots].set(fill, mode="drop"))
-        return tuple(new_leaves), dcounts.at[:, pane_slots].set(
-            0, mode="drop")
+        new_leaves = tuple(
+            layout.fill_columns(l, pane_slots, np.asarray(init).astype(mdt))
+            for l, init, mdt in zip(dleaves, self.spec.leaf_inits,
+                                    self._mirror_dtypes))
+        return new_leaves, layout.fill_columns(dcounts, pane_slots, 0)
 
     def _devprobe_sync_mirror(self, panes=None) -> None:
         """Pane-granular mirror catch-up: pull the delta columns of
@@ -1254,11 +1265,13 @@ class WindowAggOperator(StreamOperator):
             with _x64():
                 slots_d = jnp.asarray(slots_np)
                 cnt, sel = self._delta_pull_step(
-                    self._delta_leaves, self._delta_counts, rows, slots_d)
+                    self._delta_layout, self._delta_leaves,
+                    self._delta_counts, rows, slots_d)
                 cnt_np = np.asarray(cnt)
                 sel_np = [np.asarray(l) for l in sel]
                 self._delta_leaves, self._delta_counts = \
-                    self._delta_clear_step(self._delta_leaves,
+                    self._delta_clear_step(self._delta_layout,
+                                           self._delta_leaves,
                                            self._delta_counts, slots_d)
             self.phase_bytes["delta_d2h"] = \
                 self.phase_bytes.get("delta_d2h", 0) + cnt_np.nbytes + \
@@ -1325,12 +1338,13 @@ class WindowAggOperator(StreamOperator):
                 with _x64():
                     if sync == "deferred":
                         out = self._probed_delta_step(
-                            tab, b_arr, self._delta_leaves,
+                            self._layout, tab, b_arr, self._delta_leaves,
                             self._delta_counts, klo_p, khi_p, st_p, ps_p,
                             values_p)
                     else:
                         out = self._probed_update_step(
-                            tab, b_arr, self._leaves, self._counts,
+                            self._layout, tab, b_arr, self._leaves,
+                            self._counts,
                             self._delta_leaves, self._delta_counts,
                             klo_p, khi_p, st_p, ps_p, values_p)
                 # the scalar miss count is the dispatch's sync point: a
@@ -1668,11 +1682,12 @@ class WindowAggOperator(StreamOperator):
                 with _x64():
                     if sync == "deferred":
                         out = self._fused_scan_delta_step(
-                            tab, self._delta_leaves, self._delta_counts,
+                            self._layout, tab, self._delta_leaves,
+                            self._delta_counts,
                             bs, klo, khi, stt, ps, tuple(vplanes), treedef)
                     else:
                         out = self._fused_scan_update_step(
-                            tab, self._leaves, self._counts,
+                            self._layout, tab, self._leaves, self._counts,
                             self._delta_leaves, self._delta_counts,
                             bs, klo, khi, stt, ps, tuple(vplanes), treedef)
                 # the scalar miss total is the dispatch's sync point: a
@@ -1867,19 +1882,20 @@ class WindowAggOperator(StreamOperator):
                         self.spec.leaf_dtypes[k], copy=False)
         return counts, leaves
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _refresh_step(self, leaves, counts, slots, counts_cols, leaf_cols):
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _refresh_step(self, layout, leaves, counts, slots, counts_cols,
+                      leaf_cols):
         """Replace the whole ring from live-pane COLUMNS: slots i32[m] are
         the live ring slots (pads = P, dropped), counts_cols [rows, m] with
         rows <= K covering the live keys, each leaf col [rows, m, *shape].
         Every other cell resets to identity — the upload scales with live
         panes x live keys, not ring/key capacity."""
-        rows = counts_cols.shape[0]
-        new_counts = jnp.zeros_like(counts).at[:rows, slots].set(
-            counts_cols, mode="drop")
+        new_counts = layout.set_columns(jnp.zeros_like(counts), slots,
+                                        counts_cols)
         new_leaves = tuple(
-            jnp.broadcast_to(jnp.asarray(init, l.dtype), l.shape)
-            .at[:rows, slots].set(col, mode="drop")
+            layout.set_columns(
+                jnp.broadcast_to(jnp.asarray(init, l.dtype), l.shape),
+                slots, col)
             for l, init, col in zip(leaves, self.spec.leaf_inits, leaf_cols))
         if self.sharding is not None:
             # the refresh must hand back PRE-PARTITIONED state (out
@@ -1924,7 +1940,8 @@ class WindowAggOperator(StreamOperator):
         slots[:len(live)] = [p % self._P for p in live]
         counts_cols, leaf_cols = self._mirror_columns(live, rows, ncols=m)
         self._leaves, self._counts = self._refresh_step(
-            self._leaves, self._counts, slots, counts_cols, tuple(leaf_cols))
+            self._layout, self._leaves, self._counts, slots, counts_cols,
+            tuple(leaf_cols))
         self.phase_bytes["h2d_refresh"] = (
             self.phase_bytes.get("h2d_refresh", 0) + counts_cols.nbytes
             + sum(l.nbytes for l in leaf_cols))
@@ -2047,9 +2064,11 @@ class WindowAggOperator(StreamOperator):
                 or self.pane_base is None:
             return True
         n = self.key_index.num_keys if self.key_index else 0
+        layout = self._layout
         for p in range(self.pane_base, (self.max_pane or 0) + 1):
-            slot = int(p) % self._P
-            dev_counts = np.asarray(self._counts[:n, slot])
+            slot = self._pane_slots([p])
+            dev_counts = np.asarray(
+                layout.columns(self._counts, slot, rows=n))[:, 0]
             if self._nm is not None:
                 _ex, cnts, lvs = self._nm.export_pane(p, n)
                 host = [cnts] + lvs
@@ -2060,7 +2079,9 @@ class WindowAggOperator(StreamOperator):
             if not np.array_equal(dev_counts, host_counts):
                 return False
             for j in range(self.spec.num_leaves):
-                dev = np.asarray(self._leaves[j][:n, slot], np.float64)
+                dev = np.asarray(
+                    layout.columns(self._leaves[j], slot, rows=n),
+                    np.float64)[:, 0]
                 hst = (np.asarray(host[j + 1][:n], np.float64)
                        if host is not None
                        else np.broadcast_to(np.asarray(
@@ -2083,20 +2104,20 @@ class WindowAggOperator(StreamOperator):
         newK = self._round_key_capacity(needed)
         if newK == self._K and self._leaves is not None:
             return
-        old_leaves, old_counts = self._leaves, self._counts
+        old = self._layout
         self._K = newK
         # grow EVERY live mirror pane with the capacity: a pane untouched
         # after the growth must still serve fires/snapshots at the new key
         # count (the lazy per-touch grow only covers touched panes)
         for p in list(self._vmirror):
             self._vmirror_pane(p)
-        fresh, fresh_counts = self._alloc(self._K, self._P)
-        if old_leaves is not None:
-            n = old_counts.shape[0]
-            self._leaves = tuple(f.at[:n].set(o) for f, o in zip(fresh, old_leaves))
-            self._counts = fresh_counts.at[:n].set(old_counts)
-        else:
-            self._leaves, self._counts = fresh, fresh_counts
+        if self._leaves is None:
+            self._leaves, self._counts = self._alloc(self._K, self._P)
+            return
+        self._replace_state(
+            lambda a, init: _grow_keys_step(old, newK, a,
+                                            np.asarray(init, a.dtype)),
+            (*self.spec.leaf_inits, 0))
 
     def _grow_panes(self, span: int):
         """Double the pane ring until it holds ``span`` live panes, remapping
@@ -2106,96 +2127,71 @@ class WindowAggOperator(StreamOperator):
             newP <<= 1
         if newP == self._P:
             return
-        old_leaves, old_counts, oldP = self._leaves, self._counts, self._P
+        old = self._layout
         self._P = newP
-        fresh, fresh_counts = self._alloc(self._K, newP)
-        if old_leaves is not None and self.pane_base is not None:
-            panes = np.arange(self.pane_base, self.max_pane + 1, dtype=np.int64)
-            src = jnp.asarray(panes % oldP, jnp.int32)
-            dst = jnp.asarray(panes % newP, jnp.int32)
-            self._leaves = tuple(
-                f.at[:, dst].set(jnp.take(o, src, axis=1))
-                for f, o in zip(fresh, old_leaves))
-            self._counts = fresh_counts.at[:, dst].set(jnp.take(old_counts, src, axis=1))
-        else:
-            self._leaves, self._counts = fresh, fresh_counts
+        if self._leaves is None or self.pane_base is None:
+            self._leaves, self._counts = self._alloc(self._K, newP)
+            return
+        panes = np.arange(self.pane_base, self.max_pane + 1, dtype=np.int64)
+        src = (panes % old.P).astype(np.int32)
+        dst = (panes % newP).astype(np.int32)
+        self._replace_state(
+            lambda a, init: _grow_panes_step(
+                old, newP, a, np.asarray(init, a.dtype), src, dst),
+            (*self.spec.leaf_inits, 0))
 
     # ------------------------------------------------------------- device ops
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _update_step(self, leaves, counts, flat_ids, values):
-        """One micro-batch fold: lift + scatter-combine. flat_ids ∈ [0, K*P]
-        with K*P meaning 'dropped padding row'."""
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _update_step(self, layout, leaves, counts, flat_ids, values):
+        """One micro-batch fold: lift + scatter-combine into the state as
+        it is held (``layout.fold``).  flat_ids are the host's
+        ``row * P + slot``; any id at or past ``K * P`` (the padding rows'
+        ``_PAD_ID``) is dropped."""
         # the named scopes are each stage's name in a device trace: an
         # `XLA Ops` event's op_name says which of them asked for it
-        K, P = counts.shape
         with jax.named_scope("lift"):
             lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-        with jax.named_scope("state_flatten"):
-            flat_leaves = tuple(l.reshape((K * P,) + l.shape[2:])
-                                for l in leaves)
-        if self.kinds is not None:
-            new_flat = ()
-            for i, kind in enumerate(self.kinds):
-                with jax.named_scope(f"leaf{i}_scatter_{kind}"):
-                    new_flat += scatter_fast(flat_leaves[i:i + 1], flat_ids,
-                                             lifted[i:i + 1], (kind,))
-        else:
-            with jax.named_scope("leaf_scatter_generic"):
-                new_flat = scatter_generic(flat_leaves, flat_ids, lifted,
-                                           self.agg.combine_leaves, K * P)
-        with jax.named_scope("state_unflatten"):
-            new_leaves = tuple(l.reshape((K, P) + l.shape[1:])
-                               for l in new_flat)
-        with jax.named_scope("count_fold"):
-            # ones made device-side: keeps the host→device upload to
-            # ids+values only
-            ones = jnp.ones(flat_ids.shape, jnp.int32)
-            new_counts = counts.reshape(K * P).at[flat_ids].add(
-                ones, mode="drop").reshape(K, P)
+        new_leaves, new_counts = layout.fold(
+            leaves, counts, flat_ids, lifted, self.kinds,
+            self.agg.combine_leaves)
         # scalar completion token: ready exactly when THIS execution
         # finished — the staging-reuse gate (new_counts itself is donated
         # into the next step, so its own readiness is unobservable)
         with jax.named_scope("completion_token"):
-            return new_leaves, new_counts, new_counts[0, 0]
+            return new_leaves, new_counts, new_counts[(0,) * new_counts.ndim]
 
-    def _fire_core(self, leaves, counts, pane_slots, k_active: int):
-        """Shared fire body: slice live rows, gather window panes, combine,
-        get_result.  k_active (static): only the first k_active key rows are
-        live — slicing inside the jit lets XLA fuse slice+gather, so fire cost
-        scales with live keys, not allocated capacity."""
-        if k_active and k_active < counts.shape[0]:
-            with jax.named_scope("live_rows"):
-                leaves = tuple(jax.lax.slice_in_dim(l, 0, k_active, axis=0)
-                               for l in leaves)
-                counts = jax.lax.slice_in_dim(counts, 0, k_active, axis=0)
+    def _fire_acc_core(self, layout, leaves, counts, pane_slots,
+                       k_active: int):
+        """Shared fire body: the window's pane columns of the live rows,
+        combined.  k_active (static): only the first k_active key rows are
+        live, so fire cost scales with live keys, not allocated capacity
+        (0 = every row)."""
+        rows = k_active or None
         with jax.named_scope("pane_gather"):
-            sel = tuple(jnp.take(l, pane_slots, axis=1) for l in leaves)
+            sel = tuple(layout.columns(l, pane_slots, rows=rows)
+                        for l in leaves)
         with jax.named_scope("count_total"):
-            total = jnp.take(counts, pane_slots, axis=1).sum(axis=1)
+            total = layout.columns(counts, pane_slots, rows=rows).sum(axis=1)
         with jax.named_scope("pane_combine"):
             combined = combine_along_axis(sel, self.agg.combine_leaves,
                                           axis=1)
+        return total > 0, combined
+
+    @partial(jax.jit, static_argnums=(0, 1, 5))
+    def _fire_step(self, layout, leaves, counts, pane_slots, k_active: int):
+        mask, combined = self._fire_acc_core(layout, leaves, counts,
+                                             pane_slots, k_active)
         with jax.named_scope("get_result"):
-            result = self.agg.get_result(self.spec.unflatten(combined))
-        return total > 0, result
+            return mask, self.agg.get_result(self.spec.unflatten(combined))
 
-    @partial(jax.jit, static_argnums=(0, 4))
-    def _fire_step(self, leaves, counts, pane_slots, k_active: int):
-        return self._fire_core(leaves, counts, pane_slots, k_active)
-
-    @partial(jax.jit, static_argnums=(0, 4))
-    def _fire_acc_step(self, leaves, counts, pane_slots, k_active: int):
+    @partial(jax.jit, static_argnums=(0, 1, 5))
+    def _fire_acc_step(self, layout, leaves, counts, pane_slots,
+                       k_active: int):
         """Like ``_fire_step`` but returns the combined ACCUMULATOR leaves
         (pre-``get_result``): the purging-count-trigger path subtracts the
         per-window value baseline from the acc before producing output."""
-        if k_active and k_active < counts.shape[0]:
-            leaves = tuple(jax.lax.slice_in_dim(l, 0, k_active, axis=0)
-                           for l in leaves)
-            counts = jax.lax.slice_in_dim(counts, 0, k_active, axis=0)
-        sel = tuple(jnp.take(l, pane_slots, axis=1) for l in leaves)
-        total = jnp.take(counts, pane_slots, axis=1).sum(axis=1)
-        combined = combine_along_axis(sel, self.agg.combine_leaves, axis=1)
-        return total > 0, combined
+        return self._fire_acc_core(layout, leaves, counts, pane_slots,
+                                   k_active)
 
     def _pane_slots(self, panes: np.ndarray):
         """Ring slots of ``panes`` as a device int32 vector.  Cast on the
@@ -2203,6 +2199,34 @@ class WindowAggOperator(StreamOperator):
         own (``jit(convert_element_type)``) for every fire, clear and cut."""
         return jnp.asarray(
             (np.asarray(panes, np.int64) % self._P).astype(np.int32))
+
+    def _read_columns(self, panes, n: int) -> List[np.ndarray]:
+        """The first ``n`` key rows of the pane columns ``panes`` of every
+        state array (leaves, then counts) on the host: ``[n, len(panes),
+        ...]`` each, a transposed view of the columns as they arrive."""
+        layout = self._layout
+        with self._phase("snapshot_d2h"):
+            # column by column: launch every read, then wait for the
+            # device (every step queued ahead of them first) and copy
+            slots = [self._pane_slots([p]) for p in np.asarray(panes)]
+            handle = _fetch_enqueue([
+                _snapshot_read_step(layout, a, slot)
+                for a in (*self._leaves, self._counts) for slot in slots])
+            cols = _fetch_collect(handle)
+        with self._phase("snapshot_assemble"):
+            m = len(slots)
+            return [np.moveaxis(np.stack([c[:n] for c in
+                                          cols[i * m:(i + 1) * m]]), 0, 1)
+                    for i in range(len(self._leaves) + 1)]
+
+    def _set_columns(self, pane_slots, leaf_cols, counts_cols) -> None:
+        """Restore: write host columns ``[n, live, ...]`` over the first
+        ``n`` key rows of the pane columns ``pane_slots``."""
+        layout = self._layout
+        self._replace_state(
+            lambda a, cols: _set_columns_step(layout, a, pane_slots,
+                                              np.asarray(cols)),
+            (*leaf_cols, counts_cols))
 
     def _k_active(self) -> int:
         """Static pow2 bound on live key rows (0 = use full capacity).
@@ -2221,21 +2245,17 @@ class WindowAggOperator(StreamOperator):
             ka <<= 2
         return min(ka, self._K)
 
-    @partial(jax.jit, static_argnums=(0,))
-    def _fire_gather_step(self, leaves, pane_slots, idx):
-        """Fire for a host-known emit set: gather the ``idx`` key rows FIRST
-        (compute and download scale with rows *emitted*, not key capacity),
-        combine their window panes, ``get_result``.  The emit index is
-        host-derived from the mirror — nothing but result values ever rides
-        the (slow) device->host direction.  The batched analog of the
-        reference emitting only non-empty windows
+    @partial(jax.jit, static_argnums=(0, 1))
+    def _fire_gather_step(self, layout, leaves, pane_slots, idx):
+        """Fire for a host-known emit set: the window's panes combined for
+        the ``idx`` key rows only (the download scales with rows
+        *emitted*, not key capacity), then ``get_result``.  The emit index
+        is host-derived from the mirror — nothing but result values ever
+        rides the (slow) device->host direction.  The batched analog of
+        the reference emitting only non-empty windows
         (``WindowOperator.emitWindowContents:574``)."""
-        with jax.named_scope("emit_rows_pane_gather"):
-            sel = tuple(jnp.take(jnp.take(l, idx, axis=0), pane_slots,
-                                 axis=1) for l in leaves)
-        with jax.named_scope("pane_combine"):
-            combined = combine_along_axis(sel, self.agg.combine_leaves,
-                                          axis=1)
+        combined = layout.combine_panes_at(leaves, pane_slots, idx,
+                                           self.agg.combine_leaves)
         with jax.named_scope("get_result"):
             return self.agg.get_result(self.spec.unflatten(combined))
 
@@ -2254,8 +2274,8 @@ class WindowAggOperator(StreamOperator):
             idx_p = np.zeros(cap, np.int32)
             idx_p[:n] = idx
             pane_slots = self._pane_slots(panes)
-            result = self._fire_gather_step(self._leaves, pane_slots,
-                                            jnp.asarray(idx_p))
+            result = self._fire_gather_step(self._layout, self._leaves,
+                                            pane_slots, jnp.asarray(idx_p))
             handle = _fetch_enqueue(jax.tree_util.tree_leaves(result))
             treedef = jax.tree_util.tree_structure(result)
         if self._pager is not None:
@@ -2339,26 +2359,21 @@ class WindowAggOperator(StreamOperator):
         ts = np.broadcast_to(np.int64(window.max_timestamp), (n,))
         return [RecordBatch(cols, timestamps=ts)]
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _clear_panes_step(self, leaves, counts, pane_slots):
-        new_leaves = []
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _clear_panes_step(self, layout, leaves, counts, pane_slots):
         with jax.named_scope("pane_clear"):
-            for l, init in zip(leaves, self.spec.leaf_inits):
-                fill = jnp.broadcast_to(
-                    jnp.asarray(init, l.dtype),
-                    (l.shape[0], pane_slots.shape[0]) + l.shape[2:])
-                new_leaves.append(l.at[:, pane_slots].set(fill))
-            return tuple(new_leaves), counts.at[:, pane_slots].set(0)
+            new_leaves = tuple(
+                layout.fill_columns(l, pane_slots, init)
+                for l, init in zip(leaves, self.spec.leaf_inits))
+            return new_leaves, layout.fill_columns(counts, pane_slots, 0)
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _purge_keys_step(self, leaves, counts, key_mask):
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _purge_keys_step(self, layout, leaves, counts, key_mask):
         """Count-trigger purge: reset fired keys' state (FIRE_AND_PURGE)."""
-        new_leaves = []
-        for l, init in zip(leaves, self.spec.leaf_inits):
-            fill = jnp.broadcast_to(jnp.asarray(init, l.dtype), l.shape)
-            m = key_mask.reshape((-1,) + (1,) * (l.ndim - 1))
-            new_leaves.append(jnp.where(m, fill, l))
-        return tuple(new_leaves), jnp.where(key_mask[:, None], 0, counts)
+        new_leaves = tuple(
+            layout.where_rows(l, key_mask, init)
+            for l, init in zip(leaves, self.spec.leaf_inits))
+        return new_leaves, layout.where_rows(counts, key_mask, 0)
 
     # --------------------------------------------------------------- batching
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
@@ -2766,8 +2781,8 @@ class WindowAggOperator(StreamOperator):
         self._last_dispatch_geom = geom
         self._hot_dispatches += 1
         return device_health.guarded_dispatch(
-            lambda: self._update_step(self._leaves, self._counts, flat_p,
-                                      values_p),
+            lambda: self._update_step(self._layout, self._leaves,
+                                      self._counts, flat_p, values_p),
             mb=mb,
             on_oom=(self._forced_page_out if self._pager is not None
                     else None),
@@ -2800,11 +2815,8 @@ class WindowAggOperator(StreamOperator):
             def _salvage_gather():
                 if self._pager is not None:
                     return self._paged_snapshot_rows(n, panes)
-                slots = self._pane_slots(panes)
-                lv = [np.asarray(jnp.take(l, slots, axis=1))[:n]
-                      for l in self._leaves]
-                return np.asarray(jnp.take(self._counts, slots,
-                                           axis=1))[:n], lv
+                *lv, cnt = self._read_columns(panes, n)
+                return cnt, lv
 
             try:
                 # the salvage runs under its own bounded deadline on the
@@ -2961,11 +2973,7 @@ class WindowAggOperator(StreamOperator):
                     raise DeviceQuarantinedError("re-promotion superseded")
                 self._K = self._round_key_capacity(max(n, 1))
                 self._ensure_alloc()
-                self._leaves = tuple(
-                    l.at[:n, slots].set(jnp.asarray(s))
-                    for l, s in zip(self._leaves, leaves))
-                self._counts = self._counts.at[:n, slots].set(
-                    jnp.asarray(counts))
+                self._set_columns(slots, leaves, counts)
                 self._mirror = {}
                 for j, p in enumerate(panes.tolist()):
                     nz = np.flatnonzero(counts[:, j] > 0)
@@ -3105,9 +3113,11 @@ class WindowAggOperator(StreamOperator):
             # clear
             self._device_stale = True
         else:
-            slots = self._pane_slots(expired)
-            self._leaves, self._counts = self._clear_panes_step(
-                self._leaves, self._counts, slots)
+            # pane by pane: one program, however many expire at once
+            for ep in expired:
+                self._leaves, self._counts = self._clear_panes_step(
+                    self._layout, self._leaves, self._counts,
+                    self._pane_slots([ep]))
         for ep in expired:
             self._mirror.pop(ep, None)
             self._vmirror.pop(ep, None)
@@ -3125,7 +3135,8 @@ class WindowAggOperator(StreamOperator):
                 slots_np[:m] = np.asarray(dead, np.int64) % self._P
                 with _x64():
                     self._delta_leaves, self._delta_counts = \
-                        self._delta_clear_step(self._delta_leaves,
+                        self._delta_clear_step(self._delta_layout,
+                                               self._delta_leaves,
                                                self._delta_counts,
                                                jnp.asarray(slots_np))
                 self._delta_panes.difference_update(dead)
@@ -3195,7 +3206,8 @@ class WindowAggOperator(StreamOperator):
                 return out
         panes = np.arange(first, last + 1, dtype=np.int64)
         pane_slots = self._pane_slots(panes)
-        mask, result = self._fire_step(self._leaves, self._counts, pane_slots,
+        mask, result = self._fire_step(self._layout, self._leaves,
+                                       self._counts, pane_slots,
                                        self._k_active())
         return self._emit(mask, result, self.assigner.window_bounds(window_id))
 
@@ -3204,7 +3216,8 @@ class WindowAggOperator(StreamOperator):
             return []
         thr = 1 if force else self.trigger.count_threshold
         ka = self._k_active() or self._K
-        counts0 = self._counts[:ka, 0]
+        counts0 = self._layout.columns(
+            self._counts, jnp.zeros((1,), jnp.int32), rows=ka)[:, 0]
         base = None
         if not force and not self.trigger.purges_on_fire:
             # FIRE-only trigger: state persists, so "n more elements" is
@@ -3227,7 +3240,8 @@ class WindowAggOperator(StreamOperator):
         if not bool(mask.any()):  # cheap pre-check: skip the K-wide assembly
             return []
         pane_slots = jnp.zeros((1,), jnp.int32)
-        m, result = self._fire_step(self._leaves, self._counts, pane_slots,
+        m, result = self._fire_step(self._layout, self._leaves,
+                                    self._counts, pane_slots,
                                     self._k_active())
         mask = mask & m
         out = self._emit(mask, result, self.assigner.window_bounds(0))
@@ -3240,7 +3254,7 @@ class WindowAggOperator(StreamOperator):
         if self.trigger.purges_on_fire and out:
             full_mask = jnp.zeros((self._K,), bool).at[:ka].set(mask)
             self._leaves, self._counts = self._purge_keys_step(
-                self._leaves, self._counts, full_mask)
+                self._layout, self._leaves, self._counts, full_mask)
             fired_np = np.asarray(mask)
             for arr in self._mirror.values():  # whole key rows were purged
                 arr[: fired_np.size][fired_np] = False
@@ -3263,14 +3277,15 @@ class WindowAggOperator(StreamOperator):
         thr = self.trigger.count_threshold
         ka = self._k_active() or self._K
         for p in np.asarray(touched_panes).tolist():
-            slot = int(p) % self._P
-            counts_col = np.asarray(self._counts[:ka, slot])
+            pane_slots = self._pane_slots([p])
+            counts_col = np.asarray(self._layout.columns(
+                self._counts, pane_slots, rows=ka))[:, 0]
             over = counts_col >= thr
             if not over.any():
                 continue
-            pane_slots = jnp.asarray([slot], jnp.int32)
-            m, result = self._fire_step(self._leaves, self._counts,
-                                        pane_slots, self._k_active())
+            m, result = self._fire_step(self._layout, self._leaves,
+                                        self._counts, pane_slots,
+                                        self._k_active())
             mask = jnp.asarray(over) & m
             window = self.assigner.window_bounds(
                 self.assigner.windows_of_pane(int(p))[0])
@@ -3278,7 +3293,8 @@ class WindowAggOperator(StreamOperator):
             if self.trigger.purges_on_fire:
                 full = jnp.zeros((self._K,), bool).at[:ka].set(mask)
                 self._leaves, self._counts = self._purge_cells_step(
-                    self._leaves, self._counts, full, pane_slots)
+                    self._layout, self._leaves, self._counts, full,
+                    pane_slots)
                 fired_np = np.asarray(mask)
                 marr = self._mirror.get(int(p))
                 if marr is not None:
@@ -3316,7 +3332,8 @@ class WindowAggOperator(StreamOperator):
             panes = np.arange(lo, hi + 1, dtype=np.int64)
             slots = self._pane_slots(panes)
             counts_w = np.asarray(
-                jnp.take(self._counts[:ka], slots, axis=1).sum(axis=1),
+                self._layout.columns(self._counts, slots,
+                                     rows=ka).sum(axis=1),
                 dtype=np.int64)
             base = self._count_baselines.get(w)
             if base is None or len(base) < ka:
@@ -3330,8 +3347,9 @@ class WindowAggOperator(StreamOperator):
                     out.extend(self._emit_purging_sliding(w, slots, ka,
                                                           over))
                 else:
-                    m, result = self._fire_step(self._leaves, self._counts,
-                                                slots, self._k_active())
+                    m, result = self._fire_step(self._layout, self._leaves,
+                                                self._counts, slots,
+                                                self._k_active())
                     mask = jnp.asarray(over) & m
                     out.extend(self._emit(mask, result,
                                           self.assigner.window_bounds(w)))
@@ -3349,8 +3367,9 @@ class WindowAggOperator(StreamOperator):
         the combined accumulator, subtract the value baseline (= contents
         already fired-and-purged), emit, advance the baseline for fired
         keys."""
-        _m, combined = self._fire_acc_step(self._leaves, self._counts,
-                                           slots, self._k_active())
+        _m, combined = self._fire_acc_step(self._layout, self._leaves,
+                                           self._counts, slots,
+                                           self._k_active())
         comb_np = [np.asarray(l) for l in combined]
         self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + \
             sum(l.nbytes for l in comb_np)
@@ -3373,19 +3392,19 @@ class WindowAggOperator(StreamOperator):
             self._incr_vb_dirty.add(w)
         return out
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _purge_cells_step(self, leaves, counts, key_mask, pane_slots):
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _purge_cells_step(self, layout, leaves, counts, key_mask,
+                          pane_slots):
         """Reset (key, pane) cells for fired count-trigger windows."""
-        new_leaves = []
-        for l, init in zip(leaves, self.spec.leaf_inits):
-            sel = jnp.take(l, pane_slots, axis=1)
-            fill = jnp.broadcast_to(jnp.asarray(init, l.dtype), sel.shape)
-            m = key_mask.reshape((-1, 1) + (1,) * (l.ndim - 2))
-            new_leaves.append(l.at[:, pane_slots].set(jnp.where(m, fill, sel)))
-        csel = jnp.take(counts, pane_slots, axis=1)
-        new_counts = counts.at[:, pane_slots].set(
-            jnp.where(key_mask[:, None], 0, csel))
-        return tuple(new_leaves), new_counts
+        def purged(a, init):
+            sel = layout.columns(a, pane_slots)
+            m = key_mask.reshape((-1, 1) + (1,) * (sel.ndim - 2))
+            return layout.set_columns(
+                a, pane_slots, jnp.where(m, jnp.asarray(init, a.dtype), sel))
+
+        new_leaves = tuple(purged(l, init)
+                           for l, init in zip(leaves, self.spec.leaf_inits))
+        return new_leaves, purged(counts, 0)
 
     def _emit(self, mask, result, window) -> List[StreamElement]:
         mask_np = np.asarray(mask[: self.key_index.num_keys]) if self.key_index else np.asarray(mask)
@@ -3443,20 +3462,35 @@ class WindowAggOperator(StreamOperator):
         self._active_rows = active
         return rows
 
-    @partial(jax.jit, static_argnums=(0,))
-    def _gather_rows_step(self, leaves, counts, rows, pane_slots):
-        return gather_row_pane_columns(leaves, counts, rows, pane_slots)
+    @partial(jax.jit, static_argnums=(0, 1))
+    def _gather_rows_step(self, layout, leaves, counts, rows, pane_slots):
+        """Page-out gather: the ``rows x pane_slots`` sub-grid —
+        ``(counts[V, m], leaves[V, m, *leaf])``.  Pads may use any
+        in-range id (callers slice them off host-side)."""
+        return (layout.cells(counts, rows, pane_slots),
+                tuple(layout.cells(l, rows, pane_slots) for l in leaves))
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _page_in_step(self, leaves, counts, rows, pane_slots,
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _page_in_step(self, layout, leaves, counts, rows, pane_slots,
                       counts_cols, leaf_cols):
-        return set_row_pane_columns(leaves, counts, rows, pane_slots,
-                                    leaf_cols, counts_cols,
-                                    self.spec.leaf_inits)
+        """Page-in: reset the target rows across the whole ring, then set
+        their ``pane_slots`` columns from the promoted cells (identity
+        where nothing was spilled).  Row pads = K, pane pads = P."""
+        leaves, counts = self._reset_rows_core(layout, leaves, counts, rows)
+        return (tuple(layout.set_cells(l, rows, pane_slots, col)
+                      for l, col in zip(leaves, leaf_cols)),
+                layout.set_cells(counts, rows, pane_slots, counts_cols))
 
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-    def _reset_rows_step(self, leaves, counts, rows):
-        return reset_rows(leaves, counts, rows, self.spec.leaf_inits)
+    def _reset_rows_core(self, layout, leaves, counts, rows):
+        """Reset whole key rows (every pane slot) to the accumulator
+        identity.  Row pads use id K (dropped)."""
+        return (tuple(layout.fill_rows(l, rows, init)
+                      for l, init in zip(leaves, self.spec.leaf_inits)),
+                layout.fill_rows(counts, rows, 0))
+
+    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+    def _reset_rows_step(self, layout, leaves, counts, rows):
+        return self._reset_rows_core(layout, leaves, counts, rows)
 
     def _gather_rows(self, rows: np.ndarray, panes: np.ndarray):
         """Download the ``rows x panes`` cell grid (page-out / snapshot):
@@ -3468,8 +3502,8 @@ class WindowAggOperator(StreamOperator):
         rows_p[:V] = rows
         slots_p = np.zeros(mp, np.int32)
         slots_p[:m] = panes % self._P
-        c, ls = self._gather_rows_step(self._leaves, self._counts,
-                                       jnp.asarray(rows_p),
+        c, ls = self._gather_rows_step(self._layout, self._leaves,
+                                       self._counts, jnp.asarray(rows_p),
                                        jnp.asarray(slots_p))
         counts = np.asarray(c)[:V, :m]
         leaves = [np.asarray(l)[:V, :m] for l in ls]
@@ -3496,7 +3530,7 @@ class WindowAggOperator(StreamOperator):
             arr[:R, :m] = col
             lc.append(jnp.asarray(arr))
         self._leaves, self._counts = self._page_in_step(
-            self._leaves, self._counts, jnp.asarray(rows_p),
+            self._layout, self._leaves, self._counts, jnp.asarray(rows_p),
             jnp.asarray(slots_p), jnp.asarray(cc), tuple(lc))
         self.phase_bytes["h2d_page_in"] = \
             self.phase_bytes.get("h2d_page_in", 0) + cc.nbytes + \
@@ -3507,7 +3541,7 @@ class WindowAggOperator(StreamOperator):
         rows_p = np.full(Rp, self._K, np.int32)
         rows_p[: rows.size] = rows
         self._leaves, self._counts = self._reset_rows_step(
-            self._leaves, self._counts, jnp.asarray(rows_p))
+            self._layout, self._leaves, self._counts, jnp.asarray(rows_p))
 
     def _mirror_bits_rows(self, rows: np.ndarray,
                           panes: np.ndarray) -> np.ndarray:
@@ -3842,11 +3876,8 @@ class WindowAggOperator(StreamOperator):
             # slice-set, so resident row i == global id i after restore
             pager.assign_rows(np.arange(R, dtype=np.int64))
             slots = self._pane_slots(panes)
-            self._leaves = tuple(
-                l.at[:R, slots].set(jnp.asarray(s[:R]))
-                for l, s in zip(self._leaves, leaves_np))
-            self._counts = self._counts.at[:R, slots].set(
-                jnp.asarray(counts_np[:R]))
+            self._set_columns(slots, [s[:R] for s in leaves_np],
+                              counts_np[:R])
             for j, p in enumerate(panes.tolist()):
                 nz = np.flatnonzero(counts_np[:R, j] > 0)
                 if nz.size:
@@ -3937,16 +3968,8 @@ class WindowAggOperator(StreamOperator):
             else:
                 # snapshot only live keys × live panes (device→host transfer)
                 with self._phase("snapshot"):
-                    slots = self._pane_slots(panes)
-                    with self._phase("snapshot_d2h"):
-                        # array by array: launch the read, wait for the
-                        # device (every step queued ahead of it first),
-                        # copy to the host
-                        read = [np.asarray(_snapshot_read_step(a, slots))
-                                for a in (*self._leaves, self._counts)]
-                    with self._phase("snapshot_assemble"):
-                        snap["leaves"] = [a[:n] for a in read[:-1]]
-                        snap["counts"] = read[-1][:n]
+                    *snap["leaves"], snap["counts"] = \
+                        self._read_columns(panes, n)
                 nbytes = snap["counts"].nbytes + \
                     sum(l.nbytes for l in snap["leaves"])
                 self.phase_bytes["d2h"] = \
@@ -4048,11 +4071,7 @@ class WindowAggOperator(StreamOperator):
             else:
                 self._ensure_alloc()
                 slots = self._pane_slots(panes)
-                self._leaves = tuple(
-                    l.at[:n, slots].set(jnp.asarray(s))
-                    for l, s in zip(self._leaves, leaves))
-                self._counts = self._counts.at[:n, slots].set(
-                    jnp.asarray(snap["counts"]))
+                self._set_columns(slots, leaves, snap["counts"])
             # rebuild the host emit mirror from the snapshot's counts
             self._mirror = {}
             counts_np = np.asarray(snap["counts"])

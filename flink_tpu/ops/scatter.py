@@ -17,8 +17,15 @@ micro-batch over ``[num_slots, ...]`` dense state:
   pairs), and scatter each segment's total with ``.at[].set`` — indices are
   unique after segmentation, so arbitrary monoids stay race-free.
 
-Out-of-range slot ids (== num_slots) are dropped by XLA scatter semantics —
+Out-of-range slot ids (>= num_slots) are dropped by XLA scatter semantics —
 padding rows use that to make batch shapes static (no recompiles per batch).
+
+Every function here folds into FLAT state: one leading axis of cells.  How
+the window operator's K x P pane cells map onto that axis — the pane-major
+ring it holds on one chip, whose arrays are their own flat view, or the
+key-major ``[K, P]`` grid a mesh shards — is ``ops/pane_layout.py``'s
+business; its ``fold`` hands ``scatter_fold_counts`` the flat arrays and the
+cell ids in that layout.
 """
 
 from __future__ import annotations
@@ -60,15 +67,22 @@ def scatter_fast(state_leaves, slot_ids, lifted_leaves, kinds: Sequence[str]):
 
 def scatter_fold_counts(flat_leaves, flat_counts, slot_ids, lifted_leaves,
                         kinds: Sequence[str]):
-    """One batch's fold into FLAT ``[K*P]`` keyed state: the value leaves
-    scatter-combine by kind and the element counts scatter-add ones — the
-    shared body of the per-batch update step, the device-probe delta fold,
-    and the fused scan megastep's per-step fold (window_agg), so the three
-    lanes cannot drift arithmetically.  Out-of-range ids (padding, probe
-    misses) drop."""
-    new_leaves = scatter_fast(flat_leaves, slot_ids, lifted_leaves, kinds)
-    ones = jnp.ones(slot_ids.shape, jnp.int32)
-    return new_leaves, flat_counts.at[slot_ids].add(ones, mode="drop")
+    """One batch's fold into FLAT keyed state: the value leaves
+    scatter-combine by kind and the element counts scatter-add ones.  The
+    body of every lane's fold — ``pane_layout``'s ``fold`` calls it for the
+    per-batch ``_update_step``, the device-probe delta fold and the fused
+    scan megastep's per-step fold (window_agg), so the lanes cannot drift
+    arithmetically.  Out-of-range ids (padding, probe misses) drop.  Each
+    scatter carries a named scope: its name in a device trace."""
+    new_leaves = ()
+    for i, kind in enumerate(kinds):
+        with jax.named_scope(f"leaf{i}_scatter_{kind}"):
+            new_leaves += scatter_fast(flat_leaves[i:i + 1], slot_ids,
+                                       lifted_leaves[i:i + 1], (kind,))
+    with jax.named_scope("count_fold"):
+        # ones made device-side: the upload stays ids + values only
+        ones = jnp.ones(slot_ids.shape, jnp.int32)
+        return new_leaves, flat_counts.at[slot_ids].add(ones, mode="drop")
 
 
 def segment_fold(slot_ids, lifted_leaves, combine_leaves: Callable,
@@ -132,45 +146,6 @@ def scatter_generic(state_leaves, slot_ids, lifted_leaves,
         l.at[write_ids].set(m.astype(l.dtype), mode="drop")
         for l, m in zip(state_leaves, merged)
     )
-
-
-def gather_row_pane_columns(state_leaves, counts, rows, pane_slots):
-    """Page-out gather: the ``rows x pane_slots`` sub-grid of ``[K, P, ...]``
-    keyed state — ``(counts[V, m], leaves[V, m, *leaf])``.  Row/pane pads
-    may use any in-range id (callers slice the pads off host-side);
-    ``jnp.take`` clips out-of-range pads."""
-    sel_counts = jnp.take(jnp.take(counts, rows, axis=0), pane_slots, axis=1)
-    sel_leaves = tuple(
-        jnp.take(jnp.take(l, rows, axis=0), pane_slots, axis=1)
-        for l in state_leaves)
-    return sel_counts, sel_leaves
-
-
-def reset_rows(state_leaves, counts, rows, leaf_inits):
-    """Reset whole key rows (every pane slot) to the accumulator identity.
-    Row pads use id K (out of range, dropped)."""
-    new_leaves = tuple(
-        l.at[rows].set(
-            jnp.broadcast_to(jnp.asarray(init, l.dtype),
-                             (rows.shape[0],) + l.shape[1:]),
-            mode="drop")
-        for l, init in zip(state_leaves, leaf_inits))
-    return new_leaves, counts.at[rows].set(0, mode="drop")
-
-
-def set_row_pane_columns(state_leaves, counts, rows, pane_slots,
-                         leaf_cols, counts_cols, leaf_inits):
-    """Page-in: reset the target rows across the whole ring, then set their
-    ``pane_slots`` columns from the promoted cells (identity where nothing
-    was spilled).  Row pads = K, pane pads = P (both dropped)."""
-    new_leaves, new_counts = reset_rows(state_leaves, counts, rows,
-                                        leaf_inits)
-    new_leaves = tuple(
-        l.at[rows[:, None], pane_slots[None, :]].set(col, mode="drop")
-        for l, col in zip(new_leaves, leaf_cols))
-    new_counts = new_counts.at[rows[:, None], pane_slots[None, :]].set(
-        counts_cols, mode="drop")
-    return new_leaves, new_counts
 
 
 def combine_along_axis(leaves, combine_leaves: Callable, axis: int, keepdims: bool = False):

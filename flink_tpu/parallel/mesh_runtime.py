@@ -44,7 +44,7 @@ from flink_tpu.operators.session_window import SessionWindowOperator
 from flink_tpu.operators.window_agg import (WindowAggOperator, _next_pow2,
                                             _x64)
 from flink_tpu.runtime.device_health import DeviceQuarantinedError
-from flink_tpu.ops.scatter import scatter_fast, scatter_generic
+from flink_tpu.ops.pane_layout import KeyGrid
 from flink_tpu.parallel.mesh import KG_AXIS, make_mesh, state_sharding
 
 
@@ -205,20 +205,10 @@ class MeshWindowAggOperator(WindowAggOperator):
             lflat = jnp.where(ok, local * Pn + rx_panes, KD * Pn)
             lifted = tuple(jax.tree_util.tree_leaves(
                 self.agg.lift(self._values_tree(rx_vals))))
-            flat_leaves = tuple(
-                l.reshape((KD * Pn,) + l.shape[2:]) for l in leaves)
-            if self.kinds is not None:
-                new_flat = scatter_fast(flat_leaves, lflat, lifted,
-                                        self.kinds)
-            else:
-                new_flat = scatter_generic(flat_leaves, lflat, lifted,
-                                           self.agg.combine_leaves, KD * Pn)
-            new_leaves = tuple(
-                l.reshape((KD, Pn) + l.shape[1:]) for l in new_flat)
-            ones = jnp.where(ok, 1, 0).astype(jnp.int32)
-            new_counts = counts.reshape(KD * Pn).at[lflat].add(
-                ones, mode="drop").reshape(KD, Pn)
-            return new_leaves, new_counts
+            # the single-chip fold on this device's block (rows that are
+            # not ``ok`` carry the dropped id KD * Pn)
+            return KeyGrid(KD, Pn).fold(leaves, counts, lflat, lifted,
+                                        self.kinds, self.agg.combine_leaves)
 
         nv = len(batch) - 3
         state_spec = P(KG_AXIS)
@@ -269,13 +259,8 @@ class MeshWindowAggOperator(WindowAggOperator):
             lflat = jnp.where(ok, local * Pn + rx_panes, KD * Pn)
             lifted = tuple(jax.tree_util.tree_leaves(
                 self.agg.lift(self._values_tree(rx_vals))))
-            dflat = tuple(l.reshape(KD * Pn) for l in dleaves)
-            new_flat = scatter_fast(dflat, lflat, lifted, self.kinds)
-            new_leaves = tuple(l.reshape(KD, Pn) for l in new_flat)
-            ones = jnp.where(ok, 1, 0).astype(jnp.int32)
-            new_counts = dcounts.reshape(KD * Pn).at[lflat].add(
-                ones, mode="drop").reshape(KD, Pn)
-            return new_leaves, new_counts
+            return KeyGrid(KD, Pn).fold(dleaves, dcounts, lflat, lifted,
+                                        self.kinds)
 
         nv = len(batch) - 3
         state_spec = P(KG_AXIS)
@@ -469,7 +454,7 @@ class MeshWindowAggOperator(WindowAggOperator):
             self._delta_leaves, self._delta_counts = self._mesh_delta_step(
                 (self._delta_leaves, self._delta_counts), batch, cap)
 
-    def _update_step(self, leaves, counts, flat_ids, values):  # type: ignore[override]
+    def _update_step(self, layout, leaves, counts, flat_ids, values):  # type: ignore[override]
         """Intercept the base class's device dispatch (the rest of the host
         front — key probe, lateness, pane bookkeeping, growth — is reused
         verbatim from ``WindowAggOperator.process_batch``): decompose the
