@@ -313,9 +313,10 @@ def phase_cluster(ev: Events, **agg_options) -> None:
 
 
 def phase_mesh(ev: Events, n_devices: int, one_chip) -> None:
-    """The default-lane job with ``env.set_mesh(n_devices=N)``: one process
-    drives all N chips; rows equal the one-chip run."""
-    sink, op = run_local(ev, mesh_devices=n_devices)
+    """The job with ``env.set_mesh(n_devices=N)`` on the mesh operator's
+    host tier, asked for by name (a mesh job's own pick is the device tier,
+    below): one process drives all N chips; rows equal the one-chip run."""
+    sink, op = run_local(ev, mesh_devices=n_devices, emit_tier="host")
     report_lanes(op)
     check_healthy([op])
     check_state_placement(op, n_devices)
@@ -328,17 +329,21 @@ def phase_mesh(ev: Events, n_devices: int, one_chip) -> None:
 
 
 def phase_mesh_scatter(ev: Events, n_devices: int) -> None:
-    """The mesh job with the sync cadence pinned to scatter, so every batch
-    rides the ``all_to_all`` exchange into the sharded device state, which
-    is then compared with the host mirror."""
-    from flink_tpu.utils import transport
-
-    transport.reset(verdict=False)
-    sink, op = run_local(ev, mesh_devices=n_devices, drain=False)
+    """The mesh job on the device tier (``emit_tier="device"``, which is
+    also what a mesh job resolves to when nothing is asked for): every
+    batch rides the ``all_to_all`` exchange into the sharded device state,
+    and every window fires from it, so the rows check the chips' fold."""
+    sink, op = run_local(ev, mesh_devices=n_devices, emit_tier="device")
     report_lanes(op)
     check_healthy([op])
     check_state_placement(op, n_devices)
-    check_device_replica(ev, sink, op)
+    check(op.emit_tier == "device" and op.device_sync_mode == "scatter",
+          f"lanes resolved to {op.emit_tier} / {op.device_sync_mode}")
+    check(op.fused_stats()["hot_dispatches"] > 1,
+          "the operator never dispatched to the device")
+    check(op.phase_bytes.get("exchange_live", 0) > 0,
+          "no record crossed the exchange")
+    ev.check_rows(sink)
 
 
 def run_phases(args) -> dict:

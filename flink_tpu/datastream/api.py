@@ -775,7 +775,9 @@ class WindowedStream:
         """``paging``: a :class:`flink_tpu.state.paging.PagingConfig` caps
         the operator's resident key capacity — cold keys page out to the
         spill tier (state larger than HBM).  ``emit_tier`` overrides the
-        operator's auto tier pick ("host"/"device").  ``pipeline_depth`` >
+        operator's auto tier pick ("host"/"device"), with a mesh too: there
+        "auto" is "device", the sharded state on the chips kept current
+        through the ``all_to_all`` exchange every batch.  ``pipeline_depth`` >
         0 runs the operator's hot stage (probe/mirror + device dispatch)
         as a bounded software pipeline overlapping the task driver;
         ``native_shards`` partitions the native probe across cores (0 =
@@ -797,11 +799,13 @@ class WindowedStream:
         late_tag = getattr(self, "_late_tag", None)
         ev = getattr(self, "_evictor", None)
         if (paging is not None or emit_tier is not None) and (
-                ev is not None or keyed.env.mesh is not None
-                or not hasattr(assigner, "pane_of")):
-            raise ValueError("paging/emit_tier apply to the (unsharded) "
-                             "pane-ring window operator — not evictors, "
-                             "session windows or mesh-sharded state")
+                ev is not None or not hasattr(assigner, "pane_of")):
+            raise ValueError("paging/emit_tier apply to the pane-ring "
+                             "window operator — not evictors or session "
+                             "windows")
+        if paging is not None and keyed.env.mesh is not None:
+            raise ValueError("paging applies to unsharded state — not to "
+                             "a mesh job")
         if queryable is not None and (ev is not None
                                       or not hasattr(assigner, "pane_of")):
             raise ValueError("queryable= is served by the pane-ring window "
@@ -886,6 +890,8 @@ class WindowedStream:
                     allowed_lateness_ms=lateness, trigger=trigger,
                     output_column=output_column, name=name,
                     late_output_tag=late_tag)
+                if emit_tier is not None:
+                    kwargs["emit_tier"] = emit_tier
                 if mesh is not None:
                     from flink_tpu.parallel.mesh_runtime import (
                         MeshWindowAggOperator)
@@ -894,8 +900,6 @@ class WindowedStream:
                                                  queryable=queryable,
                                                  superbatch=superbatch,
                                                  **kwargs)
-                if emit_tier is not None:
-                    kwargs["emit_tier"] = emit_tier
                 return WindowAggOperator(paging=paging,
                                          pipeline_depth=pipeline_depth,
                                          native_shards=native_shards,

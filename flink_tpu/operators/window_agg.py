@@ -487,6 +487,10 @@ class WindowAggOperator(StreamOperator):
         #   declares numpy twins (functions.py ``supports_host_emit``), the
         #   state is unsharded, fires are time-triggered, and the backend is
         #   an accelerator (on CPU there is no transfer cost to dodge).
+        #   Sharded state is "device" under "auto" on every backend: a mesh
+        #   is asked for to put the state on its chips, and the host tier's
+        #   "auto" sync cadence may settle on deferred, which leaves them
+        #   untouched; the mesh operator's host tier stays there by name.
         host_capable = (
             agg.supports_host_emit()
             and (sharding is None or self._SHARDED_HOST_TIER)
@@ -495,8 +499,8 @@ class WindowAggOperator(StreamOperator):
             and not isinstance(assigner, GlobalWindows))
         if emit_tier == "auto":
             backend = jax.default_backend()
-            emit_tier = "host" if (host_capable and backend != "cpu") \
-                else "device"
+            emit_tier = "host" if (host_capable and sharding is None
+                                   and backend != "cpu") else "device"
         if emit_tier == "host" and not host_capable:
             raise ValueError(
                 "emit_tier='host' requires an unsharded, time-triggered "
@@ -3204,12 +3208,17 @@ class WindowAggOperator(StreamOperator):
                     # upload and run the same pane combine
                     out = out + self._fire_window_spilled(window_id, panes)
                 return out
-        panes = np.arange(first, last + 1, dtype=np.int64)
-        pane_slots = self._pane_slots(panes)
-        mask, result = self._fire_step(self._layout, self._leaves,
-                                       self._counts, pane_slots,
-                                       self._k_active())
-        return self._emit(mask, result, self.assigner.window_bounds(window_id))
+        # sharded device tier: no host mirror names the emit set, so the
+        # dense step combines every key row's panes where they live and
+        # the mask comes back with the values
+        with self._phase("fire"):
+            with self._phase("fire_dispatch"):
+                panes = np.arange(first, last + 1, dtype=np.int64)
+                mask, result = self._fire_step(
+                    self._layout, self._leaves, self._counts,
+                    self._pane_slots(panes), self._k_active())
+            return self._emit(mask, result,
+                              self.assigner.window_bounds(window_id))
 
     def _fire_by_count(self, force: bool = False) -> List[StreamElement]:
         if self._leaves is None:
@@ -3407,17 +3416,29 @@ class WindowAggOperator(StreamOperator):
         return new_leaves, purged(counts, 0)
 
     def _emit(self, mask, result, window) -> List[StreamElement]:
-        mask_np = np.asarray(mask[: self.key_index.num_keys]) if self.key_index else np.asarray(mask)
+        """Rows of a dense fire: the key rows ``mask`` marks, with their
+        ``result`` values.  Both are whole-capacity device arrays (one
+        block a device where the state is sharded)."""
+        with self._phase("fire_d2h"):
+            # blocks until the device has run every step queued ahead of
+            # the fire step, the step, and the mask's copy to the host
+            mask_np = np.asarray(mask)
+        if self.key_index is not None:
+            mask_np = mask_np[: self.key_index.num_keys]
         idx = np.nonzero(mask_np)[0]
         if idx.size == 0:
             return []
-        res_np = jax.tree_util.tree_map(lambda a: np.asarray(a)[idx], result)
-        nbytes = mask_np.nbytes + sum(a.nbytes for a in
-                                      jax.tree_util.tree_leaves(result))
+        leaves, treedef = jax.tree_util.tree_flatten(result)
+        with self._phase("fire_d2h"):
+            fetched = _fetch_collect(_fetch_enqueue(leaves))
+        nbytes = mask_np.nbytes + sum(f.nbytes for f in fetched)
         self.phase_bytes["d2h"] = self.phase_bytes.get("d2h", 0) + nbytes
         self.phase_bytes["d2h_fire"] = \
             self.phase_bytes.get("d2h_fire", 0) + nbytes
-        return self._rows_for(idx, res_np, window)
+        with self._phase("fire_assemble"):
+            picked = jax.tree_util.tree_unflatten(
+                treedef, [f[idx] for f in fetched])
+            return self._rows_for(idx, picked, window)
 
     # ------------------------------------------------------------- paging
     def _live_panes(self) -> np.ndarray:

@@ -118,6 +118,8 @@ class MeshWindowAggOperator(WindowAggOperator):
         self._row_sharding = NamedSharding(mesh, P(KG_AXIS))
         #: per-shard probe timing buffer (phase_shard_ns feed)
         self._shard_ns_buf = np.zeros(self.n_shards, np.int64)
+        #: sticky high-water of the exchange's bucket capacity
+        self._exchange_cap_hw = 0
 
     # ---------------------------------------------------------------- layout
     def shard_layout(self):
@@ -167,14 +169,13 @@ class MeshWindowAggOperator(WindowAggOperator):
         return snap
 
     # ------------------------------------------------------------- device op
-    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
-    def _mesh_update_step(self, leaves_counts, batch, cap: int):
-        """One sharded micro-batch: per-device bucket by destination →
-        ``all_to_all`` over ICI → scatter-combine into the local state
-        block.  ``batch`` = (dest, slots, pane_slots, values), each row-split
-        over the mesh; ``cap`` = per-(src, dest) bucket capacity (host-known
-        upper bound, so the exchange can never overflow)."""
-        leaves, counts = leaves_counts
+    def _sharded_fold(self, state, batch, cap: int, combine_leaves):
+        """The body of both mesh steps: per-device bucket by destination →
+        ``all_to_all`` over ICI → scatter-combine into the local block of
+        ``state`` = (leaves, counts).  The named scopes are each stage's
+        name in the program's HLO (every operation's ``op_name``), so a
+        device trace's operations can be told apart by stage."""
+        leaves, counts = state
         D = self.n_shards
         K, Pn = counts.shape
         KD = K // D
@@ -186,39 +187,48 @@ class MeshWindowAggOperator(WindowAggOperator):
             # ---- bucket local rows by destination shard ([D, cap]); the
             # STABLE plan keeps each key's records in batch order through
             # the exchange (bit-identical per-cell accumulation at any D)
-            order, flat, _valid = bucket_plan(dest, D, cap)
-            bucket = lambda a, fill: bucket_rows(a, order, flat, D,  # noqa: E731
-                                                 cap, fill)
-            b_slots = bucket(slots, K)           # K = invalid sentinel
-            b_panes = bucket(pane_slots, 0)
-            b_vals = [bucket(v, 0) for v in values]
-            # ---- the keyed exchange: one collective over ICI
-            rx_slots = all_to_all_rows(b_slots).reshape(D * cap)
-            rx_panes = all_to_all_rows(b_panes).reshape(D * cap)
-            rx_vals = tuple(all_to_all_rows(v).reshape((D * cap,)
-                                                       + v.shape[2:])
-                            for v in b_vals)
-            # ---- local scatter-combine (this device's key-slot block)
-            lo = jax.lax.axis_index(KG_AXIS).astype(jnp.int32) * KD
-            local = rx_slots - lo
-            ok = (rx_slots < K) & (local >= 0) & (local < KD)
-            lflat = jnp.where(ok, local * Pn + rx_panes, KD * Pn)
-            lifted = tuple(jax.tree_util.tree_leaves(
-                self.agg.lift(self._values_tree(rx_vals))))
-            # the single-chip fold on this device's block (rows that are
-            # not ``ok`` carry the dropped id KD * Pn)
-            return KeyGrid(KD, Pn).fold(leaves, counts, lflat, lifted,
-                                        self.kinds, self.agg.combine_leaves)
+            with jax.named_scope("exchange_bucket"):
+                order, flat, _valid = bucket_plan(dest, D, cap)
+                bucket = lambda a, fill: bucket_rows(a, order, flat, D,  # noqa: E731
+                                                     cap, fill)
+                b_slots = bucket(slots, K)           # K = invalid sentinel
+                b_panes = bucket(pane_slots, 0)
+                b_vals = [bucket(v, 0) for v in values]
+            # ---- the keyed exchange: one collective over ICI per array
+            with jax.named_scope("exchange_all_to_all"):
+                rx_slots = all_to_all_rows(b_slots).reshape(D * cap)
+                rx_panes = all_to_all_rows(b_panes).reshape(D * cap)
+                rx_vals = tuple(all_to_all_rows(v).reshape((D * cap,)
+                                                           + v.shape[2:])
+                                for v in b_vals)
+            # ---- local scatter-combine (this device's key-slot block):
+            # the single-chip fold; rows that are not ``ok`` carry the
+            # dropped id KD * Pn
+            with jax.named_scope("shard_fold"):
+                lo = jax.lax.axis_index(KG_AXIS).astype(jnp.int32) * KD
+                local = rx_slots - lo
+                ok = (rx_slots < K) & (local >= 0) & (local < KD)
+                lflat = jnp.where(ok, local * Pn + rx_panes, KD * Pn)
+                lifted = tuple(jax.tree_util.tree_leaves(
+                    self.agg.lift(self._values_tree(rx_vals))))
+                return KeyGrid(KD, Pn).fold(leaves, counts, lflat, lifted,
+                                            self.kinds, combine_leaves)
 
-        nv = len(batch) - 3
-        state_spec = P(KG_AXIS)
-        in_specs = ((state_spec,) * len(leaves), state_spec,
-                    P(KG_AXIS), P(KG_AXIS), P(KG_AXIS)) \
-            + (P(KG_AXIS),) * nv
-        out_specs = ((state_spec,) * len(leaves), state_spec)
-        fn = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+        rows = P(KG_AXIS)
+        state_specs = ((rows,) * len(leaves), rows)
+        fn = jax.shard_map(step, mesh=self.mesh,
+                           in_specs=state_specs + (rows,) * len(batch),
+                           out_specs=state_specs, check_vma=False)
         return fn(leaves, counts, *batch)
+
+    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
+    def _mesh_update_step(self, leaves_counts, batch, cap: int):
+        """One sharded micro-batch into the state.  ``batch`` = (dest,
+        slots, pane_slots, *values), each row-split over the mesh; ``cap``
+        = per-(src, dest) bucket capacity (host-known upper bound, so the
+        exchange can never overflow)."""
+        return self._sharded_fold(leaves_counts, batch, cap,
+                                  self.agg.combine_leaves)
 
     def _values_tree(self, flat_values):
         """Rebuild the user value tree from the flat leaves that rode the
@@ -228,49 +238,11 @@ class MeshWindowAggOperator(WindowAggOperator):
 
     @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
     def _mesh_delta_step(self, dleaves_counts, batch, cap: int):
-        """Device-probe DELTA fold over the mesh: the same bucket →
-        ``all_to_all`` → local scatter pipeline as ``_mesh_update_step``,
-        but into the sharded delta ring (mirror dtypes — warm-row
-        contributions carry the host mirror's f64/i64 precision and fold
-        into it later via ``wm_apply_delta``)."""
-        dleaves, dcounts = dleaves_counts
-        D = self.n_shards
-        K, Pn = dcounts.shape
-        KD = K // D
-
-        def step(dleaves, dcounts, dest, slots, pane_slots, *values):
-            from flink_tpu.parallel.exchange import (all_to_all_rows,
-                                                     bucket_plan,
-                                                     bucket_rows)
-            order, flat, _valid = bucket_plan(dest, D, cap)
-            bucket = lambda a, fill: bucket_rows(a, order, flat, D,  # noqa: E731
-                                                 cap, fill)
-            b_slots = bucket(slots, K)
-            b_panes = bucket(pane_slots, 0)
-            b_vals = [bucket(v, 0) for v in values]
-            rx_slots = all_to_all_rows(b_slots).reshape(D * cap)
-            rx_panes = all_to_all_rows(b_panes).reshape(D * cap)
-            rx_vals = tuple(all_to_all_rows(v).reshape((D * cap,)
-                                                       + v.shape[2:])
-                            for v in b_vals)
-            lo = jax.lax.axis_index(KG_AXIS).astype(jnp.int32) * KD
-            local = rx_slots - lo
-            ok = (rx_slots < K) & (local >= 0) & (local < KD)
-            lflat = jnp.where(ok, local * Pn + rx_panes, KD * Pn)
-            lifted = tuple(jax.tree_util.tree_leaves(
-                self.agg.lift(self._values_tree(rx_vals))))
-            return KeyGrid(KD, Pn).fold(dleaves, dcounts, lflat, lifted,
-                                        self.kinds)
-
-        nv = len(batch) - 3
-        state_spec = P(KG_AXIS)
-        in_specs = ((state_spec,) * len(dleaves), state_spec,
-                    P(KG_AXIS), P(KG_AXIS), P(KG_AXIS)) \
-            + (P(KG_AXIS),) * nv
-        out_specs = ((state_spec,) * len(dleaves), state_spec)
-        fn = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-        return fn(dleaves, dcounts, *batch)
+        """Device-probe DELTA fold over the mesh: the same pipeline as
+        ``_mesh_update_step``, but into the sharded delta ring (mirror
+        dtypes — warm-row contributions carry the host mirror's f64/i64
+        precision and fold into it later via ``wm_apply_delta``)."""
+        return self._sharded_fold(dleaves_counts, batch, cap, None)
 
     @partial(jax.jit, static_argnums=(0,))
     def _mesh_probe_step(self, tab, b, key_lo, key_hi, start):
@@ -402,39 +374,52 @@ class MeshWindowAggOperator(WindowAggOperator):
         """Shared exchange routing for the state and delta folds: pad rows
         to the mesh, compute destination shards, pick the STICKY bucket
         capacity, and device_put the row-split batch.  Returns
-        ``(batch, cap)`` for a ``_mesh_*_step`` dispatch."""
-        D = self.n_shards
-        K = self._K
-        KD = K // D
-        # pad rows to a multiple of D with invalid-slot sentinels (quantized
-        # for a bounded compile count, then re-rounded: D may not be pow2)
-        Bp = -(-_quantize(-(-B // D) * D, D) // D) * D
+        ``(batch, cap)`` for a ``_mesh_*_step`` dispatch.  Timed as phase
+        ``exchange_route`` (inside ``device_dispatch`` on the state fold's
+        path); ``phase_bytes`` counts what the exchange then moves."""
+        with self._phase("exchange_route"):
+            D = self.n_shards
+            K = self._K
+            KD = K // D
+            # pad rows to a multiple of D with invalid-slot sentinels
+            # (quantized for a bounded compile count, then re-rounded: D
+            # may not be pow2)
+            Bp = -(-_quantize(-(-B // D) * D, D) // D) * D
 
-        def pad(a, fill, dtype):
-            out = np.full((Bp,) + a.shape[1:], fill, dtype)
-            out[:B] = a[:B]
-            return out
+            def pad(a, fill, dtype):
+                out = np.full((Bp,) + a.shape[1:], fill, dtype)
+                out[:B] = a[:B]
+                return out
 
-        slots_p = pad(slots.astype(np.int32), K, np.int32)
-        panes_p = pad(panes.astype(np.int32), 0, np.int32)
-        dest = np.minimum(slots_p.astype(np.int64) // KD, D - 1).astype(
-            np.int32)
-        dest[B:] = np.arange(Bp - B) % D  # spread pad rows evenly
-        # host-known capacity: max rows any (src block, dest) pair sends.
-        # STICKY high-water (the credit-capacity-only-grows rule of
-        # ResizingExchange): batch-to-batch skew wobble must not recompile
-        # the step — steady state is exactly one compile per (mesh, K,
-        # batch geometry), which the tier-1 recompile smoke asserts
-        src = np.repeat(np.arange(D), Bp // D)
-        per_pair = np.bincount(src * D + dest, minlength=D * D)
-        cap = _quantize(int(per_pair.max()))
-        cap = self._exchange_cap_hw = max(
-            getattr(self, "_exchange_cap_hw", 0), cap)
-        vleaves, self._values_treedef = jax.tree_util.tree_flatten(values)
-        vpad = [jax.device_put(pad(np.asarray(v), 0, np.asarray(v).dtype),
-                               self._row_sharding) for v in vleaves]
-        put = lambda a: jax.device_put(a, self._row_sharding)  # noqa: E731
-        return (put(dest), put(slots_p), put(panes_p), *vpad), cap
+            slots_p = pad(slots.astype(np.int32), K, np.int32)
+            panes_p = pad(panes.astype(np.int32), 0, np.int32)
+            dest = np.minimum(slots_p.astype(np.int64) // KD, D - 1).astype(
+                np.int32)
+            dest[B:] = np.arange(Bp - B) % D  # spread pad rows evenly
+            # host-known capacity: max rows any (src block, dest) pair
+            # sends.  STICKY high-water (the credit-capacity-only-grows
+            # rule of ResizingExchange): batch-to-batch skew wobble must
+            # not recompile the step — steady state is exactly one compile
+            # per (mesh, K, batch geometry), which the tier-1 recompile
+            # smoke asserts
+            src = np.repeat(np.arange(D), Bp // D)
+            per_pair = np.bincount(src * D + dest, minlength=D * D)
+            cap = _quantize(int(per_pair.max()))
+            cap = self._exchange_cap_hw = max(self._exchange_cap_hw, cap)
+            vleaves, self._values_treedef = jax.tree_util.tree_flatten(values)
+            vleaves = [np.asarray(v) for v in vleaves]
+            put = lambda a: jax.device_put(a, self._row_sharding)  # noqa: E731
+            batch = (put(dest), put(slots_p), put(panes_p),
+                     *(put(pad(v, 0, v.dtype)) for v in vleaves))
+        # bytes through the all_to_all, padding included (every device
+        # sends D buckets of cap rows), and of the rows that carry a record
+        row_bytes = 8 + sum(v.dtype.itemsize * int(np.prod(v.shape[1:]))
+                            for v in vleaves)
+        for key, rows in (("exchange_sent", D * D * cap),
+                          ("exchange_live", int((slots_p < K).sum()))):
+            self.phase_bytes[key] = \
+                self.phase_bytes.get(key, 0) + rows * row_bytes
+        return batch, cap
 
     def _apply_update(self, values, B: int,
                       slots: np.ndarray, panes: np.ndarray) -> None:
