@@ -22,8 +22,6 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
-import numpy as np
-
 from flink_tpu.core import keygroups
 from flink_tpu.core.batch import (CheckpointBarrier, EndOfInput, RecordBatch,
                                   StreamElement)
@@ -207,6 +205,9 @@ class OutputDispatcher:
         self.max_parallelism = max_parallelism
         self.key_column = key_column  # hash edges key on this column
         self._rr = subtask_index  # stagger round-robin starts across producers
+        #: records whose key groups this dispatcher derived (a hash edge
+        #: with several targets is their reader)
+        self.key_groups_computed = 0
 
     def emit(self, el: StreamElement) -> None:
         n = len(self.channels)
@@ -226,10 +227,10 @@ class OutputDispatcher:
             return
         if len(el) == 0:
             return
-        if n == 1:
-            self.channels[0].put(el)
-        elif self.partitioning == "hash":
+        if self.partitioning == "hash":
             self._emit_hash(el)
+        elif n == 1:
+            self.channels[0].put(el)
         elif self.partitioning == "broadcast":
             for ch in self.channels:
                 ch.put(el)
@@ -243,30 +244,34 @@ class OutputDispatcher:
                 f"forward edge cannot fan out to {n} channels")
 
     def _emit_hash(self, batch: RecordBatch) -> None:
-        # the producer's own work (the key-group hash here, one `select`
-        # per target below) in spans of its own, apart from the puts, which
-        # may block on credit.  Each target's part is put as soon as it is
-        # cut: a consumer must not wait for the parts of the others.
+        n = len(self.channels)
+        batch = keygroups.keyed_for_edge(batch, self.key_column,
+                                         self.max_parallelism)
+        if n == 1:   # nothing reads the key groups here: none are derived
+            self.channels[0].put(batch)
+            return
+        # the producer's own work (the key groups and the rows of every
+        # target here, one gather per column and target below) in spans of
+        # its own, apart from the puts, which may block on credit.  Each
+        # target's part is put as soon as it is cut: a consumer must not
+        # wait for the parts of the others.
         with tracing.span("exchange.partition", cat="exchange",
                           records=len(batch)):
+            carried = batch.key_groups_derived
             kg = batch.key_groups
-            if kg is None and self.key_column is not None:
-                # the keying operator lives at the consumer chain head; the
-                # producer-side partitioner derives key groups from the key
-                # column itself (KeyGroupStreamPartitioner's key selector)
-                keys = np.asarray(batch.column(self.key_column))
-                kg = keygroups.assign_to_key_group(
-                    keygroups.hash_keys(keys), self.max_parallelism)
             if kg is None:
                 raise ValueError("hash edge requires key_groups on the "
                                  "batch (key_by upstream)")
-            n = len(self.channels)
-            # KeyGroupRangeAssignment.computeOperatorIndexForKeyGroup
-            target = (np.asarray(kg, np.int64) * n) // self.max_parallelism
+            if not carried:
+                self.key_groups_computed += len(batch)
+            # one stable sort lists the rows of every target, in row order
+            order, bounds = keygroups.rows_by_target(
+                kg, self.max_parallelism, n)
         for t in range(n):
+            lo, hi = bounds[t], bounds[t + 1]
+            if hi == lo:
+                continue
             with tracing.span("exchange.partition", cat="exchange",
                               target=t):
-                sel = target == t
-                part = batch.select(sel) if sel.any() else None
-            if part is not None:
-                self.channels[t].put(part)
+                part = batch.take(order[lo:hi])
+            self.channels[t].put(part)

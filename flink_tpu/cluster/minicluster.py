@@ -1106,6 +1106,7 @@ class MiniCluster(TaskListener):
                     "index": t.subtask_index, "state": t.state,
                     "records_in": t.records_in,
                     "records_out": t.records_out,
+                    "key_group_records": t.key_group_records,
                     "busy_ratio": b, "idle_ratio": i,
                     "backpressure_ratio": bp}
                 # channel-consuming subtasks: per-channel queue depth /

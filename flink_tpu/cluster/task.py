@@ -145,6 +145,21 @@ class SubtaskBase:
         # backpressure: the reference gauges recordWriter availability
         self.backpressure_ns += time.monotonic_ns() - t0
 
+    @property
+    def key_group_records(self) -> Dict[str, int]:
+        """Records whose key groups this task's hash edges derived
+        (``computed``), and records its key-by operators found keyed for
+        their own key with the key groups there (``carried``) or named the
+        key of without deriving anything (``unread``)."""
+        chain = getattr(self.operator, "operators", None) or [self.operator]
+        return {
+            "computed": sum(getattr(out, "key_groups_computed", 0)
+                            for out in self.outputs),
+            "carried": sum(getattr(op, "key_groups_carried", 0)
+                           for op in chain),
+            "unread": sum(getattr(op, "key_groups_unread", 0)
+                          for op in chain)}
+
     def _transition(self, state: str, error: Optional[str] = None) -> None:
         self.state = state
         self.listener.task_state_changed(self.vertex_uid, self.subtask_index,

@@ -15,7 +15,7 @@ router uses it to split record batches across device shards (the analog of
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Iterator, List
+from typing import Iterator, List, Tuple
 
 import numpy as np
 
@@ -173,3 +173,32 @@ def route_raw_keys(keys: np.ndarray, parallelism: int,
         return np.zeros(len(keys), np.int32)
     return assign_key_to_parallel_operator(hash_keys(np.asarray(keys)),
                                            max_parallelism, parallelism)
+
+
+def keyed_for_edge(batch, key_column, max_parallelism: int):
+    """``batch`` as a hash edge on ``key_column`` routes it — the live
+    dispatcher (``cluster.channels.OutputDispatcher``) and the rescale of
+    persisted in-flight batches (``state.redistribute``) alike.  The keying
+    operator lives at the consumer chain head; the producer-side partitioner
+    names the key itself (KeyGroupStreamPartitioner's key selector) unless
+    the batch carries key groups of that very key, or of no named one, and
+    what it puts carries them on to that operator."""
+    if key_column is None or (
+            batch.key_spec is None and batch.key_groups_derived):
+        return batch
+    return batch.keyed_by(key_column, max_parallelism)
+
+
+def rows_by_target(key_groups: np.ndarray, max_parallelism: int,
+                   parallelism: int) -> Tuple[np.ndarray, List[int]]:
+    """``computeOperatorIndexForKeyGroup`` for every record, as one index
+    pass: ``(order, bounds)`` with the rows of target ``t``, in row order,
+    at ``order[bounds[t]:bounds[t + 1]]``."""
+    # the reference's own int arithmetic where the product fits 32 bits (it
+    # does up to Flink's bound of 2^15 key groups); 16 bits: a radix sort
+    wide = np.int32 if max_parallelism * parallelism < 2 ** 31 else np.int64
+    target = (np.asarray(key_groups, wide) * wide(parallelism)
+              // wide(max_parallelism)).astype(np.uint16)
+    order = np.argsort(target, kind="stable")
+    counts = np.bincount(target, minlength=parallelism)[:parallelism]
+    return order, [0, *np.cumsum(counts).tolist()]

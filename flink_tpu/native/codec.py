@@ -100,9 +100,14 @@ def _get_block(data: bytes, pos: int) -> Tuple[bytes, int]:
 
 def encode_batch(batch: RecordBatch, compress: bool = True) -> bytes:
     out = bytearray(MAGIC)
+    # key groups of a named key are not shipped, derived or not: the name
+    # is, and the receiver derives the same values if it ever reads them
+    spec = batch.key_spec
+    key_groups = None if spec is not None else batch.key_groups
     flags = ((batch.timestamps is not None) |
              ((batch.key_ids is not None) << 1) |
-             ((batch.key_groups is not None) << 2))
+             ((key_groups is not None) << 2) |
+             ((spec is not None) << 3))
     out.append(flags)
     _put_varint(out, len(batch))
     _put_varint(out, len(batch.columns))
@@ -110,8 +115,13 @@ def encode_batch(batch: RecordBatch, compress: bool = True) -> bytes:
         _put_i64_block(out, np.asarray(batch.timestamps, np.int64), compress)
     if batch.key_ids is not None:
         _put_block(out, np.ascontiguousarray(batch.key_ids, np.int32).tobytes(), compress)
-    if batch.key_groups is not None:
-        _put_block(out, np.ascontiguousarray(batch.key_groups, np.int32).tobytes(), compress)
+    if key_groups is not None:
+        _put_block(out, np.ascontiguousarray(key_groups, np.int32).tobytes(), compress)
+    if spec is not None:
+        nb = spec[0].encode()
+        _put_varint(out, len(nb))
+        out += nb
+        _put_varint(out, spec[1])
     for name, col in batch.columns.items():
         nb = name.encode()
         _put_varint(out, len(nb))
@@ -143,7 +153,7 @@ def decode_batch(data: bytes) -> RecordBatch:
     pos += 1
     n, pos = _get_varint(data, pos)
     n_cols, pos = _get_varint(data, pos)
-    ts = kid = kg = None
+    ts = kid = kg = spec = None
     if flags & 1:
         raw, pos = _get_block(data, pos)
         ts = np.frombuffer(raw, np.int64).copy()
@@ -153,6 +163,12 @@ def decode_batch(data: bytes) -> RecordBatch:
     if flags & 4:
         raw, pos = _get_block(data, pos)
         kg = np.frombuffer(raw, np.int32).copy()
+    if flags & 8:
+        ln, pos = _get_varint(data, pos)
+        key_column = data[pos:pos + ln].decode()
+        pos += ln
+        max_parallelism, pos = _get_varint(data, pos)
+        spec = (key_column, max_parallelism)
     cols = {}
     for _ in range(n_cols):
         ln, pos = _get_varint(data, pos)
@@ -174,4 +190,4 @@ def decode_batch(data: bytes) -> RecordBatch:
                 shape.append(d)
             raw, pos = _get_block(data, pos)
             cols[name] = np.frombuffer(raw, dtype).reshape(shape).copy()
-    return RecordBatch(cols, ts, kid, kg)
+    return RecordBatch(cols, ts, kid, kg, spec)

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flink_tpu.core import keygroups
 from flink_tpu.core.batch import RecordBatch, StreamElement, Watermark
 from flink_tpu.core.functions import AggregateFunction, RuntimeContext
 from flink_tpu.core.watermarks import WatermarkGenerator
@@ -83,11 +82,16 @@ class FlatMapOperator(StreamOperator):
 
 
 class KeyByOperator(StreamOperator):
-    """Attach key-group routing metadata (``KeyGroupStreamPartitioner`` analog).
+    """Name the key of a batch (``KeyGroupStreamPartitioner`` analog).
 
-    Computes ``key_group = murmur(hash(key)) % max_parallelism`` per record —
-    the unit both network routing and state sharding agree on, so rescaling
-    moves whole key-group ranges (``KeyGroupRangeAssignment.java:50-84``).
+    Key groups travel with a batch for a named key column and
+    ``max_parallelism``: a batch the exchange already keyed for this
+    operator's pair passes through as it is, any other comes out keyed for
+    it, and the values are derived at most once a record, by the first
+    reader of ``batch.key_groups`` and only if there is one.  They are
+    ``key_group = murmur(hash(key)) % max_parallelism`` — the unit both
+    network routing and state sharding agree on, so rescaling moves whole
+    key-group ranges (``KeyGroupRangeAssignment.java:50-84``).
     Dense per-key slot ids stay owned by the downstream stateful operator.
     """
 
@@ -98,12 +102,19 @@ class KeyByOperator(StreamOperator):
         self.key_column = key_column
         self.max_parallelism = max_parallelism
         self.name = name
+        #: records that came keyed for this pair with the key groups there
+        #: (``carried``), and records whose key this operator named without
+        #: deriving anything (``unread``: a later reader derives them)
+        self.key_groups_carried = 0
+        self.key_groups_unread = 0
 
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
-        keys = np.asarray(batch.column(self.key_column))
-        kg = keygroups.assign_to_key_group(keygroups.hash_keys(keys),
-                                           self.max_parallelism)
-        return [batch.with_keys(batch.key_ids, kg)]
+        keyed = batch.keyed_by(self.key_column, self.max_parallelism)
+        if keyed is batch and batch.key_groups_derived:
+            self.key_groups_carried += len(batch)
+        else:
+            self.key_groups_unread += len(batch)
+        return [keyed]
 
 
 class TimestampsAndWatermarksOperator(StreamOperator):
@@ -244,7 +255,7 @@ class KeyedReduceOperator(StreamOperator):
             cols.update(out)
         else:
             cols[self.output_column] = out
-        return [RecordBatch(cols, batch.timestamps, batch.key_ids, batch.key_groups)]
+        return [batch.with_columns(cols)]
 
     def snapshot_state(self) -> Dict[str, Any]:
         if self.key_index is None:

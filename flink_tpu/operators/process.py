@@ -124,7 +124,7 @@ class KeyedProcessOperator(StreamOperator):
 
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
         slots = self.backend.key_slots(np.asarray(batch.column(self.key_column)))
-        batch = batch.with_keys(slots, batch.key_groups)
+        batch = batch.with_keys(slots)
         ctx = Context(self, slots)
         out = self.fn.process_batch(ctx, batch)
         return _normalize(out) + ctx._side

@@ -1562,8 +1562,7 @@ class OverAggregateOperator(StreamOperator):
                          else None)
                 cols[spec.out_name] = self._unbounded(i, spec, key, ts, vals,
                                                       first)
-        return [RecordBatch(cols, batch.timestamps, batch.key_ids,
-                            batch.key_groups)]
+        return [batch.with_columns(cols)]
 
     def _first_occurrence(self, i: int, key: Any,
                           vals: np.ndarray) -> np.ndarray:

@@ -76,26 +76,21 @@ def _route_batch(el, info, new_parallelism: int):
     """One persisted in-flight RecordBatch -> ``[(target, sub_batch)]``,
     routed by the RECORD'S OWN KEY exactly the way the producing edge's
     dispatcher routes live batches: the batch's own ``key_groups`` when
-    the upstream keying attached them, else the edge's key column hashed
-    with the producer's max-parallelism (``KeyGroupStreamPartitioner``),
+    they are the edge's key's (or were handed in under no key's name), else
+    the edge's key column hashed with the producer's max-parallelism
+    (``KeyGroupStreamPartitioner``),
     then ``kg * P' // maxp`` — the same assignment
     ``core.keygroups.route_raw_keys`` computes.  Returns None when the
     element is not key-routable (non-keyed edge, no key metadata)."""
-    kg = getattr(el, "key_groups", None)
     maxp = int(info.get("max_parallelism", 128)) if info else 128
+    if info and info.get("partitioning") == "hash":
+        el = keygroups.keyed_for_edge(el, info.get("key_column"), maxp)
+    kg = el.key_groups
     if kg is None:
-        if not info or info.get("partitioning") != "hash" \
-                or info.get("key_column") is None:
-            return None
-        keys = np.asarray(el.column(info["key_column"]))
-        kg = keygroups.assign_to_key_group(keygroups.hash_keys(keys), maxp)
-    target = (np.asarray(kg, np.int64) * new_parallelism) // maxp
-    out = []
-    for t in range(new_parallelism):
-        sel = target == t
-        if sel.any():
-            out.append((int(t), el.select(sel)))
-    return out
+        return None
+    order, bounds = keygroups.rows_by_target(kg, maxp, new_parallelism)
+    return [(t, el.take(order[lo:hi]))
+            for t, (lo, hi) in enumerate(zip(bounds, bounds[1:])) if hi > lo]
 
 
 def redistribute_channel_state(sections, new_parallelism: int,
