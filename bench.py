@@ -102,8 +102,7 @@ def _build_op(window_ms: int, emit_tier: str = "host",
               device_sync: str = "auto", paging_cap: int = 0,
               pipeline_depth: int = 1, native_shards: int = 0,
               mesh_devices: int = 0, key_capacity: int = 1 << 20,
-              device_probe: str = "auto", queryable=None,
-              superbatch: int = 0):
+              queryable=None):
     import jax.numpy as jnp
 
     from flink_tpu.core.functions import RuntimeContext, SumAggregator
@@ -124,15 +123,10 @@ def _build_op(window_ms: int, emit_tier: str = "host",
         paging=paging,
         # the bench IS the hot-path deployment: pipelined by default
         # (--pipeline-depth 0 A/Bs the serial path), native probe sharded
-        # across cores (--native-shards; 0 = auto), device-resident key
-        # probe behind --device-probe (auto = measured A/B calibration)
+        # across cores (--native-shards; 0 = auto)
         pipeline_depth=pipeline_depth,
         native_shards=native_shards,
-        device_probe=device_probe,
-        queryable=queryable,
-        # one-dispatch fused megastep (ISSUE-11): stage N micro-batches
-        # and advance them in one pass (0 = measured auto-calibration)
-        superbatch=superbatch)
+        queryable=queryable)
     if mesh_devices > 1:
         # the mesh-sharded hot path: ONE logical operator over the chip
         # mesh (parallel/mesh_runtime) — state in key-group-range blocks,
@@ -196,8 +190,7 @@ def run_tpu_native(batches, window_ms: int, checkpoint_every: int,
                    emit_tier: str = "host", device_sync: str = "auto",
                    timed_passes: int = 3, pipeline_depth: int = 1,
                    native_shards: int = 0, mesh_devices: int = 0,
-                   key_capacity: int = 1 << 20, device_probe: str = "auto",
-                   superbatch: int = 0):
+                   key_capacity: int = 1 << 20):
     """Timed checkpointable run.  Returns (records/sec, windows fired,
     snapshots taken, phase dict, mid-run snapshot + its batch index +
     post-checkpoint digests for the replay check)."""
@@ -269,8 +262,7 @@ def run_tpu_native(batches, window_ms: int, checkpoint_every: int,
             for lo in range(0, nk, bsz)]
     op = _build_op(window_ms, emit_tier, device_sync,
                    pipeline_depth=pipeline_depth, native_shards=native_shards,
-                   mesh_devices=mesh_devices, key_capacity=key_capacity,
-                   device_probe=device_probe, superbatch=superbatch)
+                   mesh_devices=mesh_devices, key_capacity=key_capacity)
     run(op, warm + batches[:2] + batches[-1:])
     # best of three timed passes: a shared host suffers EPISODIC
     # multi-second slowdowns (measured ±70% swings on otherwise-stable C
@@ -296,8 +288,7 @@ def run_tpu_native(batches, window_ms: int, checkpoint_every: int,
 def replay_check(batches, window_ms: int, mid, digests,
                  emit_tier: str = "host", device_sync: str = "auto",
                  pipeline_depth: int = 1, native_shards: int = 0,
-                 mesh_devices: int = 0, key_capacity: int = 1 << 20,
-                 device_probe: str = "auto", superbatch: int = 0) -> bool:
+                 mesh_devices: int = 0, key_capacity: int = 1 << 20) -> bool:
     """Exactly-once evidence: restore the mid-run snapshot into a FRESH
     operator, replay the remaining batches, and require the identical
     per-window fire digests."""
@@ -308,8 +299,7 @@ def replay_check(batches, window_ms: int, mid, digests,
     i, snap = mid
     op = _build_op(window_ms, emit_tier, device_sync,
                    pipeline_depth=pipeline_depth, native_shards=native_shards,
-                   mesh_devices=mesh_devices, key_capacity=key_capacity,
-                   device_probe=device_probe, superbatch=superbatch)
+                   mesh_devices=mesh_devices, key_capacity=key_capacity)
     op.restore_state(snap)
     out = []
     for keys, vals, ts in batches[i + 1:]:
@@ -332,8 +322,7 @@ def measure_fire_latency(batches, window_ms: int,
                          emit_tier: str = "host",
                          device_sync: str = "auto",
                          pipeline_depth: int = 1,
-                         native_shards: int = 0,
-                         device_probe: str = "auto") -> dict:
+                         native_shards: int = 0) -> dict:
     """Window-fire latency: watermark arrival -> fired rows materialized on
     the host.  >= ``min_samples`` samples (VERDICT r2 weak #2), capped at
     ``max_samples`` (each device-tier sample is a real synchronous
@@ -358,8 +347,7 @@ def measure_fire_latency(batches, window_ms: int,
         cycles = halved
     cycles = cycles[:max_samples]
     op = _build_op(window_ms, emit_tier, device_sync,
-                   pipeline_depth=pipeline_depth, native_shards=native_shards,
-                   device_probe=device_probe)
+                   pipeline_depth=pipeline_depth, native_shards=native_shards)
     # warm compiles/allocations outside the timed samples
     warm_keys = batches[0][0]
     for i in range(2):
@@ -1802,13 +1790,12 @@ def run_queryable_bench(args) -> dict:
         return n_records * n_repeats / elapsed, cid
 
     # warm-up: one throwaway prefix drain + snapshot so pass 1 measures
-    # the job, not XLA compiles / process-wide sync+superbatch
+    # the job, not XLA compiles / process-wide sync
     # calibration / allocator warm-up (pass ordering must not bias the
     # under-load-vs-unloaded fraction)
     warm = _build_op(window_ms, "host", args.device_sync,
                      pipeline_depth=args.pipeline_depth,
-                     native_shards=args.native_shards,
-                     device_probe=args.device_probe)
+                     native_shards=args.native_shards)
     for k, v, ts in batches[: max(1, len(batches) // 8)]:
         warm.process_batch(RecordBatch({"k": k, "v": v}, timestamps=ts))
         warm.process_watermark(Watermark(int(ts.max()) - 1))
@@ -1836,8 +1823,7 @@ def run_queryable_bench(args) -> dict:
     def _leg_op():
         return _build_op(window_ms, "host", args.device_sync,
                          pipeline_depth=args.pipeline_depth,
-                         native_shards=args.native_shards,
-                         device_probe=args.device_probe, queryable="agg")
+                         native_shards=args.native_shards, queryable="agg")
 
     # ONE serving tier + server for the whole bench: loaded legs
     # re-register their op's live view (register_views replaces), the
@@ -2180,7 +2166,6 @@ def run_mesh_bench(args) -> dict:
         timed_passes=2 if args.smoke else 3,
         pipeline_depth=args.pipeline_depth,
         native_shards=args.native_shards, mesh_devices=D,
-        device_probe=args.device_probe, superbatch=args.superbatch,
         # size the ring to the workload so the key-group-range blocks are
         # POPULATED on every device (capacity-sized blocks would park all
         # live rows on shard 0 at small key counts)
@@ -2189,13 +2174,10 @@ def run_mesh_bench(args) -> dict:
                              args.emit_tier, args.device_sync,
                              pipeline_depth=args.pipeline_depth,
                              native_shards=args.native_shards,
-                             mesh_devices=D, key_capacity=n_keys,
-                             device_probe=args.device_probe,
-                             superbatch=args.superbatch)
+                             mesh_devices=D, key_capacity=n_keys)
     ns = phases.pop("elapsed", 1)
     per_shard_ms = [round(v / 1e6, 1)
                     for v in shard_ns.get("probe_mirror", [])]
-    dp = op.device_probe_stats()
     detail = {
         "mesh_devices": D,
         "platform": jax.devices()[0].platform,
@@ -2209,15 +2191,6 @@ def run_mesh_bench(args) -> dict:
         "restore_replay_ok": replay_ok,
         "emit_tier": args.emit_tier,
         "device_sync": op.device_sync_mode,
-        "device_probe": "on" if dp["enabled"] else "off",
-        "probe_hit_rate": (round(dp["probe_hit_rate"], 4)
-                           if dp["probe_hit_rate"] is not None else None),
-        # fused staging on the mesh: the host super-pass + one exchange
-        # dispatch per super-batch (the scan lane is structurally off)
-        "fused": {k: (bool(v) if k == "enabled" else v)
-                  for k, v in op.fused_stats().items()
-                  if k in ("enabled", "depth", "flushes",
-                           "host_super_passes", "hot_dispatches")},
         # --mesh-devices 1 is the single-chip leg of the comparison: the
         # plain operator has no shard layout, its "manifest" is one block
         "shard_manifest": ([
@@ -2276,74 +2249,6 @@ def check_mesh_budget(result: dict, budget: dict) -> list:
     return viol
 
 
-def fused_equivalence_check(window_ms: int) -> bool:
-    """Fused on/off digest equality, asserted IN the run (ISSUE-11): a
-    small prefix of the headline stream drains through (a) the unfused
-    path, (b) the fused host super-pass, and (c) the forced scan lane
-    (device probe on + superbatch), and all three must produce identical
-    fire digests AND identical mid-run snapshot bytes.  The mirror tier's
-    f64/i64 accumulation is exact for f32 inputs, so this is equality,
-    not tolerance."""
-    from flink_tpu.core.batch import RecordBatch, Watermark
-
-    eq_batches = make_batches(1 << 16, 1 << 13, 1 << 13, window_ms,
-                              seed=41)
-
-    def drain(superbatch, device_probe):
-        op = _build_op(window_ms, "host", "deferred",
-                       pipeline_depth=0, native_shards=1,
-                       key_capacity=1 << 13, device_probe=device_probe,
-                       superbatch=superbatch)
-        out = []
-        sbytes = None
-        for i, (k, v, ts) in enumerate(eq_batches):
-            out += op.process_batch(RecordBatch({"k": k, "v": v},
-                                                timestamps=ts))
-            out += op.process_watermark(Watermark(int(ts.max()) - 1))
-            if i == len(eq_batches) // 2:
-                op.prepare_snapshot_pre_barrier()
-                snap = op.snapshot_state()
-                sbytes = (snap["counts"].tobytes(),
-                          tuple(np.asarray(l).tobytes()
-                                for l in snap["leaves"]))
-        out += op.end_input()
-        return _fire_digests(out), sbytes
-
-    base = drain(1, "off")
-    return drain(8, "off") == base and drain(4, "on") == base
-
-
-def check_fused_budget(result: dict, budget: dict,
-                       smoke: bool = False) -> list:
-    """Fused-lane gate (BENCH_BUDGET ``fused_cpu``/``fused_device``): the
-    in-run fused on/off digest equivalence is unconditional (divergent
-    digests never exit 0), ``max_dispatches_per_batch`` pins the
-    one-dispatch claim (steady-state warm-key super-batches must not leak
-    per-stage dispatches back in), and ``min_vs_numpy`` floors the CPU
-    fallback tier's ratio on full runs (smoke is one batch of fixed
-    costs)."""
-    viol = []
-    d = result["details"].get("fused") or {}
-    if not d.get("equivalence_ok"):
-        viol.append("fused on/off digest equivalence failed (fire digests "
-                    "or snapshot bytes diverge between the staged and "
-                    "per-batch paths)")
-    # the one-dispatch ceiling gates the FUSED lane's claim only: a run
-    # whose lane resolved (or was forced) off never promised amortized
-    # dispatch — e.g. per-batch probe+miss-update is structurally 2/batch
-    cap = budget.get("max_dispatches_per_batch")
-    dpb = d.get("dispatches_per_batch")
-    if (cap is not None and dpb is not None and d.get("enabled")
-            and dpb > cap):
-        viol.append(f"hot-path dispatches/batch {dpb} > ceiling {cap} "
-                    f"(the megastep is not amortizing dispatch)")
-    floor = budget.get("min_vs_numpy")
-    vs = result.get("vs_numpy_baseline")
-    if floor is not None and not smoke and vs is not None and vs < floor:
-        viol.append(f"vs_numpy_baseline {vs} < fused floor {floor}")
-    return viol
-
-
 def check_budget(result: dict, budget: dict) -> list:
     """Compare one bench result against a BENCH_BUDGET.json section; returns
     human-readable violations (empty = pass).  The in-repo regression gate
@@ -2376,12 +2281,6 @@ def check_budget(result: dict, budget: dict) -> list:
         if share > frac:
             viol.append(f"probe_mirror {pm}ms is {share:.0%} of elapsed "
                         f"{elapsed}ms > ceiling {frac:.0%}")
-    hr_floor = budget.get("min_probe_hit_rate")
-    hr = result["details"].get("probe_hit_rate")
-    if hr_floor is not None and result["details"].get("device_probe") == "on" \
-            and hr is not None and hr < hr_floor:
-        viol.append(f"probe_hit_rate {hr} < floor {hr_floor} (the device "
-                    f"probe is not absorbing the warm-key steady state)")
     return viol
 
 
@@ -2396,8 +2295,7 @@ def run_trace_bench(args, batches) -> dict:
 
     kw = dict(emit_tier=args.emit_tier, device_sync=args.device_sync,
               timed_passes=2, pipeline_depth=args.pipeline_depth,
-              native_shards=args.native_shards,
-              device_probe=args.device_probe)
+              native_shards=args.native_shards)
     off_rps = run_tpu_native(batches, args.window_ms,
                              args.checkpoint_every, **kw)[0]
     journal = tracing.install(tracing.SpanJournal(capacity=1 << 17))
@@ -2501,24 +2399,6 @@ def main():
     ap.add_argument("--native-shards", type=int, default=0,
                     help="native probe shard count (0 = auto: "
                          "FLINK_TPU_NATIVE_SHARDS or one per core up to 4)")
-    ap.add_argument("--superbatch", type=int, default=0, metavar="N",
-                    help="one-dispatch fused megastep (ISSUE-11): stage N "
-                         "micro-batches and advance them in ONE pass — a "
-                         "device-side lax.scan over donated buffers when "
-                         "the device probe is active, one concatenated "
-                         "fused C probe+fold on the host tier.  0 = auto "
-                         "(measured process-wide A/B, like "
-                         "--pipeline-depth/--device-probe), 1 = off; "
-                         "details land in details.fused and with --check "
-                         "gate against BENCH_BUDGET.json fused_cpu")
-    ap.add_argument("--device-probe", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="device-resident key probe (state/device_keyindex):"
-                         " resolve warm keys inside the jitted step so the "
-                         "host C fold touches only misses.  auto runs a "
-                         "measured A/B calibration (the probe usually loses "
-                         "on CPU-forced runs and wins on real "
-                         "accelerators); on/off force")
     ap.add_argument("--profile", metavar="PATH", default=None,
                     help="write the per-phase breakdown (phase_ns, "
                          "phase_bytes, phases_ms) of the winning timed pass "
@@ -2862,19 +2742,11 @@ def main():
      op) = run_tpu_native(batches, args.window_ms, args.checkpoint_every,
                           args.emit_tier, args.device_sync,
                           pipeline_depth=args.pipeline_depth,
-                          native_shards=args.native_shards,
-                          device_probe=args.device_probe,
-                          superbatch=args.superbatch)
+                          native_shards=args.native_shards)
     replay_ok = replay_check(batches, args.window_ms, mid, digests,
                              args.emit_tier, args.device_sync,
                              pipeline_depth=args.pipeline_depth,
-                             native_shards=args.native_shards,
-                             device_probe=args.device_probe,
-                             superbatch=args.superbatch)
-    # fused on/off digest equality, asserted in THIS run (ISSUE-11): the
-    # staged super-pass and the forced scan lane must match the per-batch
-    # path exactly at small scale before the headline number counts
-    fused_eq_ok = fused_equivalence_check(args.window_ms)
+                             native_shards=args.native_shards)
     # device-vs-mirror consistency: a REAL device download of the live
     # panes, compared against the host mirror (post-timing).  Under
     # deferred sync this validates the refresh round trip (upload ->
@@ -2892,7 +2764,7 @@ def main():
         max_samples=256 if args.emit_tier == "host" else 16,
         emit_tier=args.emit_tier, device_sync=args.device_sync,
         pipeline_depth=args.pipeline_depth,
-        native_shards=args.native_shards, device_probe=args.device_probe)
+        native_shards=args.native_shards)
 
     # transparency: when the transport calibration sent the headline run
     # down the deferred path, ALSO measure the scatter path (the r1-r3
@@ -2945,33 +2817,6 @@ def main():
         "device_sync": op.device_sync_mode,
         "pipeline_depth": args.pipeline_depth,
         "native_shards": op._nm_shards,
-    }
-    dp = op.device_probe_stats()
-    detail["device_probe"] = "on" if dp["enabled"] else "off"
-    if dp["enabled"]:
-        detail["probe_hit_rate"] = (round(dp["probe_hit_rate"], 4)
-                                    if dp["probe_hit_rate"] is not None
-                                    else None)
-        detail["miss_inserts"] = dp["miss_inserts"]
-        detail["delta_d2h_mb"] = round(dp["delta_d2h_bytes"] / 1e6, 2)
-    # ---- fused megastep accounting (ISSUE-11): the winning pass's staged
-    # depth, scan dispatches, hot-path dispatches/batch (the one-dispatch
-    # claim, gated by fused_cpu.max_dispatches_per_batch), compile counts
-    # of the scan megasteps (sticky geometry ⇒ O(log) per run), and the
-    # in-run fused on/off equivalence verdict
-    fu = op.fused_stats()
-    detail["fused"] = {
-        "enabled": bool(fu["enabled"]),
-        "superbatch": fu["depth"],
-        "staged_batches": fu["staged_batches"],
-        "flushes": fu["flushes"],
-        "scan_dispatches": fu["scan_dispatches"],
-        "scan_steps": fu["scan_steps"],
-        "host_super_passes": fu["host_super_passes"],
-        "dispatches_per_batch": round(
-            fu["hot_dispatches"] / max(1, len(batches)), 3),
-        "scan_compiles": op.fused_step_cache_size(),
-        "equivalence_ok": fused_eq_ok,
     }
     from flink_tpu.utils import transport
     if transport.dispatch_ms_per_mb() is not None:
@@ -3057,10 +2902,6 @@ def main():
             tier = f"{tier}_device"
         budget = budgets[tier]
         viol = check_budget(result, budget)
-        fused_tier = ("fused_cpu" if platform == "cpu" else "fused_device")
-        if fused_tier in budgets:
-            viol += check_fused_budget(result, budgets[fused_tier],
-                                       smoke=args.smoke)
         if trace_detail is not None:
             # tracing-on must cost <5% throughput (trace_cpu section) and
             # the artifact must carry the spans the round needs
@@ -3073,8 +2914,7 @@ def main():
             sys.exit(1)
     # correctness gates the exit code with or without --check
     failed = [name for name, ok in (("restore_replay_ok", replay_ok),
-                                    ("device_mirror_consistent", mirror_ok),
-                                    ("fused equivalence_ok", fused_eq_ok))
+                                    ("device_mirror_consistent", mirror_ok))
               if not ok]
     if failed:
         print(f"# CORRECTNESS FAILED: {', '.join(failed)}", file=sys.stderr)

@@ -40,8 +40,8 @@ Two kernel backends share one generic step (``xp`` = numpy or
   hash.
 
 :func:`calibrated_vectorized_cep` is the measured engine A/B behind
-``CepOperator(vectorized="auto")`` — the same measure-don't-assume pattern
-as ``--device-probe`` (``state/device_keyindex.calibrated_device_probe``).
+``CepOperator(vectorized="auto")``: each engine runs one synthetic batch
+once per process, and the faster one is kept.
 """
 
 from __future__ import annotations
@@ -666,8 +666,7 @@ def step_jit(tab: TransitionTable, m_cap: int, block, inputs
 def default_kernel() -> str:
     """Kernel backend pick: ``FLINK_TPU_CEP_KERNEL=numpy|jit`` overrides;
     otherwise jit on accelerators, numpy on CPU (the XLA per-step dispatch
-    loses to one fused numpy pass there, same verdict as the device
-    probe's CPU calibration)."""
+    loses to one fused numpy pass there)."""
     env = os.environ.get(_ENV_KERNEL, "").lower()
     if env in ("numpy", "np", "host"):
         return "numpy"
@@ -682,7 +681,7 @@ def default_kernel() -> str:
 
 
 # ---------------------------------------------------------------------------
-# engine calibration (the --device-probe-style measured A/B)
+# engine calibration (a measured A/B, once per process)
 # ---------------------------------------------------------------------------
 
 _calibrated: Optional[bool] = None
@@ -692,8 +691,7 @@ _calib_lock = threading.Lock()
 def calibrated_vectorized_cep() -> bool:
     """MEASURED verdict, cached process-wide: does the batched kernel beat
     the interpreted NFA on this host/backend?  ``vectorized="auto"`` asks
-    this once; ``FLINK_TPU_CEP_VECTORIZED=on|off`` short-circuits (same
-    contract as ``FLINK_TPU_DEVICE_PROBE``)."""
+    this once; ``FLINK_TPU_CEP_VECTORIZED=on|off`` short-circuits."""
     global _calibrated
     if _calibrated is not None:
         return _calibrated

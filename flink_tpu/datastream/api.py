@@ -769,9 +769,7 @@ class WindowedStream:
                   paging=None,
                   pipeline_depth: int = 0,
                   native_shards: int = 0,
-                  device_probe: str = "auto",
-                  queryable: Optional[str] = None,
-                  superbatch: int = 1) -> DataStream:
+                  queryable: Optional[str] = None) -> DataStream:
         """``paging``: a :class:`flink_tpu.state.paging.PagingConfig` caps
         the operator's resident key capacity — cold keys page out to the
         spill tier (state larger than HBM).  ``emit_tier`` overrides the
@@ -782,18 +780,11 @@ class WindowedStream:
         as a bounded software pipeline overlapping the task driver;
         ``native_shards`` partitions the native probe across cores (0 =
         auto) — both bit-identical to the serial defaults.
-        ``device_probe`` gates the device-resident key probe
-        (``state/device_keyindex.py``: warm keys resolve inside the jitted
-        step, the host C fold touches only misses) — "auto" runs a
-        measured A/B calibration, "on"/"off" force; bit-identical fires
-        and snapshots either way.  ``queryable`` registers the operator's
+        ``queryable`` registers the operator's
         state under that name with the queryable serving tier (ISSUE-9):
         fired values become readable over the batched lookup protocol /
         REST at ``live`` and (when checkpoints run) ``checkpoint``
-        consistency.  ``superbatch`` stages N micro-batches into one
-        fused megastep pass (ISSUE-11: one scan dispatch / one fused C
-        super-pass per N batches; 0 = measured auto-calibration, 1 = off)
-        — bit-identical fires, snapshots, and counters either way."""
+        consistency."""
         keyed, assigner = self.keyed, self.assigner
         trigger, lateness = self._trigger, self._allowed_lateness
         late_tag = getattr(self, "_late_tag", None)
@@ -896,16 +887,12 @@ class WindowedStream:
                     from flink_tpu.parallel.mesh_runtime import (
                         MeshWindowAggOperator)
                     return MeshWindowAggOperator(mesh=mesh,
-                                                 device_probe=device_probe,
                                                  queryable=queryable,
-                                                 superbatch=superbatch,
                                                  **kwargs)
                 return WindowAggOperator(paging=paging,
                                          pipeline_depth=pipeline_depth,
                                          native_shards=native_shards,
-                                         device_probe=device_probe,
                                          queryable=queryable,
-                                         superbatch=superbatch,
                                          **kwargs)
 
         t = keyed._then(name, factory)

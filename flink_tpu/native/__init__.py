@@ -150,8 +150,6 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.wm_export_pane.argtypes = [vp, i64, i64, vp, vp]
     lib.wm_import_pane.restype = None
     lib.wm_import_pane.argtypes = [vp, i64, i64, vp, vp]
-    lib.wm_apply_delta.restype = None
-    lib.wm_apply_delta.argtypes = [vp, i64, i64, vp, vp, u8p]
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

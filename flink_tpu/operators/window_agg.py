@@ -133,14 +133,6 @@ from flink_tpu.ops.shapes import next_pow2 as _next_pow2  # noqa: E402
 _PAD_ID = DROP_ID
 
 
-def _x64():
-    """Scoped 64-bit trace context for the device-probe DELTA arrays: the
-    mirror's f64/i64 precision must ride the device, but the repo runs jax
-    in 32-bit mode — ``enable_x64`` widens dtypes for exactly the delta
-    steps (allocation, fold, pull, clear) and nothing else."""
-    return jax.enable_x64()
-
-
 @partial(jax.jit, static_argnums=(0,))
 def _snapshot_read_step(layout, state, pane_slot):
     """A checkpoint's device read of one state array: ONE pane column,
@@ -268,12 +260,12 @@ class _Staging:
             return False   # provably-finished unknown -> never reuse
 
     def fill_values(self, leaves, B: int):
-        """Edge-pad the value leaves into the reused buffers (same values
-        as ``_pad_rows``); full-width leaves pass through uncopied."""
+        """Edge-pad the value leaves into the reused buffers; full-width
+        leaves pass through uncopied."""
         out = []
         for buf, a in zip(self.bufs, leaves):
             if a.shape[0] == buf.shape[0]:
-                out.append(a)  # already pow2: no copy (matches _pad_rows)
+                out.append(a)  # already pow2: no copy
                 continue
             buf[:B] = a
             buf[B:] = a[-1]
@@ -331,13 +323,6 @@ class WindowAggOperator(StreamOperator):
     _SHARDED_HOST_TIER = False
     _SHARDED_PAGING = False
     _SHARDED_DEGRADE = False
-    #: fused scan-lane capability (operators/fused_step.py): the single-
-    #: dispatch ``lax.scan`` megastep over a staged [N, B] super-batch.
-    #: The mesh subclass turns it off — its exchange routing (bucket plan,
-    #: sticky capacity) is host-computed per batch — and stages through the
-    #: fused HOST pass instead (one concatenated C probe+fold + one
-    #: exchange dispatch per super-batch).
-    _FUSED_SCAN = True
 
     def __init__(
         self,
@@ -364,9 +349,7 @@ class WindowAggOperator(StreamOperator):
         paging=None,
         pipeline_depth: int = 0,
         native_shards: int = 0,
-        device_probe: str = "auto",
         queryable: Optional[str] = None,
-        superbatch: int = 1,
     ):
         #: host tier: use the C++ WinMirror kernels (fused probe+mirror,
         #: compacting fire) when eligible; False pins the numpy mirror —
@@ -653,63 +636,17 @@ class WindowAggOperator(StreamOperator):
         import threading as _threading
         self._tier_lock = _threading.Lock()
 
-        # ---- device-resident key probe (state/device_keyindex.py): resolve
-        # warm keys ON the device, inside the already-dispatched XLA step —
-        # the host C pass then touches only misses.  Warm-row contributions
-        # accumulate in device-resident DELTA arrays (mirror precision:
-        # f64/i64) and the host value mirror catches up pane-granularly at
-        # fire/snapshot/verify time (wm_apply_delta + a bounded d2h pull of
-        # only the panes about to fire).  "auto" runs the measured A/B
-        # calibration (calibrated_device_probe); "on"/"off" force.
-        if device_probe not in ("auto", "on", "off"):
-            raise ValueError(f"device_probe must be auto|on|off, "
-                             f"got {device_probe!r}")
-        self.device_probe = device_probe
-        self._dki = None                      # DeviceKeyIndex when active
-        self._devprobe_resolved: Optional[bool] = None
-        self._delta_leaves = None             # mirror-dtype state twins
-        self._delta_counts = None             # int32, in ``_delta_layout``
-        self._delta_layout = None
-        self._delta_panes: set = set()        # panes with unsynced delta
-        self._dp_stats = {"probe_hits": 0, "probe_misses": 0,
-                          "miss_inserts": 0, "delta_syncs": 0}
-
-        # ---- one-dispatch fused megastep (operators/fused_step.py,
-        # ROADMAP item 6): stage up to ``superbatch`` micro-batches and
-        # advance them in ONE pass — a device-side lax.scan over donated
-        # state buffers when the device-resident probe is active, or one
-        # concatenated fused C probe+fold (+ one replica dispatch under
-        # scatter sync) on the host tier.  1 = off (the default — the
-        # serial-equivalent baseline, like pipeline_depth=0); 0 = auto
-        # (measured process-wide A/B, calibrated_superbatch); N > 1
-        # forces depth N.
-        # Watermarks that pass no window end leave the stage untouched
-        # (fire-boundary math decides the scan boundary); every state read
-        # flushes through flush_pipeline, so observable behaviour is
-        # bit-identical to the unfused path.
-        if int(superbatch) < 0:
-            raise ValueError("superbatch must be >= 0 (0 = auto)")
-        self.superbatch = int(superbatch)
-        from flink_tpu.operators.fused_step import SuperBatchStage
-        self._fused_resolved: Optional[int] = None   # depth; 1 = off
-        self._fused_stage = SuperBatchStage()
-        self._fused_counters = {"flushes": 0, "staged_batches": 0,
-                                "scan_dispatches": 0, "scan_steps": 0,
-                                "host_super_passes": 0}
-        self._fused_bp_hw = 0    # sticky pow2 high-water: scan step width
-        self._fused_n_hw = 0     # sticky pow2 high-water: scan depth
-        self._fused_shards = 0   # super-pass C shard count (0 = unresolved)
-        #: guarded hot-path dispatch count (bench: dispatches/batch)
+        #: guarded update dispatches so far (``fused_stats``)
         self._hot_dispatches = 0
 
         # ---- queryable serving tier (ISSUE-9): when named, every fired
         # window's emissions publish into a barrier-free live-read view
         # (queryable/view.py) — the SAME (keys, values) arrays the fire
-        # emitted, off the delta-synced host mirror, so a live read is
-        # bit-equal to the operator's fire-time values on every tier and
-        # mesh size.  Tagged with the watermark + last-completed-checkpoint
-        # id they reflect.  None (the default) costs one attribute check
-        # per fire and nothing on the record hot path.
+        # emitted, so a live read is bit-equal to the operator's fire-time
+        # values on every tier and mesh size.  Tagged with the watermark +
+        # last-completed-checkpoint id they reflect.  None (the default)
+        # costs one attribute check per fire and nothing on the record hot
+        # path.
         self.queryable = queryable
         self._qview = None
         self._last_completed_checkpoint: Optional[int] = None
@@ -870,11 +807,6 @@ class WindowAggOperator(StreamOperator):
         a warm operator, and by restore paths before loading a snapshot."""
         if self._pipe is not None:
             self._pipe.flush()   # in-flight stages still write this state
-        # staged micro-batches die with the state they were bound for (a
-        # fold into state we are about to drop would be wasted work); the
-        # sticky scan geometry and the resolved depth survive, like the
-        # resolved sync mode — compile-once across warm re-runs
-        self._fused_stage.take()
         self._staging_pool = {}
         self.key_index = None
         self._leaves = None
@@ -896,19 +828,11 @@ class WindowAggOperator(StreamOperator):
         self.phase_bytes = {}
         self.phase_shard_ns = {}
         self._hot_dispatches = 0
-        self._fused_counters = {"flushes": 0, "staged_batches": 0,
-                                "scan_dispatches": 0, "scan_steps": 0,
-                                "host_super_passes": 0}
         self._device_stale = False  # resolved sync mode survives the reset
         self._degraded = False      # fresh state restores on the device
         with self._tier_lock:
             self._tier_epoch += 1   # fence any in-flight promotion
         self._active_rows = None
-        self._dki = None            # device probe table died with key_index
-        self._drop_delta()
-        self._devprobe_resolved = None
-        self._dp_stats = {"probe_hits": 0, "probe_misses": 0,
-                          "miss_inserts": 0, "delta_syncs": 0}
         if self._pager is not None:
             self._pager.reset()
         self._incr_clear()      # a fresh state has no confirmed delta base
@@ -1027,756 +951,16 @@ class WindowAggOperator(StreamOperator):
             acc = self.phase_shard_ns[phase] = grown
         acc[:shard_ns.size] += shard_ns
 
-    # ----------------------------------------------- device-resident probe
-    def _devprobe_table_sharding(self):
-        """Placement for the device probe table (None = default device);
-        the mesh subclass keeps it unsharded too (the probe runs as one
-        plain dispatch; only the fold rides the exchange)."""
-        return None
-
-    def _devprobe_eligible(self) -> bool:
-        """Static eligibility of the device-resident key probe: the host
-        emit tier (the probe_mirror wall lives there), int64 keys, scalar
-        add/min/max accumulator leaves (the delta fold + wm_apply_delta
-        contract), and no paging — the pager needs every record's global
-        id ON THE HOST to translate gid -> resident row per batch, so a
-        device-resolved slot would be pulled straight back; the probe is
-        not the wall there (there is no host mirror fold to fuse with)."""
-        return (self.device_probe != "off"
-                and self.emit_tier == "host"
-                and self._pager is None
-                and self.kinds is not None
-                and all(tuple(s) == () for s in self.spec.leaf_shapes)
-                and isinstance(self.key_index, KeyIndex)
-                and not self.trigger.fires_on_count)
-
-    def _devprobe_active(self, sync: str) -> bool:
-        """Per-batch gate: resolved once per key-index lifetime ("on"
-        forces, "auto" asks the measured A/B calibration), then cheap."""
-        if self._degraded or sync not in ("scatter", "deferred"):
-            return False
-        if self._devprobe_resolved is None:
-            if not self._devprobe_eligible():
-                self._devprobe_resolved = False
-            elif self.device_probe == "on":
-                self._devprobe_resolved = True
-            else:
-                from flink_tpu.state.device_keyindex import \
-                    calibrated_device_probe
-                self._devprobe_resolved = calibrated_device_probe()
-        return self._devprobe_resolved
-
+    # ------------------------------------------ shims the benchmark reads
+    # ``benchmarks/harness/runner.py`` (``check_healthy``, ``_lanes``) reads
+    # these two by name, and no PR but a ``benchmark`` one may edit it
+    # (ROADMAP C7): the lanes they described are gone.
     def device_probe_stats(self) -> Dict[str, Any]:
-        """Device-probe counters (monitoring-grade, no pipeline barrier):
-        hits/misses resolve the warm-key story (steady state ~= 100% hit
-        rate ⇒ the host C fold touches only miss rows), ``miss_inserts``
-        counts table scatters, ``delta_d2h_bytes`` the pane-granular
-        mirror catch-up pulls."""
-        s = dict(self._dp_stats)
-        total = s["probe_hits"] + s["probe_misses"]
-        s["enabled"] = int(bool(self._devprobe_resolved))
-        s["probe_hit_rate"] = (s["probe_hits"] / total) if total else None
-        s["delta_d2h_bytes"] = int(self.phase_bytes.get("delta_d2h", 0))
-        return s
-
-    def _drop_delta(self) -> None:
-        self._delta_leaves = None
-        self._delta_counts = None
-        self._delta_layout = None
-        self._delta_panes = set()
-
-    def _ensure_delta(self) -> None:
-        """Allocate the device-resident DELTA ring (K x P cells in the
-        state's layout) in the MIRROR dtypes (f64/i64 — the
-        higher-precision twins, so warm-row folds carry exactly the
-        precision the host mirror fold would have)."""
-        layout = self._layout
-        if self._delta_counts is not None and self._delta_layout == layout:
-            return
-        with _x64():
-            leaves = [layout.full(np.asarray(init).astype(mdt), (), mdt)
-                      for init, mdt in zip(self.spec.leaf_inits,
-                                           self._mirror_dtypes)]
-            *leaves, counts = self._placed(
-                leaves + [layout.full(0, (), jnp.int32)])
-        self._delta_leaves = tuple(leaves)
-        self._delta_counts = counts
-        self._delta_layout = layout
-        self._delta_panes = set()
-
-    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5, 6, 7))
-    def _probed_update_step(self, layout, tab, b, leaves, counts, dleaves,
-                            dcounts, key_lo, key_hi, start, pane_slots,
-                            values):
-        """Scatter-sync micro-batch with the key probe INSIDE the jitted
-        step: probe the device table, fold warm (hit) rows into both the
-        device state (device precision) and the delta ring (mirror
-        precision), and return a compact miss list for the host.  Miss and
-        pad rows carry the dropped _PAD_ID.  The scalar miss count is the
-        host's only mandatory read-back."""
-        from flink_tpu.state.device_keyindex import lax_probe
-        slot = lax_probe(*tab, key_lo, key_hi, start)
-        Bp = key_lo.shape[0]
-        valid = jnp.arange(Bp, dtype=jnp.int32) < b
-        hit = valid & (slot >= 0)
-        flat = jnp.where(hit, slot * layout.P + pane_slots, _PAD_ID)
-        lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-        new_leaves, new_counts = layout.fold(leaves, counts, flat, lifted,
-                                             self.kinds)
-        # the delta twins are f64/i64: the fold casts the lifted leaves up
-        ndl, ndc = layout.fold(dleaves, dcounts, flat, lifted, self.kinds)
-        miss = valid & (slot < 0)
-        miss_idx = jnp.nonzero(miss, size=Bp,
-                               fill_value=Bp)[0].astype(jnp.int32)
-        miss_count = jnp.sum(miss, dtype=jnp.int32)
-        return new_leaves, new_counts, ndl, ndc, miss_idx, miss_count
-
-    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5))
-    def _probed_delta_step(self, layout, tab, b, dleaves, dcounts,
-                           key_lo, key_hi, start, pane_slots, values):
-        """Deferred-sync twin of :meth:`_probed_update_step`: the mirror is
-        authoritative, so warm rows fold into the delta ring ONLY (the
-        device state replica catches up at device_refresh, as before)."""
-        from flink_tpu.state.device_keyindex import lax_probe
-        slot = lax_probe(*tab, key_lo, key_hi, start)
-        Bp = key_lo.shape[0]
-        valid = jnp.arange(Bp, dtype=jnp.int32) < b
-        hit = valid & (slot >= 0)
-        flat = jnp.where(hit, slot * layout.P + pane_slots, _PAD_ID)
-        lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-        ndl, ndc = layout.fold(dleaves, dcounts, flat, lifted, self.kinds)
-        miss = valid & (slot < 0)
-        miss_idx = jnp.nonzero(miss, size=Bp,
-                               fill_value=Bp)[0].astype(jnp.int32)
-        miss_count = jnp.sum(miss, dtype=jnp.int32)
-        return ndl, ndc, miss_idx, miss_count
-
-    def _fused_scan_body(self, tab, layout, pad_id, treedef,
-                         carry_is_state):
-        """One scan step of the fused megastep: probe the device table,
-        fold warm rows, emit the compact miss list.  Shared by the scatter
-        and deferred scan steps; ``carry_is_state`` distinguishes the
-        (state, delta) carry from the delta-only carry."""
-        from flink_tpu.state.device_keyindex import lax_probe
-        Pn = layout.P
-
-        def fold(flat, lifted, leaves, counts):
-            return layout.fold(leaves, counts, flat, lifted, self.kinds)
-
-        def body(carry, xs):
-            b, klo, khi, stt, ps = xs[:5]
-            vals = xs[5:]
-            Bp = klo.shape[0]
-            valid = jnp.arange(Bp, dtype=jnp.int32) < b
-            values = jax.tree_util.tree_unflatten(treedef, list(vals))
-            lifted = tuple(jax.tree_util.tree_leaves(self.agg.lift(values)))
-            slot = lax_probe(*tab, klo, khi, stt)
-            hit = valid & (slot >= 0)
-            flat = jnp.where(hit, slot * Pn + ps, pad_id)
-            if carry_is_state:
-                fl, fc, dl, dc = carry
-                fl, fc = fold(flat, lifted, fl, fc)
-                dl, dc = fold(flat, lifted, dl, dc)
-                out = (fl, fc, dl, dc)
-            else:
-                dl, dc = carry
-                dl, dc = fold(flat, lifted, dl, dc)
-                out = (dl, dc)
-            miss = valid & (slot < 0)
-            mi = jnp.nonzero(miss, size=Bp,
-                             fill_value=Bp)[0].astype(jnp.int32)
-            return out, (mi, jnp.sum(miss, dtype=jnp.int32))
-
-        return body
-
-    @partial(jax.jit, static_argnums=(0, 1, 13),
-             donate_argnums=(3, 4, 5, 6))
-    def _fused_scan_update_step(self, layout, tab, leaves, counts, dleaves,
-                                dcounts, bs, key_lo, key_hi, start,
-                                pane_slots, vplanes, treedef):
-        """Scatter-sync scan megastep: ONE dispatch advances every staged
-        micro-batch — per step, probe + device-state fold (device
-        precision) + delta fold (mirror precision) — over donated state
-        buffers, so steady-state warm-key super-batches cost exactly one
-        dispatch.  Returns the per-step compact miss lists; the scalar
-        miss total is the host's only mandatory read-back."""
-        body = self._fused_scan_body(tab, layout, _PAD_ID, treedef, True)
-        (leaves, counts, dleaves, dcounts), (miss_idx, miss_counts) = \
-            jax.lax.scan(
-                body, (leaves, counts, dleaves, dcounts),
-                (bs, key_lo, key_hi, start, pane_slots) + tuple(vplanes))
-        return leaves, counts, dleaves, dcounts, miss_idx, miss_counts
-
-    @partial(jax.jit, static_argnums=(0, 1, 11), donate_argnums=(3, 4))
-    def _fused_scan_delta_step(self, layout, tab, dleaves, dcounts, bs,
-                               key_lo, key_hi, start, pane_slots, vplanes,
-                               treedef):
-        """Deferred-sync scan megastep: the mirror is authoritative, so
-        warm rows fold into the delta ring ONLY (the device replica
-        catches up at device_refresh) — still one dispatch per
-        super-batch."""
-        body = self._fused_scan_body(tab, layout, _PAD_ID, treedef, False)
-        (dleaves, dcounts), (miss_idx, miss_counts) = jax.lax.scan(
-            body, (dleaves, dcounts),
-            (bs, key_lo, key_hi, start, pane_slots) + tuple(vplanes))
-        return dleaves, dcounts, miss_idx, miss_counts
-
-    @partial(jax.jit, static_argnums=(0, 1, 4))
-    def _delta_pull_step(self, layout, dleaves, dcounts, rows: int,
-                         pane_slots):
-        """Bounded d2h pull: the delta columns of the panes about to be
-        read (fire/snapshot/verify), first ``rows`` key rows only — the
-        download scales with live keys x syncing panes, never the ring."""
-        cnt = layout.columns(dcounts, pane_slots, rows=rows, fill=0)
-        sel = tuple(layout.columns(l, pane_slots, rows=rows, fill=0)
-                    for l in dleaves)
-        return cnt, sel
-
-    @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
-    def _delta_clear_step(self, layout, dleaves, dcounts, pane_slots):
-        """Reset synced (or expired) delta columns back to identity."""
-        new_leaves = tuple(
-            layout.fill_columns(l, pane_slots, np.asarray(init).astype(mdt))
-            for l, init, mdt in zip(dleaves, self.spec.leaf_inits,
-                                    self._mirror_dtypes))
-        return new_leaves, layout.fill_columns(dcounts, pane_slots, 0)
-
-    def _devprobe_sync_mirror(self, panes=None) -> None:
-        """Pane-granular mirror catch-up: pull the delta columns of
-        ``panes`` (None = every unsynced pane), fold them into the host
-        value mirror (``wm_apply_delta`` / numpy twin), and reset those
-        delta columns on device.  Identity delta rows fold as no-ops, so
-        no mask rides the transfer."""
-        if self._delta_counts is None or not self._delta_panes:
-            return
-        if panes is None:
-            sync = sorted(self._delta_panes)
-        else:
-            want = {int(p) for p in np.asarray(panes).reshape(-1).tolist()}
-            sync = sorted(self._delta_panes & want)
-        if not sync:
-            return
-        n = self.key_index.num_keys if self.key_index is not None else 0
-        if n == 0:
-            self._delta_panes.difference_update(sync)
-            return
-        with self._phase("delta_sync"):
-            rows = min(_next_pow2(max(n, 1), 1024), self._K)
-            m = len(sync)
-            mp = _next_pow2(m, 1)
-            slots_np = np.full(mp, self._P, np.int32)   # pads: dropped
-            slots_np[:m] = np.asarray(sync, np.int64) % self._P
-            with _x64():
-                slots_d = jnp.asarray(slots_np)
-                cnt, sel = self._delta_pull_step(
-                    self._delta_layout, self._delta_leaves,
-                    self._delta_counts, rows, slots_d)
-                cnt_np = np.asarray(cnt)
-                sel_np = [np.asarray(l) for l in sel]
-                self._delta_leaves, self._delta_counts = \
-                    self._delta_clear_step(self._delta_layout,
-                                           self._delta_leaves,
-                                           self._delta_counts, slots_d)
-            self.phase_bytes["delta_d2h"] = \
-                self.phase_bytes.get("delta_d2h", 0) + cnt_np.nbytes + \
-                sum(l.nbytes for l in sel_np)
-            for j, p in enumerate(sync):
-                col_cnt = cnt_np[:n, j]
-                if not col_cnt.any():
-                    continue
-                if self._nm is not None:
-                    self._nm.apply_delta(int(p), col_cnt.astype(np.int64),
-                                         [l[:n, j] for l in sel_np])
-                else:
-                    entry = self._vmirror_pane(int(p))
-                    entry[0][:n] += col_cnt
-                    for k, kind in enumerate(self.kinds):
-                        ufunc = SCATTER_UFUNCS[kind]
-                        entry[k + 1][:n] = ufunc(
-                            entry[k + 1][:n],
-                            sel_np[k][:n, j].astype(self._mirror_dtypes[k],
-                                                    copy=False))
-            self._delta_panes.difference_update(sync)
-            self._dp_stats["delta_syncs"] += 1
-
-    def _hot_stage_devprobe(self, keys: np.ndarray, panes: np.ndarray,
-                            values, B: int, sync: str) -> None:
-        """Device-probe variant of the hot stage: one guarded dispatch
-        probes + folds the warm rows; the host pass then touches ONLY the
-        compact miss list (insert into the keydict, C-fold into the
-        mirror, one scatter to keep the device table current)."""
-        from flink_tpu.runtime import device_health
-        self._ensure_alloc()
-        self._ensure_delta()
-        if self._dki is None:
-            from flink_tpu.state.device_keyindex import DeviceKeyIndex
-            self._dki = DeviceKeyIndex(
-                initial_capacity=max(1 << 16, 2 * self._K),
-                sharding=self._devprobe_table_sharding())
-        self._dki.ensure_loaded(self.key_index)   # bulk/restore load
-        with self._phase("device_probe"):
-            key_lo, key_hi, start = self._dki.prepare_batch(keys)
-            Bp = _next_pow2(B, 64)
-
-            def pad32(a, fill=0):
-                out = np.full(Bp, fill, np.int32)
-                out[:B] = a
-                return out
-
-            klo_p, khi_p, st_p = pad32(key_lo), pad32(key_hi), pad32(start)
-            ps_p = pad32((panes % self._P).astype(np.int32))
-            vleaves = [np.asarray(a) for a in
-                       jax.tree_util.tree_leaves(values)]
-            treedef = jax.tree_util.tree_structure(values)
-            values_p = jax.tree_util.tree_unflatten(
-                treedef, [_pad_rows(a, Bp) for a in vleaves])
-            mb = (16 * Bp + sum(a.nbytes for a in vleaves)) / 1e6
-            tab = self._dki.table()
-            b_arr = np.int32(B)
-            geom = ("devprobe", self._dki.capacity, self._K, self._P, Bp,
-                    tuple((a.dtype.str, a.shape[1:]) for a in vleaves))
-            fresh_geom = geom != getattr(self, "_last_dispatch_geom", None)
-            self._last_dispatch_geom = geom
-
-            def thunk():
-                with _x64():
-                    if sync == "deferred":
-                        out = self._probed_delta_step(
-                            self._layout, tab, b_arr, self._delta_leaves,
-                            self._delta_counts, klo_p, khi_p, st_p, ps_p,
-                            values_p)
-                    else:
-                        out = self._probed_update_step(
-                            self._layout, tab, b_arr, self._leaves,
-                            self._counts,
-                            self._delta_leaves, self._delta_counts,
-                            klo_p, khi_p, st_p, ps_p, values_p)
-                # the scalar miss count is the dispatch's sync point: a
-                # wedged device must surface HERE, under the watchdog
-                return out, int(out[-1])
-
-            try:
-                self._hot_dispatches += 1
-                res, mc = device_health.guarded_dispatch(
-                    thunk, mb=mb, on_oom=None,
-                    label=f"{self.name}.device_probe",
-                    compile_grace=fresh_geom)
-            except DeviceQuarantinedError as err:
-                self._devprobe_degrade(err, keys, panes, values)
-                return
-            if sync == "deferred":
-                (self._delta_leaves, self._delta_counts,
-                 miss_idx, _mcnt) = res
-                self._device_stale = True
-            else:
-                (self._leaves, self._counts, self._delta_leaves,
-                 self._delta_counts, miss_idx, _mcnt) = res
-                self.phase_bytes["h2d"] = \
-                    self.phase_bytes.get("h2d", 0) + int(mb * 1e6)
-            self._delta_panes.update(
-                int(p) for p in np.unique(panes).tolist())
-            self._dp_stats["probe_hits"] += B - mc
-            self._dp_stats["probe_misses"] += mc
-        if mc:
-            self._devprobe_handle_misses(keys, panes, values, miss_idx, mc,
-                                         sync)
-
-    def _devprobe_absorb_misses(self, mkeys, mpanes, mvalues) -> np.ndarray:
-        """Shared miss-list host pass (single-chip AND mesh): fused C
-        probe+mirror fold over the miss rows only (numpy twin when the
-        native mirror is off), key growth with a delta drain/rebuild, and
-        one scatter to bring the device table current.  Returns the miss
-        rows' slot ids."""
-        with self._phase("probe_mirror"):
-            if self._nm is not None:
-                lifted = [np.asarray(l) for l in jax.tree_util.tree_leaves(
-                    self.agg.host_lift(mvalues))]
-                nshards, shard_div, shard_ns = self._probe_shards()
-                mslots = self._nm.probe_update(mkeys, mpanes, lifted,
-                                               shards=nshards,
-                                               shard_div=shard_div,
-                                               shard_ns=shard_ns)
-                self._record_shard_ns("probe_mirror", shard_ns)
-            else:
-                mslots = self.key_index.lookup_or_insert(mkeys)
-        if self.key_index.num_keys > self._K:
-            # growth reallocates the delta ring: drain it into the mirror
-            # first so no warm contribution is lost, then rebuild at newK
-            self._devprobe_sync_mirror(None)
-            self._drop_delta()
-            self._grow_keys(self.key_index.num_keys)
-            self._ensure_delta()
-        if self._nm is None:
-            # numpy value mirror: fold AFTER growth (the pane entries must
-            # already be sized for the new key count)
-            with self._phase("mirror"):
-                self._vmirror_update(mslots, mpanes, mvalues)
-        self._dp_stats["miss_inserts"] += \
-            self._dki.ensure_loaded(self.key_index)
-        return mslots
-
-    def _devprobe_handle_misses(self, keys, panes, values, miss_idx,
-                                mc: int, sync: str) -> None:
-        """The host pass over the compact miss list, plus — under scatter
-        sync — the miss rows' device-state fold."""
-        mi = np.asarray(miss_idx)[:mc].astype(np.int64)
-        mkeys = np.ascontiguousarray(keys[mi])
-        mpanes = np.ascontiguousarray(panes[mi])
-        mvalues = jax.tree_util.tree_map(lambda a: np.asarray(a)[mi],
-                                         values)
-        mslots = self._devprobe_absorb_misses(mkeys, mpanes, mvalues)
-        if sync != "deferred":
-            self._miss_replica_update(
-                mslots, mpanes, jax.tree_util.tree_structure(mvalues),
-                [np.asarray(a)
-                 for a in jax.tree_util.tree_leaves(mvalues)])
-
-    def _miss_replica_update(self, mslots, mpanes, treedef,
-                             vleaves) -> None:
-        """Scatter-sync replica catch-up for probe-miss rows (the shared
-        tail of the per-batch and fused miss paths): the device replica
-        must see every record, so fold the miss rows through the plain
-        (guarded) update step — host-built flat ids, the same
-        watchdog/OOM/quarantine path as every other hot-path dispatch.
-        Callers reach here only after every record is accounted for in
-        mirror-land (warm rows in the delta, miss rows C-folded), so a
-        quarantine degrades without refolding anything."""
-        Bm = int(mslots.size)
-        Bmp = _next_pow2(Bm, 64)
-        flat = np.full(Bmp, _PAD_ID, np.int32)
-        flat[:Bm] = (mslots.astype(np.int64) * self._P
-                     + (mpanes % self._P)).astype(np.int32)
-        values_p = jax.tree_util.tree_unflatten(
-            treedef, [_pad_rows(a, Bmp) for a in vleaves])
-        mb = (flat.nbytes + sum(a.nbytes for a in vleaves)) / 1e6
-        try:
-            with self._phase("device_dispatch"):
-                res = self._guarded_update(flat, values_p, mb)
-        except DeviceQuarantinedError as err:
-            self._devprobe_degrade(err)
-            return
-        self._leaves, self._counts = res[0], res[1]
-
-    def _devprobe_degrade(self, err: BaseException, keys=None, panes=None,
-                          values=None) -> None:
-        """Quarantine mid-batch with the device probe active: salvage the
-        unsynced delta into the mirror (under the monitor's bounded
-        salvage deadline — a REALLY wedged device fails the pull and the
-        task restarts from the last checkpoint, whose snapshot always
-        drained the delta first), drop the probe state, degrade the tier,
-        and — when ``keys`` is given — fold those not-yet-accounted rows
-        through the host pass so no record is lost.  Call sites that fail
-        AFTER every record reached mirror-land (warm rows in the delta,
-        misses C-folded) pass no rows."""
-        from flink_tpu.runtime import device_health
-        try:
-            if self._delta_counts is not None and self._delta_panes:
-                # donated-buffer safety (PR-4's _enter_degraded guard,
-                # extended to the probe/scan lanes' delta planes): a
-                # genuinely timed-out dispatch may already have CONSUMED
-                # the donated delta arrays — salvaging a deleted buffer is
-                # a use-after-free, so fail the salvage up front and take
-                # the restart path (the last checkpoint always drained the
-                # delta first)
-                if any(getattr(a, "is_deleted", lambda: False)()
-                       for a in (self._delta_counts,
-                                 *(self._delta_leaves or ()))):
-                    raise RuntimeError(
-                        "delta planes were donated into the abandoned "
-                        "dispatch (consumed); in-process salvage is "
-                        "impossible")
-                mon = device_health.get_monitor(create=False)
-                if mon is not None:
-                    mon.run_salvage(
-                        lambda: self._devprobe_sync_mirror(None),
-                        label=f"{self.name} delta salvage")
-                else:
-                    self._devprobe_sync_mirror(None)
-        except Exception as serr:  # noqa: BLE001 — delta unrecoverable
-            raise err from serr
-        self._drop_delta()
-        self._dki = None
-        self._devprobe_resolved = None   # re-resolve after a heal
-        self._enter_degraded(err)        # host tier: flags only
-        if keys is None or len(keys) == 0:
-            return
-        with self._phase("probe_mirror"):
-            if self._nm is not None:
-                lifted = [np.asarray(l) for l in jax.tree_util.tree_leaves(
-                    self.agg.host_lift(values))]
-                nshards, shard_div, shard_ns = self._probe_shards()
-                self._nm.probe_update(keys, panes, lifted, shards=nshards,
-                                      shard_div=shard_div,
-                                      shard_ns=shard_ns)
-            else:
-                slots = self.key_index.lookup_or_insert(keys)
-                self._vmirror_update(slots, panes, values)
-
-    def devprobe_step_cache_size(self) -> Dict[str, int]:
-        """Compiled-variant counts of the probed steps (the tier-1
-        sticky-capacity recompile smoke, like PR 6's exchange test):
-        steady state must be exactly one compile per (table capacity,
-        K_cap, batch geometry)."""
-        out = {}
-        for name in ("_probed_update_step", "_probed_delta_step"):
-            fn = getattr(type(self), name)
-            try:
-                out[name] = int(fn._cache_size())
-            except Exception:  # noqa: BLE001 — jax without the cache probe
-                out[name] = -1
-        return out
-
-    # ------------------------------------------------- fused megastep lane
-    def _fused_depth(self, sync: str) -> int:
-        """Resolved super-batch staging depth for this batch (1 = off).
-        Resolution happens once per operator (like the sync cadence and the
-        device-probe verdict): forced by ``superbatch > 1``, measured by
-        ``calibrated_superbatch`` on auto.  Only the host emit tier stages —
-        its f64/i64 mirror makes regrouped accumulation bit-exact, and its
-        fires/snapshots already funnel through the flush barrier.  While
-        the sync cadence is still calibrating, batches run unfused (the
-        calibration measures per-batch dispatch cost)."""
-        if sync not in ("scatter", "deferred"):
-            return 1
-        if self._fused_resolved is None:
-            if (self.emit_tier != "host" or self._pager is not None
-                    or self.trigger.fires_on_count
-                    or self.superbatch == 1):
-                self._fused_resolved = 1
-            elif self.superbatch > 1:
-                self._fused_resolved = self.superbatch
-            else:
-                from flink_tpu.operators.fused_step import \
-                    calibrated_superbatch
-                self._fused_resolved = calibrated_superbatch()
-        return self._fused_resolved
-
-    def _fused_pending(self) -> bool:
-        return bool(self._fused_stage)
+        return {"enabled": 0}
 
     def fused_stats(self) -> Dict[str, Any]:
-        """Fused-lane counters (monitoring-grade, no pipeline barrier —
-        the ``paging_stats`` contract): staging depth, flush/dispatch
-        counts, and the guarded hot-path dispatch total the bench divides
-        into dispatches/batch."""
-        s = dict(self._fused_counters)
-        depth = self._fused_resolved or (self.superbatch
-                                         if self.superbatch > 1 else 0)
-        s["enabled"] = int((self._fused_resolved or 1) > 1)
-        s["depth"] = depth
-        s["staged_pending"] = len(self._fused_stage)
-        s["hot_dispatches"] = self._hot_dispatches
-        return s
-
-    def fused_step_cache_size(self) -> Dict[str, int]:
-        """Compiled-variant counts of the scan megasteps (the tier-1
-        sticky-geometry recompile smoke, the ``_cache_size`` pattern of
-        PR 6/7): steady state must be exactly one compile per (table
-        capacity, K_cap, P, scan depth, step width, value spec)."""
-        out = {}
-        for name in ("_fused_scan_update_step", "_fused_scan_delta_step"):
-            fn = getattr(type(self), name)
-            try:
-                out[name] = int(fn._cache_size())
-            except Exception:  # noqa: BLE001 — jax without the cache probe
-                out[name] = -1
-        return out
-
-    def _fused_flush(self) -> None:
-        """Advance every staged micro-batch in ONE pass.  Scan-capable
-        operators with the device probe active take the single-dispatch
-        ``lax.scan`` lane; everything else concatenates and runs the fused
-        host pass once (still one replica dispatch per super-batch under
-        scatter sync).  Runs wherever the stage filled (pipeline worker or
-        task thread) — never concurrently, see ``SuperBatchStage``."""
-        if not self._fused_stage:
-            return
-        st = self._fused_stage.take()
-        self._fused_counters["flushes"] += 1
-        sync = self.device_sync_mode or "deferred"
-        if self._degraded:
-            sync = "deferred"
-        if (self._FUSED_SCAN and len(st) > 1
-                and self._devprobe_active(sync)):
-            self._fused_flush_scan(st, sync)
-            return
-        from flink_tpu.operators.fused_step import concat_staged
-        if len(st) == 1:
-            # a fire boundary (or state read) drained a single staged
-            # batch: that is the plain per-batch path, not a super pass
-            keys, panes, values, B = st[0]
-        else:
-            self._fused_counters["host_super_passes"] += 1
-            with self._phase("fused_scan"):
-                keys, panes, values, B = concat_staged(st)
-        if self._devprobe_active(sync):
-            # scan-incapable subclass (mesh): one per-super-batch probe
-            # pass — the probe, exchange, and miss fold each amortize
-            # across the staged batches
-            self._hot_stage_devprobe(keys, panes, values, B, sync)
-            return
-        self._hot_stage_fold(keys, panes, values, B, sync,
-                             super_pass=len(st) > 1)
-
-    def _fused_super_shards(self):
-        """(shards, shard_div, shard_ns) for the fused host SUPER pass:
-        the per-batch calibration measured thread-pool wake against one
-        micro-batch — re-measure at super-batch size (fused_step.
-        calibrated_super_shards) and take whichever is larger.  Mesh
-        subclasses keep their device-aligned contiguous ranges."""
-        nshards, shard_div, shard_ns = self._probe_shards()
-        if shard_div == 0 and self.native_shards == 0:
-            if not self._fused_shards:
-                from flink_tpu.operators.fused_step import \
-                    calibrated_super_shards
-                self._fused_shards = calibrated_super_shards()
-            nshards = max(nshards, self._fused_shards)
-        return nshards, shard_div, shard_ns
-
-    def _fused_flush_scan(self, st, sync: str) -> None:
-        """The scan lane: stage the super-batch as padded [N, B] planes
-        (sticky pow2 high-water on both axes) and advance all N steps in
-        ONE jitted dispatch over donated state buffers.  Only the per-step
-        compact miss lists and the scalar miss total (the sync point) come
-        back; the host pass then touches misses only, in step order — the
-        same slot-assignment order as the per-batch path."""
-        from flink_tpu.runtime import device_health
-        self._ensure_alloc()
-        self._ensure_delta()
-        if self._dki is None:
-            from flink_tpu.state.device_keyindex import DeviceKeyIndex
-            self._dki = DeviceKeyIndex(
-                initial_capacity=max(1 << 16, 2 * self._K),
-                sharding=self._devprobe_table_sharding())
-        self._dki.ensure_loaded(self.key_index)
-        with self._phase("fused_scan"):
-            N = len(st)
-            bp = max(_next_pow2(int(s[3]), 64) for s in st)
-            self._fused_bp_hw = bp = max(self._fused_bp_hw, bp)
-            self._fused_n_hw = nhw = max(self._fused_n_hw,
-                                         _next_pow2(N, 1))
-            klo = np.zeros((nhw, bp), np.int32)
-            khi = np.zeros((nhw, bp), np.int32)
-            stt = np.zeros((nhw, bp), np.int32)
-            ps = np.zeros((nhw, bp), np.int32)
-            bs = np.zeros(nhw, np.int32)   # pad steps: b=0, all rows dropped
-            treedef = jax.tree_util.tree_structure(st[0][2])
-            leaves0 = [np.asarray(a)
-                       for a in jax.tree_util.tree_leaves(st[0][2])]
-            vplanes = [np.zeros((nhw, bp) + a.shape[1:], a.dtype)
-                       for a in leaves0]
-            for i, (keys, panes, values, B) in enumerate(st):
-                lo, hi, start = self._dki.prepare_batch(keys)
-                klo[i, :B] = lo
-                khi[i, :B] = hi
-                stt[i, :B] = start
-                ps[i, :B] = (panes % self._P).astype(np.int32)
-                bs[i] = B
-                for j, a in enumerate(jax.tree_util.tree_leaves(values)):
-                    vplanes[j][i, :B] = np.asarray(a)
-            mb = (16 * nhw * bp + sum(v.nbytes for v in vplanes)) / 1e6
-            tab = self._dki.table()
-            geom = ("fused_scan", sync, self._dki.capacity, self._K,
-                    self._P, nhw, bp,
-                    tuple((v.dtype.str, v.shape[2:]) for v in vplanes))
-            fresh_geom = geom != getattr(self, "_last_dispatch_geom", None)
-            self._last_dispatch_geom = geom
-
-            def thunk():
-                with _x64():
-                    if sync == "deferred":
-                        out = self._fused_scan_delta_step(
-                            self._layout, tab, self._delta_leaves,
-                            self._delta_counts,
-                            bs, klo, khi, stt, ps, tuple(vplanes), treedef)
-                    else:
-                        out = self._fused_scan_update_step(
-                            self._layout, tab, self._leaves, self._counts,
-                            self._delta_leaves, self._delta_counts,
-                            bs, klo, khi, stt, ps, tuple(vplanes), treedef)
-                # the scalar miss total is the dispatch's sync point: a
-                # wedged device must surface HERE, under the watchdog
-                return out, int(np.asarray(out[-1]).sum())
-
-            try:
-                self._hot_dispatches += 1
-                res, total_miss = device_health.guarded_dispatch(
-                    thunk, mb=mb, on_oom=None,
-                    label=f"{self.name}.fused_scan",
-                    compile_grace=fresh_geom)
-            except DeviceQuarantinedError as err:
-                self._fused_scan_degrade(err, st)
-                return
-            self._fused_counters["scan_dispatches"] += 1
-            self._fused_counters["scan_steps"] += N
-            if sync == "deferred":
-                (self._delta_leaves, self._delta_counts,
-                 miss_idx, miss_counts) = res
-                self._device_stale = True
-            else:
-                (self._leaves, self._counts, self._delta_leaves,
-                 self._delta_counts, miss_idx, miss_counts) = res
-                self.phase_bytes["h2d"] = \
-                    self.phase_bytes.get("h2d", 0) + int(mb * 1e6)
-            for _keys, panes, _values, _B in st:
-                self._delta_panes.update(
-                    int(p) for p in np.unique(panes).tolist())
-            total_rows = int(sum(s[3] for s in st))
-            self._dp_stats["probe_hits"] += total_rows - total_miss
-            self._dp_stats["probe_misses"] += total_miss
-        if total_miss:
-            self._fused_handle_misses(st, np.asarray(miss_idx),
-                                      np.asarray(miss_counts), sync)
-
-    def _fused_handle_misses(self, st, miss_idx, miss_counts,
-                             sync: str) -> None:
-        """Post-scan host pass over the compact per-step miss lists, in
-        step (= batch) order, so new keys get exactly the slot ids the
-        per-batch path would assign.  A key first seen mid-super-batch
-        misses on every later step too (the device table is immutable
-        during the scan); its rows all land here, folding into the SAME
-        mirror cells the warm path would have used — bit-identical under
-        the mirror's exact accumulation."""
-        parts = []
-        for i, (keys, panes, values, _B) in enumerate(st):
-            mc = int(miss_counts[i])
-            if not mc:
-                continue
-            mi = miss_idx[i, :mc].astype(np.int64)
-            mkeys = np.ascontiguousarray(keys[mi])
-            mpanes = np.ascontiguousarray(panes[mi])
-            mvalues = jax.tree_util.tree_map(
-                lambda a: np.asarray(a)[mi], values)
-            mslots = self._devprobe_absorb_misses(mkeys, mpanes, mvalues)
-            if sync != "deferred":
-                parts.append((mslots, mpanes, mvalues))
-        if sync == "deferred" or not parts:
-            return
-        # ONE guarded update folds every step's miss rows (the mirror-
-        # precision story already landed above, so concatenation order
-        # here only moves replica low bits — verify_mirror tolerance
-        # territory)
-        self._miss_replica_update(
-            np.concatenate([p[0] for p in parts]),
-            np.concatenate([p[1] for p in parts]),
-            jax.tree_util.tree_structure(parts[0][2]),
-            [np.concatenate([np.asarray(l) for l in ls])
-             for ls in zip(*[jax.tree_util.tree_leaves(p[2])
-                             for p in parts])])
-
-    def _fused_scan_degrade(self, err: BaseException, st) -> None:
-        """A quarantined scan dispatch.  The scan is transactional — one
-        ``guarded_dispatch``, like PR-8's ``cep.vectorized_drain``: the
-        watchdog's failure modes precede execution (the chaos point fires
-        before the thunk; an abandoned lane skips it), so NO staged row
-        reached any state plane.  Salvage the PRIOR delta into the mirror
-        (with the donated-buffer guard — planes a genuinely timed-out
-        dispatch already consumed fail the salvage and take the restart
-        path), degrade the tier, and refold EVERY staged batch through the
-        host pass so no record is lost."""
-        from flink_tpu.operators.fused_step import concat_staged
-        keys, panes, values, _B = concat_staged(st)
-        self._devprobe_degrade(err, keys, panes, values)
+        """``hot_dispatches``: guarded update dispatches so far."""
+        return {"enabled": 0, "hot_dispatches": self._hot_dispatches}
 
     # ------------------------------------------------------------- pipeline
     def _pipe_active(self) -> bool:
@@ -1789,15 +973,12 @@ class WindowAggOperator(StreamOperator):
         return self._pipe is not None and self._pipe.pending()
 
     def flush_pipeline(self) -> List[StreamElement]:
-        """Pipeline barrier: complete every in-flight hot stage AND fold
-        any staged super-batch (the fused lane's flush boundary).  Called
+        """Pipeline barrier: complete every in-flight hot stage.  Called
         internally before any state read (fires, snapshots, verification)
-        and by task drivers at idle points so pipelined/staged results
-        never wait on the NEXT batch's arrival.  Safe no-op when both
-        lanes are off."""
+        and by task drivers at idle points so pipelined results never wait
+        on the NEXT batch's arrival.  Safe no-op when pipelining is off."""
         if self._pipe is not None:
             self._pipe.flush()
-        self._fused_flush()
         return []
 
     def _staging_acquire(self, Bp: int, leaves, treedef) -> _Staging:
@@ -1856,9 +1037,6 @@ class WindowAggOperator(StreamOperator):
         identity).  The single source of the mirror export semantics —
         identity fill, int64->int32 counts, mirror->device dtype casts —
         shared by mirror-sourced snapshots and the deferred-sync refresh."""
-        # device-probe delta: every mirror READER lands here (snapshots,
-        # refresh, re-promotion) — drain ALL unsynced panes first
-        self._devprobe_sync_mirror(None)
         ncols = len(panes) if ncols is None else ncols
         counts = np.zeros((rows, ncols), np.int32)
         leaves = []
@@ -2011,10 +1189,7 @@ class WindowAggOperator(StreamOperator):
     def _fire_window_host(self, window_id: int,
                           panes: np.ndarray) -> List[StreamElement]:
         """Serve a window fire ENTIRELY from the host mirror: no device op,
-        no download — the emit path for egress-constrained links.  With
-        the device probe active the mirror first catches up on exactly the
-        panes about to fire (the bounded pane-granular delta pull)."""
-        self._devprobe_sync_mirror(panes)
+        no download — the emit path for egress-constrained links."""
         n = self.key_index.num_keys if self.key_index is not None else 0
         if n == 0:
             return []
@@ -2063,7 +1238,6 @@ class WindowAggOperator(StreamOperator):
             return True  # replica intentionally stale/absent in quarantine
         if self.device_sync_mode == "deferred":
             self.device_refresh()
-        self._devprobe_sync_mirror(None)   # mirror must be caught up
         if self.emit_tier != "host" or self._leaves is None \
                 or self.pane_base is None:
             return True
@@ -2521,12 +1695,13 @@ class WindowAggOperator(StreamOperator):
 
     def _hot_stage(self, keys: np.ndarray, panes: np.ndarray, values,
                    B: int, pmin: int, pmax: int) -> None:
-        """The pipelined hot stage of one micro-batch: pane-ring
-        bookkeeping/growth, the fused probe/mirror pass, key growth,
-        paging, and the device dispatch.  Runs inline when pipelining is
-        off, on the ``_HotPipeline`` worker when on — the SAME code in the
-        SAME order either way, so fire digests, snapshots, and counters
-        cannot diverge between the two modes."""
+        """The hot stage of one micro-batch: pane-ring bookkeeping and
+        growth, then either the quarantined device tier's numpy fold or
+        the sync cadence's verdict and ONE call to ``_hot_stage_fold``.
+        Runs inline when pipelining is off, on the ``_HotPipeline`` worker
+        when on — the SAME code in the SAME order either way, so fire
+        digests, snapshots, and counters cannot diverge between the two
+        modes."""
         if self.pane_base is None:
             self.pane_base = pmin
             self.max_pane = pmax
@@ -2571,32 +1746,15 @@ class WindowAggOperator(StreamOperator):
             # skip the replica dispatch (deferred-sync semantics) until
             # re-promotion
             sync = "deferred"
-        if self._fused_depth(sync) > 1:
-            # one-dispatch fused megastep: park the batch; the whole
-            # super-batch advances in ONE pass at the flush boundary
-            # (depth/row bound here, fire boundary or any state read via
-            # flush_pipeline)
-            from flink_tpu.operators.fused_step import MAX_STAGED_ROWS
-            self._fused_stage.push(keys, panes, values, B)
-            self._fused_counters["staged_batches"] += 1
-            if (len(self._fused_stage) >= self._fused_resolved
-                    or self._fused_stage.rows >= MAX_STAGED_ROWS):
-                self._fused_flush()
-            return
-        if self._devprobe_active(sync):
-            # device-resident key probe: warm keys resolve INSIDE the
-            # dispatched step, the host pass touches only misses
-            return self._hot_stage_devprobe(keys, panes, values, B, sync)
         self._hot_stage_fold(keys, panes, values, B, sync)
 
     def _hot_stage_fold(self, keys: np.ndarray, panes: np.ndarray, values,
-                        B: int, sync: str, super_pass: bool = False) -> None:
-        """The fold half of the hot stage (probe/mirror pass, paging,
-        device dispatch) for one batch — a micro-batch on the unfused
-        path, a whole concatenated super-batch from ``_fused_flush``: the
-        SAME code folding the SAME records in the SAME order either way,
-        so fire digests, snapshots, and counters cannot diverge between
-        the fused and unfused lanes."""
+                        B: int, sync: str) -> None:
+        """The fold half of the hot stage for one micro-batch: the key
+        probe (fused with the value mirror's write-through on the host
+        tier), key growth, paging, and — unless ``sync`` is "deferred" —
+        the padded staging and the guarded ``_update_step`` dispatch, then
+        the emit mirror's marks."""
         staging = None
         flat_ready = False
         # flatten the value tree ONCE per batch: staging acquisition and
@@ -2616,16 +1774,10 @@ class WindowAggOperator(StreamOperator):
             # ids (the triples are computed once and consumed twice —
             # VERDICT r3 next #1b), sharded across the native worker pool
             # when native_shards > 1.  Deferred sync needs no scatter ids.
-            # Super-batches re-measure the shard verdict at their own size
-            # (thread-pool wake amortizes over N× the rows).
             with self._phase("probe_mirror"):
                 lifted = [np.asarray(l) for l in jax.tree_util.tree_leaves(
                     self.agg.host_lift(values))]
-                if super_pass:
-                    nshards, shard_div, shard_ns = \
-                        self._fused_super_shards()
-                else:
-                    nshards, shard_div, shard_ns = self._probe_shards()
+                nshards, shard_div, shard_ns = self._probe_shards()
                 if sync == "deferred":
                     slots = self._nm.probe_update(keys, panes, lifted,
                                                   shards=nshards,
@@ -2756,11 +1908,6 @@ class WindowAggOperator(StreamOperator):
             while self._P < span:
                 self._P <<= 1
             return
-        if self._delta_counts is not None and span > self._P:
-            # the delta ring reallocates with P: drain it into the mirror
-            # first (no warm contribution may be lost), rebuild at new P
-            self._devprobe_sync_mirror(None)
-            self._drop_delta()
         self._ensure_alloc()
         self._grow_panes(span)
 
@@ -3008,7 +2155,7 @@ class WindowAggOperator(StreamOperator):
 
     def process_watermark(self, watermark: Watermark) -> List[StreamElement]:
         self.watermark = max(self.watermark, watermark.timestamp)
-        if ((self._pipe_pending() or self._fused_pending())
+        if (self._pipe_pending()
                 and not self.async_fire
                 and self.lateness == 0
                 and self.trigger.fires_on_time and self.assigner.is_event_time
@@ -3127,23 +2274,6 @@ class WindowAggOperator(StreamOperator):
             self._vmirror.pop(ep, None)
             if self._nm is not None:
                 self._nm.drop_pane(ep)
-        if self._delta_counts is not None and not self._degraded:
-            # expired panes' unsynced delta is DISCARDED, exactly like the
-            # mirror pane it would have folded into (reset, or a later
-            # sync of the reused ring slot would resurrect dead data)
-            dead = [p for p in expired if p in self._delta_panes]
-            if dead:
-                m = len(dead)
-                mp2 = _next_pow2(m, 1)
-                slots_np = np.full(mp2, self._P, np.int32)
-                slots_np[:m] = np.asarray(dead, np.int64) % self._P
-                with _x64():
-                    self._delta_leaves, self._delta_counts = \
-                        self._delta_clear_step(self._delta_layout,
-                                               self._delta_leaves,
-                                               self._delta_counts,
-                                               jnp.asarray(slots_np))
-                self._delta_panes.difference_update(dead)
         if self._pager is not None and not self._degraded:
             self._pager.drop_panes(expired)
         if self.pane_base > self.max_pane:
@@ -4039,10 +3169,7 @@ class WindowAggOperator(StreamOperator):
         self._P = snap["P"]
         self._nm = None          # rebinds to the restored key index below
         self._nm_tried = False
-        self._dki = None         # probe table rebuilds from the key index
-        self._drop_delta()
         self._incr_clear()       # restored state: first cut is a full base
-        self._devprobe_resolved = None
         if "key_index" in snap:
             if snap["key_index_kind"] == "ObjectKeyIndex":
                 self.key_index = ObjectKeyIndex.restore(snap["key_index"])
@@ -4125,9 +3252,3 @@ class WindowAggOperator(StreamOperator):
                                  for w, leaves in
                                  snap.get("value_baselines", {}).items()}
 
-
-def _pad_rows(a: np.ndarray, n: int) -> np.ndarray:
-    if a.shape[0] == n:
-        return a
-    pad = [(0, n - a.shape[0])] + [(0, 0)] * (a.ndim - 1)
-    return np.pad(a, pad, mode="edge")

@@ -41,9 +41,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flink_tpu.operators.session_window import SessionWindowOperator
-from flink_tpu.operators.window_agg import (WindowAggOperator, _next_pow2,
-                                            _x64)
-from flink_tpu.runtime.device_health import DeviceQuarantinedError
+from flink_tpu.operators.window_agg import WindowAggOperator, _next_pow2
 from flink_tpu.ops.pane_layout import KeyGrid
 from flink_tpu.parallel.mesh import KG_AXIS, make_mesh, state_sharding
 
@@ -97,13 +95,6 @@ class MeshWindowAggOperator(WindowAggOperator):
     _SHARDED_HOST_TIER = True
     _SHARDED_PAGING = True
     _SHARDED_DEGRADE = True
-    #: the single-dispatch ``lax.scan`` lane stays off on the mesh: the
-    #: exchange routing (bucket plan, sticky capacity) is host-computed
-    #: per batch.  Super-batch STAGING still applies — the fused host pass
-    #: concatenates the staged batches, so the C probe, the all_to_all
-    #: exchange, and (with the probe on) the device probe dispatch each
-    #: run once per super-batch instead of once per micro-batch.
-    _FUSED_SCAN = False
 
     def __init__(self, *args, mesh: Optional[Mesh] = None,
                  n_devices: Optional[int] = None, **kwargs):
@@ -169,13 +160,17 @@ class MeshWindowAggOperator(WindowAggOperator):
         return snap
 
     # ------------------------------------------------------------- device op
-    def _sharded_fold(self, state, batch, cap: int, combine_leaves):
-        """The body of both mesh steps: per-device bucket by destination →
-        ``all_to_all`` over ICI → scatter-combine into the local block of
-        ``state`` = (leaves, counts).  The named scopes are each stage's
-        name in the program's HLO (every operation's ``op_name``), so a
-        device trace's operations can be told apart by stage."""
-        leaves, counts = state
+    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
+    def _mesh_update_step(self, leaves_counts, batch, cap: int):
+        """One sharded micro-batch into the state: per-device bucket by
+        destination → ``all_to_all`` over ICI → scatter-combine into the
+        local block.  ``batch`` = (dest, slots, pane_slots, *values), each
+        row-split over the mesh; ``cap`` = per-(src, dest) bucket capacity
+        (host-known upper bound, so the exchange can never overflow).  The
+        named scopes are each stage's name in the program's HLO (every
+        operation's ``op_name``), so a device trace's operations can be
+        told apart by stage."""
+        leaves, counts = leaves_counts
         D = self.n_shards
         K, Pn = counts.shape
         KD = K // D
@@ -212,7 +207,8 @@ class MeshWindowAggOperator(WindowAggOperator):
                 lifted = tuple(jax.tree_util.tree_leaves(
                     self.agg.lift(self._values_tree(rx_vals))))
                 return KeyGrid(KD, Pn).fold(leaves, counts, lflat, lifted,
-                                            self.kinds, combine_leaves)
+                                            self.kinds,
+                                            self.agg.combine_leaves)
 
         rows = P(KG_AXIS)
         state_specs = ((rows,) * len(leaves), rows)
@@ -221,162 +217,21 @@ class MeshWindowAggOperator(WindowAggOperator):
                            out_specs=state_specs, check_vma=False)
         return fn(leaves, counts, *batch)
 
-    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
-    def _mesh_update_step(self, leaves_counts, batch, cap: int):
-        """One sharded micro-batch into the state.  ``batch`` = (dest,
-        slots, pane_slots, *values), each row-split over the mesh; ``cap``
-        = per-(src, dest) bucket capacity (host-known upper bound, so the
-        exchange can never overflow)."""
-        return self._sharded_fold(leaves_counts, batch, cap,
-                                  self.agg.combine_leaves)
-
     def _values_tree(self, flat_values):
         """Rebuild the user value tree from the flat leaves that rode the
         exchange (set by ``_flatten_values`` on the host side)."""
         treedef = self._values_treedef
         return jax.tree_util.tree_unflatten(treedef, list(flat_values))
 
-    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
-    def _mesh_delta_step(self, dleaves_counts, batch, cap: int):
-        """Device-probe DELTA fold over the mesh: the same pipeline as
-        ``_mesh_update_step``, but into the sharded delta ring (mirror
-        dtypes — warm-row contributions carry the host mirror's f64/i64
-        precision and fold into it later via ``wm_apply_delta``)."""
-        return self._sharded_fold(dleaves_counts, batch, cap, None)
-
-    @partial(jax.jit, static_argnums=(0,))
-    def _mesh_probe_step(self, tab, b, key_lo, key_hi, start):
-        """The device-resident key probe as its own dispatch: the mesh
-        routing (bucket plan, sticky capacity) is host-computed from the
-        resolved slots, so the probe runs once up front and the slots ride
-        back with the scalar miss count."""
-        from flink_tpu.state.device_keyindex import lax_probe
-        slot = lax_probe(*tab, key_lo, key_hi, start)
-        valid = jnp.arange(slot.shape[0], dtype=jnp.int32) < b
-        miss = valid & (slot < 0)
-        return slot, jnp.sum(miss, dtype=jnp.int32)
-
-    def _hot_stage_devprobe(self, keys: np.ndarray, panes: np.ndarray,
-                            values, B: int, sync: str) -> None:
-        """Mesh device-probe hot stage: probe on device, route the warm
-        rows' delta fold (and, under scatter sync, the full state fold)
-        through the all_to_all exchange; the host C pass touches only the
-        miss rows (sharded by the same contiguous slot ranges as ever)."""
-        from flink_tpu.runtime import device_health
-        self._ensure_alloc()
-        self._ensure_delta()
-        if self._dki is None:
-            from flink_tpu.state.device_keyindex import DeviceKeyIndex
-            self._dki = DeviceKeyIndex(
-                initial_capacity=max(1 << 16, 2 * self._K),
-                sharding=self._devprobe_table_sharding())
-        self._dki.ensure_loaded(self.key_index)
-        mi = np.empty(0, np.int64)
-        with self._phase("device_probe"):
-            key_lo, key_hi, start = self._dki.prepare_batch(keys)
-            Bp = _next_pow2(B, 64)
-
-            def pad32(a, fill=0):
-                out = np.full(Bp, fill, np.int32)
-                out[:B] = a
-                return out
-
-            klo_p, khi_p, st_p = pad32(key_lo), pad32(key_hi), pad32(start)
-            geom = ("mesh_devprobe", self._dki.capacity, Bp)
-            fresh_geom = geom != getattr(self, "_last_dispatch_geom", None)
-            self._last_dispatch_geom = geom
-
-            def thunk():
-                slot_d, miss_d = self._mesh_probe_step(
-                    self._dki.table(), np.int32(B), jnp.asarray(klo_p),
-                    jnp.asarray(khi_p), jnp.asarray(st_p))
-                return slot_d, int(miss_d)
-
-            try:
-                self._hot_dispatches += 1
-                slot_d, mc = device_health.guarded_dispatch(
-                    thunk, mb=12 * Bp / 1e6, on_oom=None,
-                    label=f"{self.name}.device_probe",
-                    compile_grace=fresh_geom)
-            except DeviceQuarantinedError as err:
-                self._devprobe_degrade(err, keys, panes, values)
-                return
-            slots = np.array(np.asarray(slot_d)[:B], np.int32)
-            self._dp_stats["probe_hits"] += B - mc
-            self._dp_stats["probe_misses"] += mc
-        if mc:
-            mi = np.flatnonzero(slots < 0)
-            mkeys = np.ascontiguousarray(keys[mi])
-            mpanes = np.ascontiguousarray(panes[mi])
-            mvalues = jax.tree_util.tree_map(lambda a: np.asarray(a)[mi],
-                                             values)
-            slots[mi] = self._devprobe_absorb_misses(mkeys, mpanes, mvalues)
-        panes_mod = (panes % self._P).astype(np.int32)
-        hit_mask = np.ones(B, bool)
-        if mc:
-            hit_mask[mi] = False
-        mb = sum(np.asarray(a).nbytes for a in
-                 jax.tree_util.tree_leaves(values)) / 1e6
-        if hit_mask.any():
-            h_idx = np.flatnonzero(hit_mask)
-            h_vals = jax.tree_util.tree_map(
-                lambda a: np.asarray(a)[h_idx], values)
-            try:
-                with self._phase("device_probe"):
-                    self._hot_dispatches += 1
-                    device_health.guarded_dispatch(
-                        lambda: self._apply_delta_update(
-                            h_vals, int(h_idx.size), slots[h_idx],
-                            panes_mod[h_idx]),
-                        mb=mb, label=f"{self.name}.delta_fold")
-            except DeviceQuarantinedError as err:
-                # warm rows never reached the delta (the chaos/dispatch
-                # failure precedes execution): refold exactly those rows
-                # on the host; misses are already in the mirror
-                self._devprobe_degrade(
-                    err, np.ascontiguousarray(keys[h_idx]),
-                    np.ascontiguousarray(panes[h_idx]), h_vals)
-                return
-            self._delta_panes.update(
-                int(p) for p in np.unique(panes[h_idx]).tolist())
-        if sync == "deferred":
-            self._device_stale = True
-        else:
-            values_np = jax.tree_util.tree_map(np.asarray, values)
-            try:
-                with self._phase("device_dispatch"):
-                    self._hot_dispatches += 1
-                    device_health.guarded_dispatch(
-                        lambda: self._apply_update(values_np, B, slots,
-                                                   panes_mod),
-                        mb=mb, label=f"{self.name}.update_step")
-            except DeviceQuarantinedError as err:
-                # every record is in mirror-land already (delta + misses):
-                # degrade without refolding
-                self._devprobe_degrade(err)
-
-    def devprobe_step_cache_size(self):
-        """Mesh twin of the probed-step recompile smoke: the probe and
-        delta steps must each compile once per (table capacity / batch
-        geometry, exchange capacity)."""
-        out = super().devprobe_step_cache_size()
-        for name in ("_mesh_probe_step", "_mesh_delta_step"):
-            fn = getattr(type(self), name)
-            try:
-                out[name] = int(fn._cache_size())
-            except Exception:  # noqa: BLE001 — jax without the cache probe
-                out[name] = -1
-        return out
-
     # ------------------------------------------------------------- host side
     def _route_batch(self, values, B: int, slots: np.ndarray,
                      panes: np.ndarray):
-        """Shared exchange routing for the state and delta folds: pad rows
-        to the mesh, compute destination shards, pick the STICKY bucket
-        capacity, and device_put the row-split batch.  Returns
-        ``(batch, cap)`` for a ``_mesh_*_step`` dispatch.  Timed as phase
-        ``exchange_route`` (inside ``device_dispatch`` on the state fold's
-        path); ``phase_bytes`` counts what the exchange then moves."""
+        """The exchange's host routing: pad rows to the mesh, compute
+        destination shards, pick the STICKY bucket capacity, and
+        device_put the row-split batch.  Returns ``(batch, cap)`` for the
+        ``_mesh_update_step`` dispatch.  Timed as phase ``exchange_route``
+        (inside ``device_dispatch``); ``phase_bytes`` counts what the
+        exchange then moves."""
         with self._phase("exchange_route"):
             D = self.n_shards
             K = self._K
@@ -429,15 +284,6 @@ class MeshWindowAggOperator(WindowAggOperator):
         batch, cap = self._route_batch(values, B, slots, panes)
         self._leaves, self._counts = self._mesh_update_step(
             (self._leaves, self._counts), batch, cap)
-
-    def _apply_delta_update(self, values, B: int, slots: np.ndarray,
-                            panes: np.ndarray) -> None:
-        """Device-probe warm rows: fold into the SHARDED delta ring via the
-        same exchange (mirror precision — x64-scoped trace)."""
-        batch, cap = self._route_batch(values, B, slots, panes)
-        with _x64():
-            self._delta_leaves, self._delta_counts = self._mesh_delta_step(
-                (self._delta_leaves, self._delta_counts), batch, cap)
 
     def _update_step(self, layout, leaves, counts, flat_ids, values):  # type: ignore[override]
         """Intercept the base class's device dispatch (the rest of the host

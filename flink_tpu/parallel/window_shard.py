@@ -14,12 +14,7 @@ operator whose
 - probe/mirror maintenance shards by the same contiguous slot ranges
   (per-shard probes; ``phase_shard_ns`` breakdown),
 - snapshots are per-shard slices with key-group-range manifests,
-  rescalable across mesh sizes,
-- the one-dispatch fused lane (ISSUE-11) stages super-batches through the
-  fused HOST pass: the C probe, the ``all_to_all`` exchange, and the
-  device-probe dispatch each run once per super-batch (``superbatch=``
-  kwarg; the single-dispatch ``lax.scan`` megastep itself stays off on
-  the mesh — its exchange routing is host-computed per batch).
+  rescalable across mesh sizes.
 
 This mirrors how the reference scales ``keyBy``: identical operator logic
 per subtask, state split by key-group range
@@ -44,10 +39,8 @@ def sharded_window_operator(mesh: Optional[Mesh] = None, *,
                             n_devices: Optional[int] = None,
                             **kwargs) -> WindowAggOperator:
     """A window operator whose keyed state, probe path, and record route
-    are sharded over ``mesh`` (the full mesh runtime).  ``superbatch=N``
-    stages N micro-batches per fused pass (0 = auto-calibrated, the
-    ISSUE-11 fused lane); all other ``WindowAggOperator`` kwargs pass
-    through unchanged."""
+    are sharded over ``mesh`` (the full mesh runtime); every
+    ``WindowAggOperator`` kwarg passes through unchanged."""
     from flink_tpu.parallel.mesh_runtime import MeshWindowAggOperator
     if mesh is None:
         mesh = make_mesh(n_devices)

@@ -5,10 +5,8 @@ Instead of probing the operator's key index from a foreign thread (the old
 ``server.py`` stub — a read racing the task thread's backend), the operator
 PUBLISHES an immutable columnar view of every window it fires: the very
 ``(keys, values)`` arrays the fire emitted downstream, tagged with the
-watermark and last-completed-checkpoint id they reflect.  Those values come
-off the host value mirror after the pane-granular device-delta catch-up
-(``_fire_window_host`` -> ``_devprobe_sync_mirror`` -> ``wm_apply_delta``),
-so a live read is **bit-equal to the operator's own fire-time values** for
+watermark and last-completed-checkpoint id they reflect.  A live read is
+therefore **bit-equal to the operator's own fire-time values** for
 already-fired panes — on any tier (host/device/deferred), at any mesh size,
 and through a quarantine degrade, because every fire path funnels through
 the same publish hook.

@@ -287,9 +287,7 @@ class DeviceHealthMonitor:
             "watchdog_timeouts": 0, "transient_retries": 0,
             "oom_pageouts": 0, "near_misses": 0, "probe_attempts": 0,
         }
-        #: guarded dispatches per label (e.g. "win.fused_scan") — the
-        #: fused-megastep era's dispatch accounting: dispatches/batch is
-        #: the metric the one-dispatch scan lane exists to shrink, and the
+        #: guarded dispatches per label (e.g. "win.update_step"): the
         #: per-site breakdown shows WHICH dispatch a regression added
         self.label_counts: Dict[str, int] = {}
 

@@ -70,9 +70,8 @@ def measure_fused_probe(lib, shards: int, n_keys: int, B: int,
                         keys_all: np.ndarray, vals_all: np.ndarray,
                         rounds: int = 3) -> float:
     """Best-of-``rounds`` wall seconds of the fused C probe+fold at
-    ``shards`` over a warm ``n_keys`` table — the shared measurement
-    harness of the native-shards A/B and the device-probe calibration
-    (state/device_keyindex).  ``keys_all``/``vals_all`` hold ``rounds``
+    ``shards`` over a warm ``n_keys`` table — the measurement of the
+    native-shards A/B.  ``keys_all``/``vals_all`` hold ``rounds``
     consecutive batches of ``B``.  The throwaway keydict/mirror pair is
     released via try/finally even on a mid-measurement failure."""
     import time
@@ -276,26 +275,6 @@ class NativeWindowMirror:
             slots.ctypes.data, pane_mod, flat_ptr, flat_cap,
             int(flat_fill), max(1, int(shards)), int(shard_div), ns_ptr)
         return slots
-
-    def apply_delta(self, pane: int, counts: np.ndarray,
-                    leaves: List[np.ndarray]) -> None:
-        """Fold a pane-granular DELTA (warm-key contributions accumulated on
-        the device by the device-resident key probe) into the mirror:
-        counts add, each leaf combines by its declared kind.  Delta rows are
-        identity-initialized, so untouched rows fold as no-ops."""
-        counts = np.ascontiguousarray(counts, np.int64)
-        nl = len(self._mirror_dtypes)
-        arrs = []
-        vdt = (ctypes.c_uint8 * nl)()
-        for j, l in enumerate(leaves):
-            a = np.ascontiguousarray(l)
-            if a.dtype not in _VDT:
-                a = a.astype(np.float64)
-            arrs.append(a)
-            vdt[j] = _VDT[a.dtype]
-        ptrs = (ctypes.c_void_p * nl)(*[a.ctypes.data for a in arrs])
-        self._lib.wm_apply_delta(self._h, int(pane), counts.size,
-                                 counts.ctypes.data, ptrs, vdt)
 
     def fire(self, panes: np.ndarray
              ) -> Tuple[np.ndarray, np.ndarray, List[np.ndarray]]:

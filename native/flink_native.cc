@@ -1370,54 +1370,6 @@ API i64 wm_fire(void* h, const i64* pane_ids, i32 npanes, i64* out_keys,
   return m;
 }
 
-// Fold a pane-granular DELTA into the mirror (the device-resident key
-// probe's catch-up path, state/device_keyindex.py): ``counts`` adds into the
-// per-row element counts, each leaf column combines by its kind.  The delta
-// columns are identity-initialized on device, so folding an untouched row
-// is a no-op by construction (add identity 0, min/max identities compare
-// away) — no mask is needed.  Rows past the pane's current capacity grow it
-// first, like wm_import_pane.
-API void wm_apply_delta(void* h, i64 pane, i64 nrows, const i64* counts,
-                        const void* const* vals, const u8* vdt) {
-  auto* w = (WinMirror*)h;
-  i64 need = nrows > w->dict->n ? nrows : w->dict->n;
-  MirrorPane* mp = w->ensure_pane(pane, need);
-  u8* base = mp->rows.p;
-  const i64 stride = w->stride;
-  for (i64 s = 0; s < nrows; s++) {
-    u8* row = base + s * stride;
-    *(i64*)row += counts[s];
-    for (int l = 0; l < w->nl; l++) {
-      u8* cell = row + 8 + 8 * l;
-      if (w->lt[l] == 0) {
-        double x;
-        switch (vdt[l]) {
-          case VF64: x = ((const double*)vals[l])[s]; break;
-          case VF32: x = (double)((const float*)vals[l])[s]; break;
-          case VI64: x = (double)((const i64*)vals[l])[s]; break;
-          default:   x = (double)((const i32*)vals[l])[s]; break;
-        }
-        double* c = (double*)cell;
-        if (w->kind[l] == 0) *c += x;
-        else if (w->kind[l] == 1) { if (x < *c) *c = x; }
-        else { if (x > *c) *c = x; }
-      } else {
-        i64 x;
-        switch (vdt[l]) {
-          case VF64: x = (i64)((const double*)vals[l])[s]; break;
-          case VF32: x = (i64)((const float*)vals[l])[s]; break;
-          case VI64: x = ((const i64*)vals[l])[s]; break;
-          default:   x = (i64)((const i32*)vals[l])[s]; break;
-        }
-        i64* c = (i64*)cell;
-        if (w->kind[l] == 0) *c += x;
-        else if (w->kind[l] == 1) { if (x < *c) *c = x; }
-        else { if (x > *c) *c = x; }
-      }
-    }
-  }
-}
-
 // De-interleave one pane's first `nrows` rows into columnar buffers
 // (snapshots, verification).  Rows beyond the pane's capacity export as
 // count 0 / identity.  Returns 1 if the pane exists, else 0 (buffers are

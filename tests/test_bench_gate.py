@@ -85,30 +85,6 @@ def test_check_budget_probe_mirror_frac():
     assert check_budget(_result(phases={"probe_mirror": 950.0}), b) == []
 
 
-def test_check_budget_probe_hit_rate_floor():
-    """*_device sections gate the device-resident key probe: a hit rate
-    under the floor (the table not absorbing the warm-key steady state)
-    is a violation — but ONLY when the probe resolved on (auto
-    calibration may legitimately pick it off)."""
-    b = _budget(min_probe_hit_rate=0.8)
-    ok = _result()
-    ok["details"]["device_probe"] = "on"
-    ok["details"]["probe_hit_rate"] = 0.97
-    assert check_budget(ok, b) == []
-    bad = _result()
-    bad["details"]["device_probe"] = "on"
-    bad["details"]["probe_hit_rate"] = 0.4
-    viol = check_budget(bad, b)
-    assert len(viol) == 1 and "probe_hit_rate" in viol[0]
-    # probe calibrated OFF: the floor must not fire
-    off = _result()
-    off["details"]["device_probe"] = "off"
-    off["details"]["probe_hit_rate"] = 0.0
-    assert check_budget(off, b) == []
-    # no probe fields at all (pre-probe result shapes): not a violation
-    assert check_budget(_result(), b) == []
-
-
 def _mesh_result(rps_pod=4e6, per_shard=(150.0, 120.0), phases=None,
                  ok=True):
     return {"records_per_sec_pod": rps_pod, "ok": ok,
@@ -440,89 +416,6 @@ def test_trace_artifact_smoke(tmp_path):
     assert all(e["ph"] in ("X", "i", "M") for e in evs)
 
 
-# ---------------------------------------------------------------------------
-# the fused-megastep gate (bench.py --superbatch --check, ISSUE-11)
-# ---------------------------------------------------------------------------
-
-def _fused_result(vs_numpy=2.0, dpb=0.5, eq=True):
-    return {"value": 10e6, "vs_numpy_baseline": vs_numpy,
-            "details": {"fused": {"enabled": True, "superbatch": 8,
-                                  "dispatches_per_batch": dpb,
-                                  "equivalence_ok": eq}}}
-
-
-def _fused_budget(**kw):
-    b = {"min_vs_numpy": 1.0, "max_dispatches_per_batch": 1.0}
-    b.update(kw)
-    return b
-
-
-def test_check_fused_budget_pass():
-    from bench import check_fused_budget
-    assert check_fused_budget(_fused_result(), _fused_budget()) == []
-
-
-def test_check_fused_budget_equivalence_always_gates():
-    """Divergent fused-on/off digests must never exit 0 — smoke size,
-    missing floors, nothing exempts it."""
-    from bench import check_fused_budget
-    viol = check_fused_budget(_fused_result(eq=False), {}, smoke=True)
-    assert viol and "equivalence" in viol[0]
-
-
-def test_check_fused_budget_dispatch_ceiling():
-    from bench import check_fused_budget
-    viol = check_fused_budget(_fused_result(dpb=2.5), _fused_budget())
-    assert any("dispatches/batch" in v for v in viol)
-    assert check_fused_budget(_fused_result(dpb=1.0), _fused_budget()) == []
-
-
-def test_check_fused_budget_ceiling_needs_enabled_lane():
-    """A run whose fused lane resolved (or was forced) OFF never claimed
-    one-dispatch amortization: the per-batch device-probe scatter path is
-    structurally 2 dispatches/batch on cold keys (probe + miss update),
-    and --superbatch 1 --check must not fail it.  The digest equivalence
-    still gates."""
-    from bench import check_fused_budget
-    r = _fused_result(dpb=2.0)
-    r["details"]["fused"]["enabled"] = False
-    assert check_fused_budget(r, _fused_budget()) == []
-    r["details"]["fused"]["equivalence_ok"] = False
-    assert any("equivalence" in v
-               for v in check_fused_budget(r, _fused_budget()))
-
-
-def test_check_fused_budget_vs_numpy_floor_full_only():
-    from bench import check_fused_budget
-    r = _fused_result(vs_numpy=0.5)
-    assert any("vs_numpy" in v
-               for v in check_fused_budget(r, _fused_budget()))
-    # smoke runs are one batch of fixed costs: the ratio floor is waived,
-    # the structural checks are not
-    assert check_fused_budget(r, _fused_budget(), smoke=True) == []
-
-
-def test_superbatch_bench_reports_fused_and_passes_gate(tmp_path):
-    """bench.py --smoke --superbatch 4 reports the fused detail block —
-    resolved depth, dispatches/batch, scan compile counts, the in-run
-    on/off equivalence — and exits 0 under --check.  Default smoke
-    geometry on purpose: custom-shrunk geometries flip the sync
-    calibration and cannot meet the smoke_cpu rps floor even unfused
-    (the same reason the --trace smoke runs without --check)."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-         "--superbatch", "4", "--check"],
-        capture_output=True, text=True, timeout=600, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, (proc.stdout, proc.stderr[-2000:])
-    result = json.loads(proc.stdout.strip().splitlines()[-1])
-    fu = result["details"]["fused"]
-    assert fu["enabled"] and fu["superbatch"] == 4
-    assert fu["equivalence_ok"] is True
-    assert fu["staged_batches"] > 0 and fu["flushes"] > 0
-    assert fu["dispatches_per_batch"] <= 1.0
-
-
 def test_budget_file_shape():
     with open(os.path.join(REPO, "BENCH_BUDGET.json")) as f:
         budget = json.load(f)
@@ -561,11 +454,6 @@ def test_budget_file_shape():
     assert cep["min_matches_per_sec"] > 0
     assert cep["min_speedup_vs_interpreted"] >= 3.0
     assert 0 < cep["min_speedup_smoke"] <= cep["min_speedup_vs_interpreted"]
-    # the fused-megastep gate (bench.py --superbatch --check, ISSUE-11):
-    # the one-dispatch claim plus the CPU-tier vs-numpy floor
-    fused = budget["fused_cpu"]
-    assert fused["max_dispatches_per_batch"] >= 1.0
-    assert fused["min_vs_numpy"] >= budget["full_cpu"]["min_vs_numpy"]
     # the scenario-suite gates (bench.py --scenario --check, ISSUE-15):
     # every scenario must demand >= 1 autoscaler reaction; perf floors
     # exist for the full tier (exactly-once gates unconditionally in code)
@@ -581,9 +469,6 @@ def test_budget_file_shape():
     for tier in ("full_device", "smoke_device"):
         sec = budget[tier]
         assert sec["min_rps"] > 0 and sec["max_p99_ms"] > 0
-        assert 0 < sec["min_probe_hit_rate"] <= 1.0
-        assert "device_probe" in sec["max_phase_ms"]
-        assert "delta_sync" in sec["max_phase_ms"]
 
 
 def _operator_phase_names():

@@ -114,13 +114,13 @@ def test_mesh_suite_collects_under_tier1():
              f"coverage left the gate")
 
 
-def test_device_probe_suite_collects_under_tier1():
-    """The device-resident key probe suite (ISSUE-7) must contribute tests
-    to the tier-1 run under ``JAX_PLATFORMS=cpu`` — the pure-lax probe
-    fallback exists precisely so this coverage never leaves the gate."""
+@__import__("functools").lru_cache(maxsize=None)
+def _window_lanes_tier1_ids():
+    """Node ids ``tests/test_window_lanes.py`` contributes to the tier-1
+    selection (``-m 'not slow'`` under ``JAX_PLATFORMS=cpu``)."""
     import subprocess
 
-    f = "test_device_keyindex.py"
+    f = "test_window_lanes.py"
     assert (TESTS / f).exists(), f
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "--collect-only", "-q",
@@ -128,9 +128,18 @@ def test_device_probe_suite_collects_under_tier1():
         capture_output=True, text=True, timeout=300, cwd=str(REPO),
         env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert f"{f}::" in proc.stdout, \
-        (f"{f} contributes no tests to the tier-1 selection — the device "
-         f"probe's digest-equality coverage left the gate")
+    return [line for line in proc.stdout.splitlines() if f"{f}::" in line]
+
+
+def test_window_lanes_suite_collects_under_tier1():
+    """The lane suite is what checks every remaining fold lane against
+    the plain reference (and stands in for the count of the device-probe
+    and fused-step suites that went with their code): at least 40 of its
+    cases must stay in the tier-1 run."""
+    ids = _window_lanes_tier1_ids()
+    assert len(ids) >= 40, \
+        (f"test_window_lanes.py contributes {len(ids)} tests to the tier-1 "
+         f"selection — lane coverage left the gate")
 
 
 def test_cep_vectorized_suite_collects_under_tier1():
@@ -216,26 +225,19 @@ def test_tracing_suite_collects_under_tier1():
          f"observability suite left the gate")
 
 
-def test_fused_step_suite_collects_under_tier1():
-    """The one-dispatch fused megastep suite (ISSUE-11) must contribute
-    tests to the tier-1 run under ``JAX_PLATFORMS=cpu`` — the fused
-    on/off digest+snapshot+counter equality, the compile-once smoke, and
-    the mid-scan quarantine salvage all run on the CPU backend (the lax
-    scan lane needs no TPU), so a slow-mark sweep that silently drops
-    them fails here."""
-    import subprocess
-
-    f = "test_fused_step.py"
-    assert (TESTS / f).exists(), f
-    proc = subprocess.run(
-        [sys.executable, "-m", "pytest", "--collect-only", "-q",
-         "-m", "not slow", "-p", "no:cacheprovider", str(TESTS / f)],
-        capture_output=True, text=True, timeout=300, cwd=str(REPO),
-        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert f"{f}::" in proc.stdout, \
-        (f"{f} contributes no tests to the tier-1 selection — the fused "
-         f"megastep's bit-identity coverage left the gate")
+def test_window_lanes_suite_keeps_every_lane_in_tier1():
+    """A slow-mark sweep that drops one lane's cases (the mesh's, say)
+    leaves the count above its floor: every lane must keep a reference
+    case AND a restore case in the tier-1 run."""
+    ids = _window_lanes_tier1_ids()
+    for lane in ("device", "host_scatter", "host_deferred", "host_numpy",
+                 "device_pipelined", "mesh2_device"):
+        # ids: ...reference[<lane>-<job>-<order>], ...lanes[<job>-<a>-<b>]
+        assert any("test_lane_delivers_the_reference[" + lane + "-" in i
+                   for i in ids), f"no reference case left for {lane!r}"
+        assert any("test_snapshot_restores_across_lanes[" in i
+                   and i.endswith("-" + lane + "]") for i in ids), \
+            f"no restore case left into {lane!r}"
 
 
 def test_rescale_under_fire_suite_collects_under_tier1():
