@@ -13,12 +13,14 @@ the TPU-native analog of the reference's keyed exchange being the runtime
 environment is given a mesh (``StreamExecutionEnvironment(mesh=...)``).
 
 Design notes (TPU-first):
-- **No overflow, no flow-control sync in the hot loop.**  The host computes
-  every record's destination shard (it assigns dense key slots anyway —
-  the record-serializer role), so the per-``(src, dest)`` bucket capacity is
-  KNOWN before dispatch; the exchange compiles at a quantized capacity that
-  always fits.  The general device-side-destination case with capacity
-  renegotiation lives in ``parallel/exchange.py`` (``ResizingExchange``).
+- **No overflow, no flow-control sync in the hot loop.**  The host assigns
+  dense key slots (the record-serializer role), and a record's destination
+  shard is a function of its slot, so the per-``(src, dest)`` bucket
+  capacity is KNOWN before dispatch; the exchange compiles at a quantized
+  capacity that always fits.  The destination itself is derived on the
+  device, where the id already is: the host ships the staged flat ids and
+  value columns as they are.  The general case with capacity renegotiation
+  lives in ``parallel/exchange.py`` (``ResizingExchange``).
 - **One jitted step per micro-batch**: bucket → ``all_to_all`` (ICI) →
   local scatter-combine, all inside one ``shard_map`` — XLA overlaps the
   collective with the scatter epilogue.
@@ -41,7 +43,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flink_tpu.operators.session_window import SessionWindowOperator
-from flink_tpu.operators.window_agg import WindowAggOperator, _next_pow2
+from flink_tpu.operators.window_agg import (WindowAggOperator, _PAD_ID,
+                                            _next_pow2)
 from flink_tpu.ops.pane_layout import KeyGrid
 from flink_tpu.parallel.mesh import KG_AXIS, make_mesh, state_sharding
 
@@ -86,8 +89,11 @@ class MeshWindowAggOperator(WindowAggOperator):
 
     Chained dispatches stay pre-partitioned end-to-end: state flows out of
     the ``shard_map`` step with ``out_specs == in_specs`` (key-slot axis on
-    ``KG_AXIS``), batch rows are ``device_put`` pre-partitioned onto the
-    same axis, and nothing in between reshards — one XLA compile per
+    ``KG_AXIS``), a batch is the base class's staged ``(flat_ids,
+    *values)`` ``device_put`` row-split onto the same axis in one call (the
+    flat id and the value columns ride the exchange; slot, pane and
+    destination are derived from the id on the device), and nothing in
+    between reshards — one XLA compile per
     (mesh size, K_cap, batch geometry), asserted by the tier-1 smoke via
     :meth:`mesh_step_cache_size`.
     """
@@ -164,18 +170,24 @@ class MeshWindowAggOperator(WindowAggOperator):
     def _mesh_update_step(self, leaves_counts, batch, cap: int):
         """One sharded micro-batch into the state: per-device bucket by
         destination → ``all_to_all`` over ICI → scatter-combine into the
-        local block.  ``batch`` = (dest, slots, pane_slots, *values), each
-        row-split over the mesh; ``cap`` = per-(src, dest) bucket capacity
-        (host-known upper bound, so the exchange can never overflow).  The
-        named scopes are each stage's name in the program's HLO (every
-        operation's ``op_name``), so a device trace's operations can be
-        told apart by stage."""
+        local block.  ``batch`` = (flat_ids, *values), each row-split over
+        the mesh: the base class's staged ``slot * P + pane`` ids as they
+        are, padding rows (any id at or past ``K * P``) included.  The
+        destination shard is derived HERE from the id — key slots are
+        owned in contiguous blocks, so it is one division — and padding
+        rows are spread over the shards by their row index.  ``cap`` =
+        per-(src, dest) bucket capacity (host-known upper bound, so the
+        exchange can never overflow; :meth:`_pair_counts` is this rule's
+        host twin).  One id column and the value columns ride the
+        exchange.  The named scopes are each stage's name in the program's
+        HLO (every operation's ``op_name``), so a device trace's
+        operations can be told apart by stage."""
         leaves, counts = leaves_counts
         D = self.n_shards
         K, Pn = counts.shape
-        KD = K // D
+        span = (K // D) * Pn          # flat ids one shard owns
 
-        def step(leaves, counts, dest, slots, pane_slots, *values):
+        def step(leaves, counts, ids, *values):
             from flink_tpu.parallel.exchange import (all_to_all_rows,
                                                      bucket_plan,
                                                      bucket_rows)
@@ -183,32 +195,32 @@ class MeshWindowAggOperator(WindowAggOperator):
             # STABLE plan keeps each key's records in batch order through
             # the exchange (bit-identical per-cell accumulation at any D)
             with jax.named_scope("exchange_bucket"):
+                row = jnp.arange(ids.shape[0], dtype=jnp.int32)
+                dest = jnp.where(ids < K * Pn, ids // span, row % D)
                 order, flat, _valid = bucket_plan(dest, D, cap)
                 bucket = lambda a, fill: bucket_rows(a, order, flat, D,  # noqa: E731
                                                      cap, fill)
-                b_slots = bucket(slots, K)           # K = invalid sentinel
-                b_panes = bucket(pane_slots, 0)
+                b_ids = bucket(ids, K * Pn)        # K * Pn = dropped id
                 b_vals = [bucket(v, 0) for v in values]
             # ---- the keyed exchange: one collective over ICI per array
             with jax.named_scope("exchange_all_to_all"):
-                rx_slots = all_to_all_rows(b_slots).reshape(D * cap)
-                rx_panes = all_to_all_rows(b_panes).reshape(D * cap)
+                rx_ids = all_to_all_rows(b_ids).reshape(D * cap)
                 rx_vals = tuple(all_to_all_rows(v).reshape((D * cap,)
                                                            + v.shape[2:])
                                 for v in b_vals)
             # ---- local scatter-combine (this device's key-slot block):
             # the single-chip fold; rows that are not ``ok`` carry the
-            # dropped id KD * Pn
+            # dropped id ``span``
             with jax.named_scope("shard_fold"):
-                lo = jax.lax.axis_index(KG_AXIS).astype(jnp.int32) * KD
-                local = rx_slots - lo
-                ok = (rx_slots < K) & (local >= 0) & (local < KD)
-                lflat = jnp.where(ok, local * Pn + rx_panes, KD * Pn)
+                lo = jax.lax.axis_index(KG_AXIS).astype(jnp.int32) * span
+                local = rx_ids - lo
+                ok = (rx_ids < K * Pn) & (local >= 0) & (local < span)
+                lflat = jnp.where(ok, local, span)
                 lifted = tuple(jax.tree_util.tree_leaves(
                     self.agg.lift(self._values_tree(rx_vals))))
-                return KeyGrid(KD, Pn).fold(leaves, counts, lflat, lifted,
-                                            self.kinds,
-                                            self.agg.combine_leaves)
+                return KeyGrid(K // D, Pn).fold(leaves, counts, lflat,
+                                                lifted, self.kinds,
+                                                self.agg.combine_leaves)
 
         rows = P(KG_AXIS)
         state_specs = ((rows,) * len(leaves), rows)
@@ -219,86 +231,99 @@ class MeshWindowAggOperator(WindowAggOperator):
 
     def _values_tree(self, flat_values):
         """Rebuild the user value tree from the flat leaves that rode the
-        exchange (set by ``_flatten_values`` on the host side)."""
+        exchange (set by ``_route_batch`` on the host side)."""
         treedef = self._values_treedef
         return jax.tree_util.tree_unflatten(treedef, list(flat_values))
 
     # ------------------------------------------------------------- host side
-    def _route_batch(self, values, B: int, slots: np.ndarray,
-                     panes: np.ndarray):
-        """The exchange's host routing: pad rows to the mesh, compute
-        destination shards, pick the STICKY bucket capacity, and
-        device_put the row-split batch.  Returns ``(batch, cap)`` for the
-        ``_mesh_update_step`` dispatch.  Timed as phase ``exchange_route``
-        (inside ``device_dispatch``); ``phase_bytes`` counts what the
-        exchange then moves."""
+    def _pair_counts(self, ids: np.ndarray, live: np.ndarray) -> np.ndarray:
+        """Rows each source block sends to each shard, ``[D, D]``: the
+        host twin of the destination rule in :meth:`_mesh_update_step`
+        (``ids``, ``live`` are ``[D, block]``; a live row goes to the
+        shard owning its id, a padding row to ``row index % D``)."""
+        D = self.n_shards
+        span = (self._K // D) * self._P
+        if span & (span - 1):
+            dest = ids // span
+        else:
+            dest = ids >> (span.bit_length() - 1)
+        dest = np.where(live, dest,
+                        np.arange(ids.shape[1], dtype=np.int32) % D)
+        dest += (np.arange(D, dtype=np.int32) * D)[:, None]
+        return np.bincount(dest.ravel(), minlength=D * D).reshape(D, D)
+
+    def _route_batch(self, flat_ids, values):
+        """The exchange's host routing: hand the staged ``(flat_ids,
+        *values)`` to the mesh row-split, AS THEY ARE wherever the staged
+        length already divides by D (else one padded copy, read off the
+        buffer's shape), pick the STICKY bucket capacity, and
+        ``device_put`` the tuple once.  Everything that is a function of
+        the id — slot, pane, destination shard — is derived on the
+        device.  Returns ``(batch, cap)`` for the ``_mesh_update_step``
+        dispatch.  Timed as phase ``exchange_route`` (inside
+        ``device_dispatch``).  ``phase_bytes`` counts what the exchange
+        then moves (``exchange_sent``, ``exchange_live``) and, beside
+        them, how the routing went: ``exchange_route_batches``, of which
+        ``exchange_route_copied`` needed the padded copy and
+        ``exchange_cap_counts_skipped`` took no capacity count."""
         with self._phase("exchange_route"):
             D = self.n_shards
-            K = self._K
-            KD = K // D
-            # pad rows to a multiple of D with invalid-slot sentinels
-            # (quantized for a bounded compile count, then re-rounded: D
-            # may not be pow2)
-            Bp = -(-_quantize(-(-B // D) * D, D) // D) * D
+            ids = np.asarray(flat_ids)
+            vleaves, self._values_treedef = jax.tree_util.tree_flatten(values)
+            vleaves = [np.asarray(v) for v in vleaves]
+            B = ids.shape[0]
+            block = -(-B // D)
+            copied = block * D != B
+            if copied:
+                # the staged length does not split over the mesh (D is no
+                # power of two): pad to the next multiple of D
 
-            def pad(a, fill, dtype):
-                out = np.full((Bp,) + a.shape[1:], fill, dtype)
-                out[:B] = a[:B]
-                return out
+                def pad(a, fill):
+                    out = np.full((block * D,) + a.shape[1:], fill, a.dtype)
+                    out[:B] = a
+                    return out
 
-            slots_p = pad(slots.astype(np.int32), K, np.int32)
-            panes_p = pad(panes.astype(np.int32), 0, np.int32)
-            dest = np.minimum(slots_p.astype(np.int64) // KD, D - 1).astype(
-                np.int32)
-            dest[B:] = np.arange(Bp - B) % D  # spread pad rows evenly
+                ids = pad(ids, _PAD_ID)
+                vleaves = [pad(v, 0) for v in vleaves]
+            live = ids < self._K * self._P
+            n_live = int(np.count_nonzero(live))
             # host-known capacity: max rows any (src block, dest) pair
             # sends.  STICKY high-water (the credit-capacity-only-grows
             # rule of ResizingExchange): batch-to-batch skew wobble must
             # not recompile the step — steady state is exactly one compile
             # per (mesh, K, batch geometry), which the tier-1 recompile
-            # smoke asserts
-            src = np.repeat(np.arange(D), Bp // D)
-            per_pair = np.bincount(src * D + dest, minlength=D * D)
-            cap = _quantize(int(per_pair.max()))
-            cap = self._exchange_cap_hw = max(self._exchange_cap_hw, cap)
-            vleaves, self._values_treedef = jax.tree_util.tree_flatten(values)
-            vleaves = [np.asarray(v) for v in vleaves]
-            put = lambda a: jax.device_put(a, self._row_sharding)  # noqa: E731
-            batch = (put(dest), put(slots_p), put(panes_p),
-                     *(put(pad(v, 0, v.dtype)) for v in vleaves))
+            # smoke asserts.  No pair can send more than its block, so at
+            # that ceiling nothing is left to count
+            skipped = self._exchange_cap_hw >= block
+            if not skipped:
+                per_pair = self._pair_counts(ids.reshape(D, block),
+                                             live.reshape(D, block))
+                self._exchange_cap_hw = max(self._exchange_cap_hw,
+                                            _quantize(int(per_pair.max())))
+            cap = self._exchange_cap_hw
+            batch = jax.device_put((ids, *vleaves), self._row_sharding)
         # bytes through the all_to_all, padding included (every device
         # sends D buckets of cap rows), and of the rows that carry a record
-        row_bytes = 8 + sum(v.dtype.itemsize * int(np.prod(v.shape[1:]))
+        row_bytes = 4 + sum(v.dtype.itemsize * int(np.prod(v.shape[1:]))
                             for v in vleaves)
-        for key, rows in (("exchange_sent", D * D * cap),
-                          ("exchange_live", int((slots_p < K).sum()))):
-            self.phase_bytes[key] = \
-                self.phase_bytes.get(key, 0) + rows * row_bytes
+        for key, n in (
+                ("exchange_sent", D * D * cap * row_bytes),
+                ("exchange_live", n_live * row_bytes),
+                ("exchange_route_batches", 1),
+                ("exchange_route_copied", int(copied)),
+                ("exchange_cap_counts_skipped", int(skipped))):
+            self.phase_bytes[key] = self.phase_bytes.get(key, 0) + n
         return batch, cap
-
-    def _apply_update(self, values, B: int,
-                      slots: np.ndarray, panes: np.ndarray) -> None:
-        """Mesh replacement for the single-chip ``_update_step`` dispatch:
-        the records ride the all_to_all data plane to their owning shard.
-        ``panes`` are ring slots (already mod P)."""
-        batch, cap = self._route_batch(values, B, slots, panes)
-        self._leaves, self._counts = self._mesh_update_step(
-            (self._leaves, self._counts), batch, cap)
 
     def _update_step(self, layout, leaves, counts, flat_ids, values):  # type: ignore[override]
         """Intercept the base class's device dispatch (the rest of the host
-        front — key probe, lateness, pane bookkeeping, growth — is reused
-        verbatim from ``WindowAggOperator.process_batch``): decompose the
-        flat ids back into (slot, pane) and route through the mesh
-        exchange."""
-        ids = np.asarray(flat_ids)
-        B = ids.shape[0]
-        sentinel = self._K * self._P
-        valid = ids < sentinel
-        slots = np.where(valid, ids // self._P, self._K).astype(np.int32)
-        panes = np.where(valid, ids % self._P, 0).astype(np.int32)
-        values_np = jax.tree_util.tree_map(np.asarray, values)
-        self._apply_update(values_np, B, slots, panes)
+        front — key probe, lateness, pane bookkeeping, growth, staging —
+        is reused verbatim from ``WindowAggOperator``): the staged flat
+        ids and value leaves ride the all_to_all data plane to their
+        owning shard."""
+        batch, cap = self._route_batch(flat_ids, values)
+        self._leaves, self._counts = self._mesh_update_step(
+            (self._leaves, self._counts), batch, cap)
         return self._leaves, self._counts
 
     def _round_key_capacity(self, needed: int) -> int:

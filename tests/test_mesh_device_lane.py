@@ -181,10 +181,17 @@ def test_the_lane_is_timed_and_counted(ran):
         <= op.phase_ns["fire"]
     sent, live = (op.phase_bytes[k] for k in ("exchange_sent",
                                               "exchange_live"))
-    # every record crossed the exchange once: slot, pane and its value
-    # columns (the tuple aggregate ships the whole row)
+    # every record crossed the exchange once: its flat id (slot and pane
+    # in one int32) and its value columns (the tuple aggregate ships the
+    # whole row)
     assert live % (N_BATCHES * BATCH) == 0
-    assert sent >= live >= N_BATCHES * BATCH * 12
+    assert sent >= live >= N_BATCHES * BATCH * 8
+    # how the routing went: a staged power of two splits over four chips
+    # uncopied, and a capacity count is taken or skipped, never both
+    routed, copied, skipped = (op.phase_bytes[k] for k in (
+        "exchange_route_batches", "exchange_route_copied",
+        "exchange_cap_counts_skipped"))
+    assert routed >= N_BATCHES and copied == 0 and 0 <= skipped < routed
 
 
 def windowed_on_a_mesh():

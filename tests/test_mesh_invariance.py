@@ -11,6 +11,8 @@ virtual CPU mesh the conftest forces (``JAX_PLATFORMS=cpu`` +
 multi-device sharding.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -262,6 +264,221 @@ def test_mesh_per_shard_probe_breakdown_populated():
     assert "probe_mirror" in op.phase_shard_ns
     per_shard = op.phase_shard_ns["probe_mirror"]
     assert per_shard.size >= 2 and int(per_shard.sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# the staged batch goes to the mesh as it is (ISSUE 31): the destination
+# shard is derived inside the step, the answers stay the one-chip fold's
+# ---------------------------------------------------------------------------
+
+ROUTE_COUNTERS = ("exchange_route_batches", "exchange_route_copied",
+                  "exchange_cap_counts_skipped")
+
+
+def _route_counters(op):
+    return tuple(op.phase_bytes.get(k, 0) for k in ROUTE_COUNTERS)
+
+
+def _mk_routed(D, agg="sum", **kw):
+    """Device-tier operator: the one-chip one (``D`` None) or the mesh one
+    at ``D`` shards (1 included: a mesh of one device runs the exchange)."""
+    from flink_tpu.core.functions import (CountAggregator, MaxAggregator,
+                                          MinAggregator, TupleAggregator)
+    if agg == "sum":
+        kw.update(agg=SumAggregator(jnp.float32), value_column="v")
+    else:                                  # four leaves: the whole row rides
+        kw.update(agg=TupleAggregator({
+            "total": ("v", SumAggregator(jnp.float32)),
+            "n": ("v", CountAggregator()),
+            "lo": ("v", MinAggregator(jnp.float32)),
+            "hi": ("v", MaxAggregator(jnp.float32))}),
+            value_selector=lambda c: c)
+    kw.update(key_column="k", emit_tier="device", snapshot_source="device")
+    assigner = TumblingEventTimeWindows.of(WINDOW_MS)
+    if D is None:
+        op = WindowAggOperator(assigner, **kw)
+    else:
+        op = MeshWindowAggOperator(assigner, mesh=make_mesh(D), **kw)
+    op.open(RuntimeContext())
+    return op
+
+
+def _all_digests(out):
+    """Every column of every fired batch, bytes and order included."""
+    return [[(c, np.asarray(b.column(c)).tobytes()) for c in sorted(b.columns)]
+            for b in out if hasattr(b, "columns")]
+
+
+def _state_bytes(op):
+    """The snapshot's dense cells (counts and every leaf), as bytes."""
+    op.prepare_snapshot_pre_barrier()
+    snap = densify_keyed_snapshot(op.snapshot_state())
+    return (np.asarray(snap["counts"]).tobytes(),
+            [np.asarray(l).tobytes() for l in snap["leaves"]],
+            np.asarray(snap["panes"]).tobytes())
+
+
+def _drive_routed(op, sizes=(777, 1024, 1024, 300, 2048, 777), nk=900):
+    """Batches of uneven sizes over several panes: 777 stages to 1024 ids
+    of which 247 are the base class's ``_PAD_ID`` rows, 1024 and 2048
+    stage full; skew differs batch to batch (uniform, then all keys on one
+    shard's slots, then a few hot keys)."""
+    rng = np.random.default_rng(17)
+    out, state = [], None
+    for i, B in enumerate(sizes):
+        k = (rng.integers(0, nk, B), rng.integers(0, nk // 8, B),
+             rng.integers(0, 5, B))[i % 3].astype(np.int64)
+        v = rng.random(B).astype(np.float32)
+        ts = i * 400 + np.sort(rng.integers(0, 400, B)).astype(np.int64)
+        out += op.process_batch(RecordBatch({"k": k, "v": v}, timestamps=ts))
+        out += op.process_watermark(Watermark(int(ts.max()) - 1))
+        if i == 3:
+            state = _state_bytes(op)
+    out += op.end_input()
+    return _all_digests(out), state
+
+
+@functools.lru_cache(maxsize=None)
+def _one_chip(agg):
+    return _drive_routed(_mk_routed(None, agg))
+
+
+@pytest.mark.parametrize("agg", ["sum", "tuple4"])
+@pytest.mark.parametrize("D", [1, 2, 3, 4])
+def test_staged_batch_routes_bit_identically_to_one_chip(D, agg):
+    """State and fired rows of the mesh fold equal the one-chip operator's
+    to the byte at D = 1, 2, 4 and at D = 3 (where no staged power of two
+    divides by D, so every batch takes the padded copy), with ``_PAD_ID``
+    rows in most batches."""
+    ref_fired, ref_state = _one_chip(agg)
+    op = _mk_routed(D, agg)
+    fired, state = _drive_routed(op)
+    assert len(ref_fired) >= 3
+    assert fired == ref_fired, f"fired rows diverge at D={D} ({agg})"
+    assert state == ref_state, f"state diverges at D={D} ({agg})"
+    routed, copied, skipped = _route_counters(op)
+    assert routed == op.fused_stats()["hot_dispatches"] == 6
+    assert copied == (routed if D == 3 else 0)
+    # D = 1: one block is the whole batch, its one pair sends all of it
+    assert skipped <= routed - 1
+
+
+@pytest.mark.parametrize("D,n", [(4, 1022), (4, 1024), (3, 1024), (3, 1023),
+                                 (2, 65)])
+def test_update_step_takes_any_staged_length(D, n):
+    """The override reads what it needs off the buffer: a length that
+    divides by D goes uncopied, any other is padded; ``_PAD_ID`` rows may
+    sit anywhere in it.  Cells equal the one-chip step's on the same ids."""
+    from flink_tpu.operators.window_agg import _PAD_ID
+    nk = 512
+    ops = [_mk_routed(None, initial_key_capacity=nk),
+           _mk_routed(D, initial_key_capacity=nk)]
+    rng = np.random.default_rng(n)
+    warm = RecordBatch({"k": np.arange(nk, dtype=np.int64),
+                        "v": np.zeros(nk, np.float32)},
+                       timestamps=np.zeros(nk, np.int64))
+    slots = rng.integers(0, nk, n)
+    ids = (slots * ops[0]._P).astype(np.int32)     # pane 0 of every slot
+    ids[rng.random(n) < 0.2] = _PAD_ID             # pads mid-batch
+    vals = rng.random(n).astype(np.float32)
+    fired = []
+    for op in ops:
+        op.process_batch(warm)
+        assert op._P == ops[0]._P
+        before = _route_counters(op)
+        res = op._update_step(op._layout, op._leaves, op._counts,
+                              ids.copy(), vals.copy())
+        op._leaves, op._counts = res[0], res[1]
+        fired.append(_digests(op.process_watermark(Watermark(WINDOW_MS))))
+    assert fired[0] == fired[1] and len(fired[0]) == 1
+    total = np.frombuffer(fired[1][0][3], np.float32).sum(dtype=np.float64)
+    assert abs(total - vals[ids != _PAD_ID].sum(dtype=np.float64)) < 1e-3
+    routed, copied, _skipped = (
+        a - b for a, b in zip(_route_counters(ops[1]), before))
+    assert (routed, copied) == (1, 1 if n % D else 0)
+
+
+@pytest.mark.parametrize("D,K,P", [(2, 64, 16), (4, 64, 16), (3, 96, 16),
+                                   (6, 192, 4), (4, 64, 3)])
+def test_pair_counts_is_the_device_rule(D, K, P):
+    """The host's capacity count against the destination rule written out
+    row by row: a live id goes to the shard owning its slot, a padding row
+    to its row index mod D (spans that are and are not powers of two)."""
+    from flink_tpu.operators.window_agg import _PAD_ID
+    op = MeshWindowAggOperator.__new__(MeshWindowAggOperator)
+    op.n_shards, op._K, op._P = D, K, P
+    rng = np.random.default_rng(K + D)
+    block = 40
+    ids = rng.integers(0, K * P, (D, block)).astype(np.int32)
+    ids[rng.random((D, block)) < 0.3] = _PAD_ID
+    want = np.zeros((D, D), np.int64)
+    for s in range(D):
+        for r in range(block):
+            i = int(ids[s, r])
+            want[s, (i // P) // (K // D) if i < K * P else r % D] += 1
+    got = op._pair_counts(ids, ids < K * P)
+    assert np.array_equal(got, want) and got.sum() == D * block
+
+
+def test_capacity_count_taken_below_the_ceiling_and_skipped_at_it():
+    """The sticky capacity: counted from the ids while it is below a
+    source block's length, not at all once it equals it (no pair can send
+    more), and ONE compiled step per batch geometry across batches whose
+    skew differs — capacity only grows."""
+    D, B, nk = 4, 2048, 2048
+    op = _mk_routed(D, initial_key_capacity=nk)
+    if op.mesh_step_cache_size() < 0:
+        pytest.skip("jax build without the jit cache probe")
+    rng = np.random.default_rng(4)
+
+    def feed(keys, t):
+        op.process_batch(RecordBatch(
+            {"k": keys.astype(np.int64),
+             "v": rng.random(keys.size).astype(np.float32)},
+            timestamps=np.full(keys.size, t, np.int64)))
+
+    def counters():
+        return tuple(int(a - b) for a, b in zip(_route_counters(op), base))
+
+    # every key once, 64 a batch (slots in arrival order: a first batch of
+    # 2048 new keys would send each source block to its own shard, the
+    # ceiling at once): blocks of 16 rows leave the capacity at 16
+    for i in range(nk // 64):
+        feed(np.arange(i * 64, (i + 1) * 64), 0)
+    assert op._K == nk and op._exchange_cap_hw == 16
+    base = _route_counters(op)
+    assert base == (nk // 64, 0, nk // 64 - 1)
+    block = B // D
+    size = op.mesh_step_cache_size()
+    # below the ceiling every batch is counted; less skew than the
+    # high-water mark compiles nothing
+    feed(rng.integers(0, nk, B), 1)
+    cap_even = op._exchange_cap_hw
+    assert B // (D * D) <= cap_even < block
+    assert op.mesh_step_cache_size() == size + 1
+    for t in range(2, 5):
+        feed(rng.permutation(nk), t)
+    assert counters() == (4, 0, 0)
+    assert op._exchange_cap_hw >= cap_even
+    grown = op._exchange_cap_hw > cap_even
+    assert op.mesh_step_cache_size() == size + 1 + int(grown)
+    # a source block whose rows all go to one shard: the ceiling
+    feed(rng.integers(0, nk // D, B), 5)
+    assert op._exchange_cap_hw == block
+    assert counters() == (5, 0, 0)
+    size = op.mesh_step_cache_size()
+    # at the ceiling: nothing counted, nothing compiled, whatever the skew
+    for t, keys in enumerate((rng.permutation(nk),
+                              rng.integers(0, nk // D, B),
+                              rng.integers(0, 7, B),
+                              rng.integers(0, nk, B - 300)), 6):
+        feed(keys, t)
+    assert counters() == (9, 0, 4)
+    assert op._exchange_cap_hw == block
+    assert op.mesh_step_cache_size() == size
+    # and the answers are all there
+    out = op.process_watermark(Watermark(WINDOW_MS))
+    assert sum(len(b) for b in out if hasattr(b, "columns")) == nk
 
 
 # ---------------------------------------------------------------------------
