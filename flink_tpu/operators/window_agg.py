@@ -1391,6 +1391,8 @@ class WindowAggOperator(StreamOperator):
                 _snapshot_read_step(layout, a, slot)
                 for a in (*self._leaves, self._counts) for slot in slots])
             cols = _fetch_collect(handle)
+        self.phase_bytes["snapshot_column_reads"] = \
+            self.phase_bytes.get("snapshot_column_reads", 0) + len(cols)
         with self._phase("snapshot_assemble"):
             m = len(slots)
             return [np.moveaxis(np.stack([c[:n] for c in
@@ -2344,9 +2346,14 @@ class WindowAggOperator(StreamOperator):
         with self._phase("fire"):
             with self._phase("fire_dispatch"):
                 panes = np.arange(first, last + 1, dtype=np.int64)
+                rows = self._k_active()
                 mask, result = self._fire_step(
                     self._layout, self._leaves, self._counts,
-                    self._pane_slots(panes), self._k_active())
+                    self._pane_slots(panes), rows)
+            # key rows x pane columns x state arrays the step combines
+            self.phase_bytes["fire_dense_cells"] = \
+                self.phase_bytes.get("fire_dense_cells", 0) + \
+                (rows or self._K) * panes.size * (len(self._leaves) + 1)
             return self._emit(mask, result,
                               self.assigner.window_bounds(window_id))
 

@@ -262,10 +262,15 @@ class MeshWindowAggOperator(WindowAggOperator):
         device.  Returns ``(batch, cap)`` for the ``_mesh_update_step``
         dispatch.  Timed as phase ``exchange_route`` (inside
         ``device_dispatch``).  ``phase_bytes`` counts what the exchange
-        then moves (``exchange_sent``, ``exchange_live``) and, beside
-        them, how the routing went: ``exchange_route_batches``, of which
-        ``exchange_route_copied`` needed the padded copy and
-        ``exchange_cap_counts_skipped`` took no capacity count."""
+        then moves (``exchange_sent``, ``exchange_live``: a row is its
+        flat id and each value leaf at the width it has ON THE DEVICE;
+        ``exchange_value_leaves``: the value leaves handed to the step,
+        summed over batches — a leaf ``agg.lift`` never reads is shipped
+        to the devices all the same, and dropped from the compiled step
+        as dead code) and, beside them, how the routing went:
+        ``exchange_route_batches``, of which ``exchange_route_copied``
+        needed the padded copy and ``exchange_cap_counts_skipped`` took
+        no capacity count."""
         with self._phase("exchange_route"):
             D = self.n_shards
             ids = np.asarray(flat_ids)
@@ -303,12 +308,15 @@ class MeshWindowAggOperator(WindowAggOperator):
             cap = self._exchange_cap_hw
             batch = jax.device_put((ids, *vleaves), self._row_sharding)
         # bytes through the all_to_all, padding included (every device
-        # sends D buckets of cap rows), and of the rows that carry a record
-        row_bytes = 4 + sum(v.dtype.itemsize * int(np.prod(v.shape[1:]))
-                            for v in vleaves)
+        # sends D buckets of cap rows), and of the rows that carry a
+        # record.  A row is counted as it is on the device: ``device_put``
+        # canonicalises a column's dtype (int64 to int32 with x64 off)
+        row_bytes = sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
+                        for a in batch)
         for key, n in (
                 ("exchange_sent", D * D * cap * row_bytes),
                 ("exchange_live", n_live * row_bytes),
+                ("exchange_value_leaves", len(vleaves)),
                 ("exchange_route_batches", 1),
                 ("exchange_route_copied", int(copied)),
                 ("exchange_cap_counts_skipped", int(skipped))):

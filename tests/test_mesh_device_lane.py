@@ -10,6 +10,7 @@ import importlib.util
 import os
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -17,14 +18,18 @@ import pytest
 from flink_tpu.connectors.sinks import CollectSink
 from flink_tpu.connectors.sources import Source
 from flink_tpu.core.batch import RecordBatch, Watermark
-from flink_tpu.core.functions import RuntimeContext, SumAggregator
+from flink_tpu.core.functions import (CountAggregator, MaxAggregator,
+                                      MinAggregator, RuntimeContext,
+                                      SumAggregator, TupleAggregator)
 from flink_tpu.datastream.api import StreamExecutionEnvironment
-from flink_tpu.operators.window_agg import WindowAggOperator
+from flink_tpu.operators.window_agg import (WindowAggOperator,
+                                            _snapshot_read_step)
 from flink_tpu.parallel.mesh import make_mesh
 from flink_tpu.parallel.mesh_runtime import MeshWindowAggOperator
 from flink_tpu.runtime.checkpoint.storage import InMemoryCheckpointStorage
 from flink_tpu.state.shard_layout import has_shard_slices
-from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+from flink_tpu.windowing.assigners import (SlidingEventTimeWindows,
+                                           TumblingEventTimeWindows)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N_KEYS, BATCH, N_BATCHES, SLIDE_MS = 600, 500, 40, 1000
@@ -105,18 +110,22 @@ def window_operator(env):
     return found[0]
 
 
-@pytest.fixture(scope="module", params=sorted(JOBS))
-def ran(request):
-    """One run of the job per deployment: (job, source, rows, operator,
-    job result)."""
-    job = JOBS[request.param]
-    env = StreamExecutionEnvironment(parallelism=1).set_mesh(n_devices=4)
+def run_job(job, env):
+    """(job, source, rows, operator, job result) of one run on `env`."""
     source, sink = SeededSource(env, seed=2**31 + 28), CollectSink()
     bench_module("jobs", "keyed_window").build(env, source, sink, job)
     result = env.execute_cluster(
         "mesh-device-lane", storage=InMemoryCheckpointStorage(),
         checkpoint_interval_ms=0, channel_capacity=2, timeout_s=300.0)
     return job, source, sink.rows(), window_operator(env), result
+
+
+@pytest.fixture(scope="module", params=sorted(JOBS))
+def ran(request):
+    """One run of the job per deployment on the four-device mesh."""
+    return run_job(
+        JOBS[request.param],
+        StreamExecutionEnvironment(parallelism=1).set_mesh(n_devices=4))
 
 
 def test_rows_match_the_reference_exactly_once(ran):
@@ -194,6 +203,81 @@ def test_the_lane_is_timed_and_counted(ran):
     assert routed >= N_BATCHES and copied == 0 and 0 <= skipped < routed
 
 
+def test_sharding_changes_no_bit_of_a_full_window(ran):
+    """The same job with no mesh (one device, the pane-major ring, the
+    gather fire) delivers the mesh's rows: counts, min and max bit for bit
+    in every window, and so the f32 sums of every window whose panes are
+    all in the ring, since the stable bucket plan keeps a cell's records
+    in batch order through the exchange.  That is what lets the benchmark's
+    `reference/keyed_window_mesh.py` be `keyed_window.py`'s class.  Where a
+    window reaches past the ring's ends (the stream's first and last
+    slides) the gather fire combines the retained panes only and the dense
+    fire every pane, identity cells included: another order of the same
+    additions, the last bits of the sum."""
+    job, _source, rows, _op, _result = ran
+    *_, plain_rows, plain_op, plain = run_job(
+        job, StreamExecutionEnvironment(parallelism=1))
+    assert plain.state == "FINISHED", plain.error
+    assert not isinstance(plain_op, MeshWindowAggOperator)
+    fields = bench_module("jobs", "keyed_window").output_fields(job)
+
+    def cells(delivered):
+        out = {(int(r["k"]), int(r["window_end"])):
+               {f: np.float32(r[f]) for f in fields} for r in delivered}
+        assert len(out) == len(delivered)
+        return out
+
+    sharded, single = cells(rows), cells(plain_rows)
+    assert sharded.keys() == single.keys()
+    size = job["assigner"]["size_ms"]
+    last = max(end for _, end in sharded)
+    full = 0
+    for cell, got in sharded.items():
+        want = single[cell]
+        whole = size <= cell[1] <= last - size + SLIDE_MS
+        full += whole
+        for field, kind in fields.items():
+            if whole or kind != "sum":
+                assert got[field].tobytes() == want[field].tobytes(), \
+                    (cell, field, got, want)
+            else:
+                assert abs(got[field] - want[field]) \
+                    <= 4 * np.spacing(want[field]), (cell, field, got, want)
+    assert full >= N_KEYS * (N_BATCHES // 4 - size // SLIDE_MS)
+
+
+def test_what_the_deployment_adds_is_counted(ran):
+    """`exchange_value_leaves`, `exchange_live` by the width a column has
+    on the device, `fire_dense_cells` and `snapshot_column_reads`."""
+    job, source, rows, op, _result = ran
+    counted = op.phase_bytes
+    # the tuple aggregate's selector hands the whole row (k, ts, v) to the
+    # exchange; the sum its one value column
+    leaves = 3 if job["aggregate"]["kind"] == "tuple" else 1
+    batches = counted["exchange_route_batches"]
+    assert batches >= N_BATCHES
+    assert counted["exchange_value_leaves"] == leaves * batches
+    # a live row: the int32 flat id and each leaf as `device_put` left it
+    # (with x64 off the int64 `k` and `ts` are int32 there; `v` is f32)
+    wide = jax.dtypes.canonicalize_dtype(np.int64).itemsize
+    row = 4 + (wide + wide + 4 if leaves == 3 else 4)
+    assert counted["exchange_live"] == N_BATCHES * BATCH * row
+    # a dense fire combines every key row's panes of every state array
+    arrays = len(op._leaves) + 1
+    panes = job["assigner"]["size_ms"] // SLIDE_MS
+    fires = len({int(r["window_end"]) for r in rows})
+    assert counted["fire_dense_cells"] == fires * op._K * panes * arrays
+    # a cut reads each live pane of each array as one column of 4-byte
+    # cells, and keeps the rows of the keys seen (all of them by then); a
+    # pane is live from its first record until the last window over it
+    # has fired and its column was cleared
+    reads, cuts = counted["snapshot_column_reads"], len(source.cuts)
+    assert counted["d2h_snapshot"] == reads * N_KEYS * 4
+    assert reads % arrays == 0
+    assert cuts * panes <= reads // arrays <= cuts * (panes + 1)
+    assert counted["d2h_fire"] > 0
+
+
 def windowed_on_a_mesh():
     env = StreamExecutionEnvironment().set_mesh(n_devices=4)
     return (env.from_collection(
@@ -252,3 +336,48 @@ def test_one_program_for_a_steady_run_with_fires_and_cuts():
             assert has_shard_slices(op.snapshot_state())
     assert rows == 3 * BATCH            # three windows fired, every key each
     assert op.mesh_step_cache_size() == before + 1
+
+
+def test_one_program_each_for_a_steady_sliding_run():
+    """The sliding sum/count/min/max job at one geometry: the sharded update
+    step (five state arrays, three scatter kinds) and the dense fire step
+    (every window's pane count, the ring filling up included) compile once,
+    a cut's column read once per dtype, and nothing after the first fire
+    and cut, whatever the number of live panes."""
+    field = lambda agg: ("v", agg)  # noqa: E731
+    op = MeshWindowAggOperator(
+        SlidingEventTimeWindows.of(4 * SLIDE_MS, SLIDE_MS),
+        TupleAggregator({"total": field(SumAggregator(jnp.float32)),
+                         "n": field(CountAggregator()),
+                         "lo": field(MinAggregator(jnp.float32)),
+                         "hi": field(MaxAggregator(jnp.float32))}),
+        key_column="k", value_selector=lambda c: c, mesh=make_mesh(4),
+        emit_tier="device", initial_key_capacity=1024)
+    if op.mesh_step_cache_size() < 0:
+        pytest.skip("jax build without the jit cache probe")
+    op.open(RuntimeContext())
+    sizes = lambda: (op.mesh_step_cache_size(),  # noqa: E731
+                     WindowAggOperator._fire_step._cache_size(),
+                     _snapshot_read_step._cache_size())
+    before = sizes()
+    rng = np.random.default_rng(32)
+    keys = np.arange(BATCH, dtype=np.int64) * 7919 + 1
+    rows, cuts, warm = 0, 0, None
+    for b in range(40):
+        ts = np.full(BATCH, b * 250, np.int64)
+        op.process_batch(RecordBatch(
+            {"k": keys[rng.permutation(BATCH)] if b else keys,
+             "v": rng.random(BATCH).astype(np.float32)}, timestamps=ts))
+        for out in op.process_watermark(Watermark(b * 250 + 249)):
+            rows += len(out)
+        if b % 6 == 5:                  # 2, 3, 4, 3, 4, 3 live panes
+            assert has_shard_slices(op.snapshot_state())
+            cuts += 1
+            warm = warm or sizes()      # one fire and one cut behind us
+    assert rows == 10 * BATCH and cuts == 6
+    assert sizes() == warm
+    step, fire, read = (a - b for a, b in zip(warm, before))
+    assert (step, fire) == (1, 1) and read <= 2
+    assert op.phase_bytes["snapshot_column_reads"] \
+        == (2 + 3 + 4 + 3 + 4 + 3) * 5
+    assert op.phase_bytes["fire_dense_cells"] == 10 * 1024 * 4 * 5
