@@ -3,13 +3,15 @@
 The reference moves records between parallel subtasks through a Netty shuffle
 with credit-based flow control (``NettyMessage.java``,
 ``RemoteInputChannel.java:302``).  On a TPU mesh the equivalent *intra-pod*
-exchange is a bucketed ``all_to_all`` under ``shard_map``: each device sorts
-its local records into per-destination buckets of fixed capacity and one XLA
-collective rotates the buckets over ICI.  Capacity overflows are reported by
-the raw exchange and handled by :class:`ResizingExchange`, which BLOCKS and
-re-runs at doubled capacity instead of dropping — the analog of
-credit-exhaustion blocking + floating-buffer redistribution under backlog
-feedback (``RemoteInputChannel.java:302``,
+exchange is a bucketed ``all_to_all`` under ``shard_map``: each device places
+its local records into per-destination buckets of fixed capacity (a row's
+cell is its destination's bucket and the count of earlier rows bound the same
+way: :func:`bucket_plan`) and one XLA collective rotates the buckets over
+ICI.  Capacity overflows are reported by the raw exchange and handled by
+:class:`ResizingExchange`, which BLOCKS and re-runs at doubled capacity
+instead of dropping — the analog of credit-exhaustion blocking +
+floating-buffer redistribution under backlog feedback
+(``RemoteInputChannel.java:302``,
 ``NettyShuffleEnvironmentOptions.java:167``).
 
 All shapes are static (capacity per destination is fixed per compile), so the
@@ -30,35 +32,45 @@ from flink_tpu.parallel.mesh import KG_AXIS
 
 
 def bucket_plan(dest: jnp.ndarray, num_shards: int, cap: int):
-    """The shared bucketing plan of every keyed exchange: STABLE-sort local
-    rows by destination shard and compute each row's flat position in the
-    ``[num_shards, cap]`` send buckets.
+    """The shared bucketing plan of every keyed exchange: each local row's
+    flat position in the ``[num_shards, cap]`` send buckets, in closed form
+    from a D-way count — no sort, no search, no loop.
 
-    Returns ``(order, flat, valid_src)``: ``order`` is the stable row
-    permutation, ``flat[i]`` the bucket cell of sorted row ``i`` (or the
-    ``num_shards * cap`` drop sentinel once a destination's bucket is
-    full), ``valid_src`` the per-sorted-row in-capacity mask.  Stability
-    matters for more than determinism: records of one key keep their batch
-    order through the exchange, which is what makes the sharded
-    scatter-combine BIT-identical to the single-chip fold (same per-cell
-    accumulation order) at any mesh size."""
-    B = dest.shape[0]
-    order = jnp.argsort(dest, stable=True)
-    sdest = dest[order]
-    # position of each row within its destination's bucket
-    idx_in_dest = jnp.arange(B) - jnp.searchsorted(sdest, sdest, side="left")
+    Row ``i`` lands in cell ``dest[i] * cap + r``, where ``r`` is the number
+    of EARLIER rows of the block with the same destination: a one-hot
+    ``[num_shards, B]`` (rows along the minor axis), one cumulative sum
+    along it, and the row's own destination picked out.  Returns ``(flat,
+    valid_src)``, both in ROW order: ``flat[i]`` the bucket cell of row
+    ``i`` (or the ``num_shards * cap`` drop sentinel once its destination's
+    bucket is full), ``valid_src`` the in-capacity mask.  The placement is
+    the stable sort's, and that matters for more than determinism: records
+    of one key keep their batch order through the exchange, which is what
+    makes the sharded scatter-combine BIT-identical to the single-chip fold
+    (same per-cell accumulation order) at any mesh size.
+
+    The one-hot costs ``num_shards * B`` cells a pass, which at a block of
+    16,384 rows and D = 4 is 0.003 ms on a v5e chip.  A stable sort by
+    destination with per-destination start offsets costs the same whatever
+    D is, but it has every column gathered into sorted order before its
+    bucket scatter (0.116 ms a column there): 0.51 ms against 0.16 for the
+    plan and two columns.  Only past some hundreds of shards would the
+    sort be the cheaper plan (PERF.md section 6, PR 33)."""
+    hot = (dest[None, :] == jnp.arange(num_shards, dtype=dest.dtype)[:, None]
+           ).astype(jnp.int32)
+    earlier = jnp.cumsum(hot, axis=1) - hot
+    idx_in_dest = jnp.sum(hot * earlier, axis=0)
     valid_src = idx_in_dest < cap
-    flat = jnp.where(valid_src, sdest * cap + idx_in_dest, num_shards * cap)
-    return order, flat, valid_src
+    flat = jnp.where(valid_src, dest * cap + idx_in_dest, num_shards * cap)
+    return flat, valid_src
 
 
-def bucket_rows(a: jnp.ndarray, order: jnp.ndarray, flat: jnp.ndarray,
-                num_shards: int, cap: int, fill) -> jnp.ndarray:
+def bucket_rows(a: jnp.ndarray, flat: jnp.ndarray, num_shards: int, cap: int,
+                fill) -> jnp.ndarray:
     """Place one row array into its ``[num_shards, cap, ...]`` send buckets
     under a :func:`bucket_plan`; unfilled cells carry ``fill`` (an id the
     receiving scatter drops, or a neutral value)."""
     buf = jnp.full((num_shards * cap,) + a.shape[1:], fill, a.dtype)
-    return buf.at[flat].set(a[order], mode="drop").reshape(
+    return buf.at[flat].set(a, mode="drop").reshape(
         (num_shards, cap) + a.shape[1:])
 
 
@@ -74,13 +86,13 @@ def all_to_all_rows(bucketed: jnp.ndarray) -> jnp.ndarray:
 
 def _bucket_local(dest: jnp.ndarray, leaves: Tuple[jnp.ndarray, ...],
                   num_shards: int, cap: int):
-    """Sort local rows into [num_shards, cap] buckets by destination shard.
+    """Place local rows into [num_shards, cap] buckets by destination shard.
 
     Returns (bucketed_leaves, valid mask [num_shards, cap], overflow count).
     Rows beyond ``cap`` for a destination overflow (counted, not sent).
     """
-    order, flat, valid_src = bucket_plan(dest, num_shards, cap)
-    out_leaves = tuple(bucket_rows(l, order, flat, num_shards, cap, 0)
+    flat, valid_src = bucket_plan(dest, num_shards, cap)
+    out_leaves = tuple(bucket_rows(l, flat, num_shards, cap, 0)
                        for l in leaves)
     vmask = jnp.zeros((num_shards * cap,), bool).at[flat].set(
         valid_src, mode="drop").reshape(num_shards, cap)

@@ -192,14 +192,14 @@ class MeshWindowAggOperator(WindowAggOperator):
                                                      bucket_plan,
                                                      bucket_rows)
             # ---- bucket local rows by destination shard ([D, cap]); the
-            # STABLE plan keeps each key's records in batch order through
-            # the exchange (bit-identical per-cell accumulation at any D)
+            # plan keeps each key's records in batch order through the
+            # exchange (bit-identical per-cell accumulation at any D)
             with jax.named_scope("exchange_bucket"):
                 row = jnp.arange(ids.shape[0], dtype=jnp.int32)
                 dest = jnp.where(ids < K * Pn, ids // span, row % D)
-                order, flat, _valid = bucket_plan(dest, D, cap)
-                bucket = lambda a, fill: bucket_rows(a, order, flat, D,  # noqa: E731
-                                                     cap, fill)
+                flat, _valid = bucket_plan(dest, D, cap)
+                bucket = lambda a, fill: bucket_rows(a, flat, D, cap,  # noqa: E731
+                                                     fill)
                 b_ids = bucket(ids, K * Pn)        # K * Pn = dropped id
                 b_vals = [bucket(v, 0) for v in values]
             # ---- the keyed exchange: one collective over ICI per array
@@ -396,9 +396,8 @@ class MeshSessionWindowOperator(SessionWindowOperator):
             from flink_tpu.parallel.exchange import (all_to_all_rows,
                                                      bucket_plan,
                                                      bucket_rows)
-            order, flat, _valid = bucket_plan(dest, D, cap)
-            bucket = lambda a, fill: bucket_rows(a, order, flat, D,  # noqa: E731
-                                                 cap, fill)
+            flat, _valid = bucket_plan(dest, D, cap)
+            bucket = lambda a, fill: bucket_rows(a, flat, D, cap, fill)  # noqa: E731
             rx_sid = all_to_all_rows(bucket(sid, cap_sess)).reshape(D * cap)
             rx_vals = tuple(
                 all_to_all_rows(bucket(v, 0)).reshape((D * cap,)

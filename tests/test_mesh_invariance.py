@@ -481,6 +481,45 @@ def test_capacity_count_taken_below_the_ceiling_and_skipped_at_it():
     assert sum(len(b) for b in out if hasattr(b, "columns")) == nk
 
 
+def _drive_hot_key(op, nk, B):
+    """One batch geometry: every key once (slots in arrival order, so each
+    source block goes to one shard and the capacity is at its ceiling from
+    the first step), then batches in which one key takes most rows with
+    values whose f32 sum depends on the order of the additions."""
+    rng = np.random.default_rng(33)
+    out = op.process_batch(RecordBatch(
+        {"k": np.arange(nk, dtype=np.int64), "v": np.ones(nk, np.float32)},
+        timestamps=np.zeros(nk, np.int64)))
+    for t in range(1, 5):
+        k = np.where(rng.random(B) < 0.7, 7, rng.integers(0, nk, B))
+        v = (rng.random(B) * 10.0 ** rng.integers(-6, 7, B))
+        out += op.process_batch(RecordBatch(
+            {"k": k.astype(np.int64), "v": v.astype(np.float32)},
+            timestamps=np.full(B, t, np.int64)))
+    state = _state_bytes(op)
+    out += op.process_watermark(Watermark(WINDOW_MS))
+    return _all_digests(out), state
+
+
+@pytest.mark.parametrize("D", [2, 4])
+def test_a_hot_key_sums_in_batch_order_through_one_program(D):
+    """ISSUE 33: the closed-form bucket plan keeps a destination's rows in
+    batch order, so a key that recurs some 1,400 times a batch sums to the
+    one-chip fold's f32 bit for bit (an f64 sum rounds elsewhere), and a
+    steady run is ONE compiled ``_mesh_update_step``."""
+    nk = B = 2048
+    ref_fired, ref_state = _drive_hot_key(
+        _mk_routed(None, initial_key_capacity=nk), nk, B)
+    op = _mk_routed(D, initial_key_capacity=nk)
+    before = op.mesh_step_cache_size()
+    fired, state = _drive_hot_key(op, nk, B)
+    assert len(fired) >= 1
+    assert fired == ref_fired and state == ref_state
+    assert op._exchange_cap_hw == B // D
+    if before >= 0:
+        assert op.mesh_step_cache_size() == before + 1
+
+
 # ---------------------------------------------------------------------------
 # device-lane health on the mesh: whole-mesh degrade, bit-exact
 # ---------------------------------------------------------------------------

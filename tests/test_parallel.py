@@ -8,7 +8,8 @@ import pytest
 
 from flink_tpu.core.batch import RecordBatch
 from flink_tpu.core.functions import SumAggregator
-from flink_tpu.parallel.exchange import make_all_to_all_exchange
+from flink_tpu.parallel.exchange import (bucket_plan, bucket_rows,
+                                         make_all_to_all_exchange)
 from flink_tpu.parallel.mesh import KeyGroupSharding, make_mesh, state_sharding
 from flink_tpu.parallel.window_shard import sharded_window_operator
 from flink_tpu.testing.harness import KeyedOneInputOperatorHarness
@@ -140,3 +141,80 @@ def test_resizing_exchange_max_cap_guard():
     vals = jnp.ones(8 * 20, jnp.float32)
     with pytest.raises(RuntimeError, match="overflow at max capacity"):
         ex(dest, vals)
+
+
+# ---------------------------------------------------------------------------
+# the bucket plan (ISSUE 33): row i lands in cell dest[i] * cap + r, r the
+# number of EARLIER rows bound for the same destination; no loop in the
+# lowered program
+# ---------------------------------------------------------------------------
+
+def _plan_reference(dest, D, cap):
+    """The placement rule as a Python loop over rows."""
+    seen = [0] * D
+    flat = np.empty(len(dest), np.int64)
+    for i, d in enumerate(dest.tolist()):
+        flat[i] = d * cap + seen[d] if seen[d] < cap else D * cap
+        seen[d] += 1
+    return flat
+
+
+@pytest.mark.parametrize("skew", ["uniform", "one_dest"])
+@pytest.mark.parametrize("cap_of", ["ceiling", "below"])
+@pytest.mark.parametrize("B", [64, 16384])
+@pytest.mark.parametrize("D", [2, 4, 8])
+def test_bucket_plan_is_the_placement_rule(D, B, cap_of, skew):
+    """``cap`` at the ceiling (a block's length: nothing can overflow) and
+    below it (the sentinel, ``valid_src`` and the overflow count)."""
+    rng = np.random.default_rng(D * 100003 + B)
+    dest = (np.full(B, D - 1, np.int32) if skew == "one_dest"
+            else rng.integers(0, D, B).astype(np.int32))
+    cap = B if cap_of == "ceiling" else max(1, B // (D + 1))
+    flat, valid = jax.jit(bucket_plan, static_argnums=(1, 2))(
+        jnp.asarray(dest), D, cap)
+    want = _plan_reference(dest, D, cap)
+    assert np.array_equal(np.asarray(flat), want)
+    assert np.array_equal(np.asarray(valid), want < D * cap)
+    overflow = int(np.sum(~np.asarray(valid)))
+    assert overflow == sum(max(0, int(n) - cap)
+                           for n in np.bincount(dest, minlength=D))
+    assert (overflow == 0) == (cap_of == "ceiling")
+    # the buckets: rows of one destination in batch order, the rest fill
+    rows = np.arange(B, dtype=np.int32)
+    got = np.asarray(bucket_rows(jnp.asarray(rows), flat, D, cap, -1))
+    for d in range(D):
+        mine = rows[dest == d][:cap]
+        assert np.array_equal(got[d, :len(mine)], mine)
+        assert (got[d, len(mine):] == -1).all()
+
+
+@pytest.mark.parametrize("D", [2, 4, 8])
+def test_bucket_plan_lowers_to_no_loop(D):
+    """The sort-and-search plan lowered to a ``while`` (``searchsorted``'s
+    binary search: 15 rounds at a block of 16,384); the count has none,
+    and no sort either."""
+    text = jax.jit(bucket_plan, static_argnums=(1, 2)).lower(
+        jax.ShapeDtypeStruct((16384,), jnp.int32), D, 16384).as_text()
+    assert "while" not in text
+    assert "sort" not in text
+
+
+def test_exchange_keeps_batch_order_within_a_destination():
+    """Through the collective: what shard ``s`` receives from source ``d``
+    is ``d``'s rows bound for ``s``, in ``d``'s batch order."""
+    mesh = make_mesh(4)
+    D, B, cap = 4, 64, 64
+    rng = np.random.default_rng(33)
+    dest = rng.integers(0, D, D * B).astype(np.int32)
+    rows = np.arange(D * B, dtype=np.int32)
+    (rx,), rx_valid, overflow = make_all_to_all_exchange(
+        mesh, num_leaves=1, cap=cap)(jnp.asarray(dest), jnp.asarray(rows))
+    assert int(np.asarray(overflow).sum()) == 0
+    rx = np.asarray(rx).reshape(D, D, cap)          # [shard, source, cell]
+    valid = np.asarray(rx_valid).reshape(D, D, cap)
+    for s in range(D):
+        for d in range(D):
+            block = slice(d * B, (d + 1) * B)
+            want = rows[block][dest[block] == s]
+            assert np.array_equal(rx[s, d][valid[s, d]], want)
+            assert valid[s, d, :len(want)].all()
