@@ -1107,6 +1107,7 @@ class MiniCluster(TaskListener):
                     "records_in": t.records_in,
                     "records_out": t.records_out,
                     "key_group_records": t.key_group_records,
+                    "sql_projections": t.sql_projections,
                     "busy_ratio": b, "idle_ratio": i,
                     "backpressure_ratio": bp}
                 # channel-consuming subtasks: per-channel queue depth /

@@ -160,6 +160,22 @@ class SubtaskBase:
             "unread": sum(getattr(op, "key_groups_unread", 0)
                           for op in chain)}
 
+    @property
+    def sql_projections(self) -> Dict[str, Dict[str, int]]:
+        """``{span: {batches, rows, ns}}`` of the SQL planner's projection
+        maps chained into this task (``sql.pre_project``, ``sql.project``),
+        summed where a plan chains more than one of a kind; empty for a
+        task that runs none."""
+        chain = getattr(self.operator, "operators", None) or [self.operator]
+        out: Dict[str, Dict[str, int]] = {}
+        for op in chain:
+            stats = getattr(op, "projection_stats", None)
+            if stats is not None:
+                into = out.setdefault(op.span, dict.fromkeys(stats, 0))
+                for key, value in stats.items():
+                    into[key] += value
+        return out
+
     def _transition(self, state: str, error: Optional[str] = None) -> None:
         self.state = state
         self.listener.task_state_changed(self.vertex_uid, self.subtask_index,

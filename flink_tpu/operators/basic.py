@@ -28,6 +28,15 @@ from flink_tpu.ops.scatter import segment_running_fold
 from flink_tpu.state.keyindex import make_key_index
 
 
+def fire_cause(batch: RecordBatch) -> Dict[str, int]:
+    """A window fire's rows carry its ``window_end`` column: a span over
+    them shares that identifier with the fire's own spans upstream."""
+    end = batch.columns.get("window_end")
+    if len(batch) and isinstance(end, np.ndarray) and end.dtype.kind == "i":
+        return {"window_end": int(end[0])}
+    return {}
+
+
 class MapOperator(StreamOperator):
     """Vectorized map: fn(columns dict) -> columns dict (row-aligned)."""
 
@@ -322,14 +331,8 @@ class SinkOperator(StreamOperator):
             self.sink.open(ctx)
 
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
-        # a window fire's rows carry its `window_end` column: the span
-        # shares that identifier with the fire's own spans upstream
-        end = batch.columns.get("window_end")
-        cause = {"window_end": int(end[0])} if (
-            len(batch) and isinstance(end, np.ndarray)
-            and end.dtype.kind == "i") else {}
         with tracing.span("sink.invoke", cat="sink", records=len(batch),
-                          **cause):
+                          **fire_cause(batch)):
             self.sink.write_batch(batch)
         return []
 

@@ -11,13 +11,16 @@ vectorized column ops, not per-record state probes.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from flink_tpu.core.batch import (LONG_MIN, RecordBatch, StreamElement,
                                   Watermark)
+from flink_tpu.observability import tracing
 from flink_tpu.operators.base import StreamOperator
+from flink_tpu.operators.basic import MapOperator, fire_cause
 from flink_tpu.operators.joins import _join_pairs, _merge_columns
 
 
@@ -1353,6 +1356,36 @@ class SortLimitOperator(StreamOperator):
     def restore_state(self, snap: Dict[str, Any]) -> None:
         if snap.get("cols"):
             self._buf = [RecordBatch(snap["cols"], timestamps=snap.get("ts"))]
+
+
+class SqlProjectionOperator(MapOperator):
+    """A map the planner builds from a statement's expressions
+    (``StreamExecCalc``): ``sql-pre-project`` computes the aggregate calls'
+    input columns ahead of the keyed exchange, ``sql-project`` rebuilds a
+    batch (after a group aggregate, every fired batch, on the window task's
+    thread) into the select list.  A plain chained map with a span of its
+    own (``sql.pre_project`` / ``sql.project``, with the fire's
+    ``window_end`` where the rows carry one) and three counters, so a trace
+    and ``job_status()`` tell the plan's host work from the rest of the
+    chain."""
+
+    def __init__(self, fn: Callable[[Dict[str, Any]], Dict[str, Any]],
+                 name: str, span: str):
+        super().__init__(fn, name)
+        self.span = span
+        #: batches and rows through the map, and the time spent in it
+        self.projection_stats = {"batches": 0, "rows": 0, "ns": 0}
+
+    def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
+        t0 = time.perf_counter_ns()
+        with tracing.span(self.span, cat="sql", records=len(batch),
+                          **fire_cause(batch)):
+            out = super().process_batch(batch)
+        stats = self.projection_stats
+        stats["batches"] += 1
+        stats["rows"] += len(batch)
+        stats["ns"] += time.perf_counter_ns() - t0
+        return out
 
 
 class MiniBatchOperator(StreamOperator):

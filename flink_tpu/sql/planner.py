@@ -899,7 +899,7 @@ class Planner:
             n = _n(cols)
             return {nm: to_column(f(cols), n) for nm, f in zip(_names, _fns)}
 
-        out = over_stream.map(project, name="sql-project")
+        out = _projection(over_stream, project, "sql.project")
         rowtime_out = None
         if event_time:
             for it, nm in zip(items, names):
@@ -1034,7 +1034,7 @@ class Planner:
             return {nm: to_column(f(cols), nrows)
                     for nm, f in zip(_names, _fns)}
 
-        out = ranked.map(project, name="sql-project")
+        out = _projection(ranked, project, "sql.project")
         return QueryPlan(out, names, _order_names(stmt, outer_items, names),
                          stmt.limit)
 
@@ -1453,7 +1453,7 @@ class Planner:
             n = _n(cols)
             return {nm: to_column(f(cols), n) for nm, f in zip(_names, _fns)}
 
-        out = stream.map(project, name="sql-project")
+        out = _projection(stream, project, "sql.project")
         rowtime_out = _propagated_rowtime(table, items, names)
         return QueryPlan(out, names, _order_names(stmt, items, names),
                          stmt.limit, rowtime=rowtime_out,
@@ -1649,7 +1649,7 @@ class Planner:
                     out["__key"] = key
             return out
 
-        stream = stream.map(pre_project, name="sql-pre-project")
+        stream = _projection(stream, pre_project, "sql.pre_project")
         if self.mini_batch_rows:
             # bundle small batches ahead of the stateful aggregate
             # (``table.exec.mini-batch`` bundling, ``operators/bundle/``)
@@ -1839,9 +1839,22 @@ class Planner:
             n = _n(cols)
             return {nm: to_column(f(cols), n) for nm, f in zip(_names, _fns)}
 
-        out = agg_stream.map(project, name="sql-project")
+        out = _projection(agg_stream, project, "sql.project")
         return QueryPlan(out, names, _order_names(stmt, items, names),
                          stmt.limit)
+
+
+def _projection(stream, fn, span: str):
+    """``stream.map(fn)`` as a ``SqlProjectionOperator``: the same chained
+    map under the span's name in the plan (``sql.pre_project`` is the
+    vertex ``sql-pre-project``), with the span and the counters of the
+    plan's host work."""
+    from flink_tpu.datastream.api import DataStream
+    from flink_tpu.operators.sql_ops import SqlProjectionOperator
+
+    name = span.replace(".", "-").replace("_", "-")
+    return DataStream(stream.env, stream._then(
+        name, lambda: SqlProjectionOperator(fn, name, span)))
 
 
 def _n(cols) -> int:

@@ -665,6 +665,31 @@ def _run_keyed_window_job(channel_capacity=4096):
     return env.last_cluster, result
 
 
+def _run_sql_window_job():
+    """The statement of BASELINE config 5 at a small size, parallelism 1:
+    four 250 ms TUMBLE windows through the planner's `sql-pre-project` and
+    `sql-project` maps.  Returns the cluster."""
+    from flink_tpu.datastream.api import StreamExecutionEnvironment
+    from flink_tpu.sql.table_env import TableEnvironment
+
+    n = 512
+    tenv = TableEnvironment(parallelism=1)
+    tenv.register_collection(
+        "lineitem", columns={"k": np.arange(n, dtype=np.int64) % 64,
+                             "v": np.ones(n, np.float32),
+                             "ts": np.arange(n, dtype=np.int64) * 1000 // n},
+        rowtime="ts", batch_size=64)
+    env = StreamExecutionEnvironment(parallelism=1)
+    (tenv.sql_query(
+        "SELECT k, TUMBLE_END(ts, INTERVAL '0.25' SECOND) AS window_end, "
+        "SUM(v) AS total, COUNT(*) AS n, AVG(v) AS mean FROM lineitem "
+        "GROUP BY k, TUMBLE(ts, INTERVAL '0.25' SECOND)")
+        .to_data_stream(env).collect())
+    result = env.execute_cluster("sql-span-job", timeout_s=120.0)
+    assert result.state == "FINISHED", result.error
+    return env.last_cluster
+
+
 #: span -> (enclosing span on the same thread or None, identifier argument
 #: shared along one cut / one fire or None): the table of
 #: docs/operations.md "Tracing and latency tracking"
@@ -691,6 +716,9 @@ SPAN_TABLE = {
     "checkpoint.complete": (None, "checkpoint"),
     "checkpoint.store": ("checkpoint.complete", "checkpoint"),
     "sink.invoke": (None, "window_end"),
+    # a SQL job only (`_run_sql_window_job`): the planner's two maps
+    "sql.pre_project": (None, None),
+    "sql.project": (None, "window_end"),
 }
 
 
@@ -735,6 +763,7 @@ def profiled_job(tmp_path_factory):
     try:
         _run_keyed_window_job()
         phases = _host_tier_batches(False) | _host_tier_batches(True)
+        sql_cluster = _run_sql_window_job()
     finally:
         jax.profiler.stop_trace()
     found = glob.glob(f"{out}/plugins/profile/*/*.xplane.pb")
@@ -750,7 +779,12 @@ def profiled_job(tmp_path_factory):
                      or ev.name == "exchange.put_wait"]
             if spans:
                 threads.append(spans)
-    return {"threads": threads, "host_tier_phases": phases}
+    # the SQL job's threads apart: the keyed job's tests count its own
+    sql_threads = [spans for spans in threads
+                   if any(s[0].startswith("sql.") for s in spans)]
+    return {"threads": [t for t in threads if t not in sql_threads],
+            "sql_threads": sql_threads, "sql_cluster": sql_cluster,
+            "host_tier_phases": phases}
 
 
 def _enclosing(spans, child, parent_name):
@@ -764,7 +798,8 @@ def test_span_lands_in_the_profilers_trace(profiled_job, name):
     if name == "window_agg.probe_mirror" \
             and "probe_mirror" not in profiled_job["host_tier_phases"]:
         pytest.skip("the native mirror did not build here")
-    hits = [(spans, s) for spans in profiled_job["threads"]
+    hits = [(spans, s) for spans in
+            profiled_job["threads"] + profiled_job["sql_threads"]
             for s in spans if s[0] == name]
     assert hits, f"no {name!r} event on any host line"
     for spans, s in hits:
@@ -794,7 +829,7 @@ def test_one_cut_and_one_fire_share_their_identifier(profiled_job):
         assert [s[3]["checkpoint"] for spans in profiled_job["threads"]
                 for s in spans if s[0] == name] == [1]
     fire_names = {n for n, (_, ident) in SPAN_TABLE.items()
-                  if ident == "window_end"}
+                  if ident == "window_end"} - {"sql.project"}
     window_threads = [spans for spans in profiled_job["threads"]
                       if any(s[0] == "window_agg.fire" for s in spans)]
     assert len(window_threads) == 2
@@ -814,6 +849,41 @@ def test_one_cut_and_one_fire_share_their_identifier(profiled_job):
             assert sink[0][3]["records"] > 0
     assert not any(s[0] == "exchange.put_wait"
                    for spans in profiled_job["threads"] for s in spans)
+
+
+def test_a_sql_jobs_projections_are_spans_with_counters_beside_them(
+        profiled_job):
+    """`sql.pre_project` on the source task's thread, one a batch;
+    `sql.project` on the window task's, one a fired batch, after the fire
+    that produced its rows and before the sink that takes them, all three
+    under one `window_end`; `job_status()` counts the same batches and
+    rows."""
+    by_name = {name: [s for spans in profiled_job["sql_threads"]
+                      for s in spans if s[0] == name]
+               for name in ("sql.pre_project", "sql.project")}
+    assert [s[3]["records"] for s in by_name["sql.pre_project"]] == [64] * 8
+    assert not any("window_end" in s[3] for s in by_name["sql.pre_project"])
+    assert [s[3]["window_end"] for s in by_name["sql.project"]] \
+        == [250, 500, 750, 1000]
+    window_thread, = [spans for spans in profiled_job["sql_threads"]
+                      if any(s[0] == "sql.project" for s in spans)]
+    for project in by_name["sql.project"]:
+        end = project[3]["window_end"]
+        fire, = [s for s in window_thread if s[0] == "window_agg.fire"
+                 and s[3]["window_end"] == end]
+        sink, = [s for s in window_thread if s[0] == "sink.invoke"
+                 and s[3].get("window_end") == end]
+        assert fire[2] <= project[1] <= project[2] <= sink[1]
+        assert project[3]["records"] == sink[3]["records"] == 64
+    counted = {}
+    for vertex in profiled_job["sql_cluster"].job_status()["vertices"]:
+        for subtask in vertex["subtasks"]:
+            counted.update(subtask["sql_projections"])
+    assert {k: (v["batches"], v["rows"]) for k, v in counted.items()} == {
+        "sql.pre_project": (8, 512), "sql.project": (4, 256)}
+    for name, spans in by_name.items():
+        # the counter's clock starts before the span and stops after it
+        assert counted[name]["ns"] >= sum(s[2] - s[1] for s in spans) > 0
 
 
 def test_put_wait_is_a_span_only_when_a_put_blocks():
