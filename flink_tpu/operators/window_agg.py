@@ -51,6 +51,7 @@ out-of-range slot ids, dropped by XLA scatter), state grows by doubling
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import time
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -574,11 +575,12 @@ class WindowAggOperator(StreamOperator):
         else:
             self._K = _next_pow2(initial_key_capacity)
 
-        #: jax.sharding.Sharding for state arrays ([K, P, ...] sharded over the
-        #: key-slot dim = key-group axis, SURVEY §7.1).  The jitted steps are
-        #: placement-agnostic: XLA's SPMD partitioner splits the scatters per
-        #: shard (indices replicated, out-of-range rows dropped locally), so
-        #: multi-chip is pure data placement — no kernel changes.
+        #: jax.sharding.Sharding for state arrays (axis 0, which the layout
+        #: makes the key-group axis, SURVEY §7.1: ``_layout``).  The jitted
+        #: steps of the base class are placement-agnostic: XLA's SPMD
+        #: partitioner splits the scatters per shard (indices replicated,
+        #: out-of-range rows dropped locally), so multi-chip is pure data
+        #: placement — no kernel changes.
         self.sharding = sharding
         # shard count must divide K for even state splits: round K up to
         # lcm(K, n_shards); doubling growth preserves divisibility after that
@@ -842,7 +844,8 @@ class WindowAggOperator(StreamOperator):
     def _layout(self):
         """How the state arrays hold their K x P cells
         (``ops/pane_layout.py``): the pane-major ring on one chip; the
-        key-major grid where the key axis shards over a mesh."""
+        key-major grid where the key axis shards under GSPMD placement
+        (the mesh operator holds a ring per device: its override)."""
         cls = PaneRing if self.sharding is None else KeyGrid
         return cls(self._K, self._P)
 
@@ -861,7 +864,7 @@ class WindowAggOperator(StreamOperator):
         self._leaves = tuple(leaves)
 
     def _alloc(self, K: int, P: int):
-        layout = type(self._layout)(K, P)
+        layout = dataclasses.replace(self._layout, K=K, P=P)
         leaves = [layout.full(init, shape, dtype)
                   for init, shape, dtype in zip(self.spec.leaf_inits,
                                                 self.spec.leaf_shapes,

@@ -22,8 +22,9 @@ padding rows use that to make batch shapes static (no recompiles per batch).
 
 Every function here folds into FLAT state: one leading axis of cells.  How
 the window operator's K x P pane cells map onto that axis — the pane-major
-ring it holds on one chip, whose arrays are their own flat view, or the
-key-major ``[K, P]`` grid a mesh shards — is ``ops/pane_layout.py``'s
+ring it holds on one chip and, a block a device, on a mesh, whose arrays
+are their own flat view, or the key-major ``[K, P]`` grid that GSPMD
+placement shards — is ``ops/pane_layout.py``'s
 business; its ``fold`` hands ``scatter_fold_counts`` the flat arrays and the
 cell ids in that layout.
 """

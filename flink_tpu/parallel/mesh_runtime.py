@@ -45,7 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from flink_tpu.operators.session_window import SessionWindowOperator
 from flink_tpu.operators.window_agg import (WindowAggOperator, _PAD_ID,
                                             _next_pow2)
-from flink_tpu.ops.pane_layout import KeyGrid
+from flink_tpu.ops.pane_layout import ShardRing
 from flink_tpu.parallel.mesh import KG_AXIS, make_mesh, state_sharding
 
 
@@ -87,9 +87,18 @@ class MeshWindowAggOperator(WindowAggOperator):
       (``state/shard_layout.split_to_shard_slices``); restore at any mesh
       size (single-chip included) re-slices by the reader's layout.
 
+    The state is held as the fold scatters into it
+    (``ops/pane_layout.ShardRing``): every state array is ONE 1-D array
+    ``[D * P * K/D, *leaf]`` sharded over ``KG_AXIS`` on axis 0, so device
+    ``d``'s block is the pane-major ring ``PaneRing(K/D, P)`` of the key
+    rows it owns, and the step touches it with one in-place scatter on the
+    donated block: no flatten, no copy.  Fires, cuts and restores read and
+    write ``[K, m]`` pane columns in global key order through the layout,
+    so the snapshot format does not know how a block is held.
+
     Chained dispatches stay pre-partitioned end-to-end: state flows out of
-    the ``shard_map`` step with ``out_specs == in_specs`` (key-slot axis on
-    ``KG_AXIS``), a batch is the base class's staged ``(flat_ids,
+    the ``shard_map`` step with ``out_specs == in_specs`` (a device's ring
+    block on ``KG_AXIS``), a batch is the base class's staged ``(flat_ids,
     *values)`` ``device_put`` row-split onto the same axis in one call (the
     flat id and the value columns ride the exchange; slot, pane and
     destination are derived from the id on the device), and nothing in
@@ -119,6 +128,11 @@ class MeshWindowAggOperator(WindowAggOperator):
         self._exchange_cap_hw = 0
 
     # ---------------------------------------------------------------- layout
+    @property
+    def _layout(self):
+        """One pane-major ring per device (``ops/pane_layout.py``)."""
+        return ShardRing(self._K, self._P, self.mesh)
+
     def shard_layout(self):
         """The key-group-range state layout (shared by snapshots, the
         sharded probe, and the record router)."""
@@ -166,8 +180,8 @@ class MeshWindowAggOperator(WindowAggOperator):
         return snap
 
     # ------------------------------------------------------------- device op
-    @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(1,))
-    def _mesh_update_step(self, leaves_counts, batch, cap: int):
+    @partial(jax.jit, static_argnums=(0, 1, 4), donate_argnums=(2,))
+    def _mesh_update_step(self, layout, leaves_counts, batch, cap: int):
         """One sharded micro-batch into the state: per-device bucket by
         destination → ``all_to_all`` over ICI → scatter-combine into the
         local block.  ``batch`` = (flat_ids, *values), each row-split over
@@ -184,7 +198,7 @@ class MeshWindowAggOperator(WindowAggOperator):
         operations can be told apart by stage."""
         leaves, counts = leaves_counts
         D = self.n_shards
-        K, Pn = counts.shape
+        K, Pn = layout.K, layout.P
         span = (K // D) * Pn          # flat ids one shard owns
 
         def step(leaves, counts, ids, *values):
@@ -208,8 +222,8 @@ class MeshWindowAggOperator(WindowAggOperator):
                 rx_vals = tuple(all_to_all_rows(v).reshape((D * cap,)
                                                            + v.shape[2:])
                                 for v in b_vals)
-            # ---- local scatter-combine (this device's key-slot block):
-            # the single-chip fold; rows that are not ``ok`` carry the
+            # ---- local scatter-combine: the single-chip fold, in place on
+            # this device's ring block; rows that are not ``ok`` carry the
             # dropped id ``span``
             with jax.named_scope("shard_fold"):
                 lo = jax.lax.axis_index(KG_AXIS).astype(jnp.int32) * span
@@ -218,9 +232,9 @@ class MeshWindowAggOperator(WindowAggOperator):
                 lflat = jnp.where(ok, local, span)
                 lifted = tuple(jax.tree_util.tree_leaves(
                     self.agg.lift(self._values_tree(rx_vals))))
-                return KeyGrid(K // D, Pn).fold(leaves, counts, lflat,
-                                                lifted, self.kinds,
-                                                self.agg.combine_leaves)
+                return layout.local.fold(leaves, counts, lflat, lifted,
+                                         self.kinds,
+                                         self.agg.combine_leaves)
 
         rows = P(KG_AXIS)
         state_specs = ((rows,) * len(leaves), rows)
@@ -331,7 +345,7 @@ class MeshWindowAggOperator(WindowAggOperator):
         owning shard."""
         batch, cap = self._route_batch(flat_ids, values)
         self._leaves, self._counts = self._mesh_update_step(
-            (self._leaves, self._counts), batch, cap)
+            layout, (self._leaves, self._counts), batch, cap)
         return self._leaves, self._counts
 
     def _round_key_capacity(self, needed: int) -> int:

@@ -1,7 +1,8 @@
 """Sharding-aware keyed-state layout: one logical state, per-shard slices.
 
 The mesh-sharded ``WindowAggOperator`` (``parallel/mesh_runtime.py``) keeps
-its ``[K, P, *leaf]`` pane ring physically split over a 1-D device mesh:
+its K x P pane cells physically split over a 1-D device mesh (how a device
+holds its block is ``ops/pane_layout.ShardRing``'s business):
 device ``d`` owns the CONTIGUOUS key-slot block ``[d*K/D, (d+1)*K/D)`` —
 the key-group ranges of ``KeyGroupRangeAssignment.java:50-84`` mapped onto
 mesh positions (``parallel/mesh.py``).  This module is the snapshot face of
