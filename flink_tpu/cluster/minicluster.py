@@ -1091,13 +1091,14 @@ class MiniCluster(TaskListener):
                  else {})
         vertices = []
         for uid, ts in by_vertex.items():
-            total_ns = max(1, sum(t.busy_ns + t.idle_ns + t.backpressure_ns
-                                  for t in ts))
+            # one reading of each task's three gauges (busy is the loop's
+            # wall time less the other two: read apart they would not sum)
+            times = {t: t.loop_times_ns() for t in ts}
+            total_ns = max(1, sum(sum(x) for x in times.values()))
 
             def ratios(t):
-                tot = max(1, t.busy_ns + t.idle_ns + t.backpressure_ns)
-                return (t.busy_ns / tot, t.idle_ns / tot,
-                        t.backpressure_ns / tot)
+                tot = max(1, sum(times[t]))
+                return tuple(x / tot for x in times[t])
 
             subtasks = []
             for t in sorted(ts, key=lambda t: t.subtask_index):
@@ -1107,9 +1108,13 @@ class MiniCluster(TaskListener):
                     "records_in": t.records_in,
                     "records_out": t.records_out,
                     "key_group_records": t.key_group_records,
-                    "sql_projections": t.sql_projections,
+                    "chain_stats": t.chain_stats,
                     "busy_ratio": b, "idle_ratio": i,
-                    "backpressure_ratio": bp}
+                    "backpressure_ratio": bp,
+                    # CPU the task thread used over its loop's wall time:
+                    # a busy thread well under 1 is waiting (GIL, device)
+                    "cpu_ratio": t.cpu_ns / max(1, sum(times[t])),
+                    "thread_cpu_ns": t.thread_cpu_ns()}
                 # channel-consuming subtasks: per-channel queue depth /
                 # backpressured time + the alignment-queue gauge
                 chan_fn = getattr(t, "channel_stats", None)
@@ -1125,10 +1130,11 @@ class MiniCluster(TaskListener):
                 "status": sorted({t.state for t in ts}),
                 "records_in": sum(t.records_in for t in ts),
                 "records_out": sum(t.records_out for t in ts),
-                "busy_ratio": sum(t.busy_ns for t in ts) / total_ns,
-                "idle_ratio": sum(t.idle_ns for t in ts) / total_ns,
+                "busy_ratio": sum(x[0] for x in times.values()) / total_ns,
+                "idle_ratio": sum(x[1] for x in times.values()) / total_ns,
                 "backpressure_ratio":
-                    sum(t.backpressure_ns for t in ts) / total_ns,
+                    sum(x[2] for x in times.values()) / total_ns,
+                "cpu_ratio": sum(t.cpu_ns for t in ts) / total_ns,
                 "watermark": _vertex_watermark(ts),
                 "subtasks": subtasks,
             })

@@ -41,12 +41,14 @@ import os
 import socket
 import struct
 import threading
+import time
 from collections import deque
 from typing import Dict, Optional
 
 from flink_tpu.core.batch import (CheckpointBarrier, EndOfInput,
                                   LatencyMarker, RecordBatch, StreamElement,
                                   StreamStatus, TaggedBatch, Watermark)
+from flink_tpu.observability import tracing
 
 _HDR = struct.Struct("<BI")
 _BATCH, _CONTROL, _CREDIT, _HELLO, _TAGGED, _CHALLENGE = 0, 1, 2, 3, 4, 5
@@ -154,8 +156,9 @@ class _ReceiveQueue:
         self._not_empty = threading.Condition(self._lock)
         self._conn: Optional[socket.socket] = None
         self._closed = False
-        #: remote channels measure producer credit-waits sender-side; the
-        #: consumer-side gauge stays 0 here (shape parity w/ LocalChannel)
+        #: remote channels measure producer credit-waits sender-side
+        #: (``RemoteChannel.backpressured_ns``); the consumer-side gauge
+        #: stays 0 here (shape parity w/ LocalChannel)
         self.backpressured_ns = 0
         #: queued-barrier announcement (LocalChannel contract)
         self._announced: deque = deque()
@@ -401,6 +404,8 @@ class RemoteChannel:
         #: as an error, not as silent backpressure-drop
         self._error: Optional[str] = None
         self._got_credit = False
+        #: time ``put`` waited for credit (``Task.backpressure_ns`` sums it)
+        self.backpressured_ns = 0
         self._reader = threading.Thread(target=self._credit_loop,
                                         name=f"credits-{channel_id}",
                                         daemon=True)
@@ -459,9 +464,17 @@ class RemoteChannel:
         from flink_tpu.native.codec import encode_batch
 
         with self._have_credit:
-            while self._credits <= 0 and not self._closed:
-                if not self._have_credit.wait(timeout=timeout_s):
-                    return False
+            if self._credits <= 0 and not self._closed:
+                # the producer's backpressure proper, as LocalChannel.put
+                t0 = time.monotonic_ns()
+                try:
+                    with tracing.span("exchange.put_wait", cat="exchange",
+                                      channel=self.channel_id):
+                        while self._credits <= 0 and not self._closed:
+                            if not self._have_credit.wait(timeout=timeout_s):
+                                return False
+                finally:
+                    self.backpressured_ns += time.monotonic_ns() - t0
             if self._closed:
                 if self._error is not None:
                     # auth rejection: dropping silently would let the job

@@ -38,6 +38,7 @@ from flink_tpu.core.functions import RuntimeContext
 from flink_tpu.cluster.channels import (LocalChannel, OutputDispatcher,
                                         element_bytes)
 from flink_tpu.observability import tracing
+from flink_tpu.operators.chain import MemberMeter, merge_member_stats
 from flink_tpu.runtime.executor import WatermarkValve
 from flink_tpu.testing import chaos
 from flink_tpu.utils import clock
@@ -89,11 +90,24 @@ class SubtaskBase:
         self.state = TaskStates.DEPLOYING
         self._thread: Optional[threading.Thread] = None
         self._cancelled = threading.Event()
-        #: busy/idle/backpressure time accounting (TimerGauge analog,
-        #: ``runtime/metrics/TimerGauge.java`` — surfaced by the REST API)
-        self.busy_ns = 0
+        #: time accounting of the task thread's loop (the TimerGauge analog,
+        #: ``runtime/metrics/TimerGauge.java`` — surfaced by the REST API).
+        #: ``idle_ns`` is the time the loop waited for input;
+        #: ``backpressure_ns`` (a property) the time its puts were blocked on
+        #: a full channel; ``busy_ns`` (a property) the loop's wall time less
+        #: those two, as the reference computes it — so batches, fires,
+        #: watermarks, the partition's work, a source's ``next`` and a
+        #: barrier's snapshot are all busy, and the three cover the loop
         self.idle_ns = 0
-        self.backpressure_ns = 0
+        self._loop_t0_ns: Optional[int] = None
+        self._loop_end_ns: Optional[int] = None
+        #: the task thread's CPU time when it left the loop (its clock dies
+        #: with it); while it runs, ``cpu_ns`` reads the clock from outside
+        self._cpu_final_ns: Optional[int] = None
+        #: span + counters of an operator that is no chain (a chain keeps
+        #: its members' itself): ``chain_stats``
+        self._meter = (None if hasattr(operator, "meters")
+                       else MemberMeter(operator))
         self.records_in = 0
         self.records_out = 0
         #: per-(source, hop) latency recorder (observability/latency.py):
@@ -135,15 +149,93 @@ class SubtaskBase:
 
     # -- shared plumbing -----------------------------------------------------
     def _emit(self, elements: Sequence[StreamElement]) -> None:
-        t0 = time.monotonic_ns()
         for el in elements:
             if isinstance(el, RecordBatch):
                 self.records_out += len(el)
             for out in self.outputs:
                 out.emit(el)
-        # time spent pushing into (possibly full) output channels is
-        # backpressure: the reference gauges recordWriter availability
-        self.backpressure_ns += time.monotonic_ns() - t0
+
+    # -- the thread's account of its time ------------------------------------
+    @property
+    def backpressure_ns(self) -> int:
+        """Time this task's puts were blocked on a full output channel
+        (what ``exchange.put_wait`` spans; the reference gauges
+        recordWriter availability the same way): each channel has one
+        producer, so its ``backpressured_ns`` is this task's."""
+        return sum(getattr(ch, "backpressured_ns", 0)
+                   for out in self.outputs
+                   for ch in getattr(out, "channels", ()))
+
+    def loop_times_ns(self) -> "tuple[int, int, int]":
+        """``(busy, idle, backpressure)`` from ONE reading of the clock:
+        they sum to the loop's wall time so far.  Busy is what was
+        neither a wait for input nor a blocked put (a wait still going on
+        counts as busy until it ends)."""
+        t0 = self._loop_t0_ns
+        if t0 is None:
+            return 0, 0, 0
+        end = self._loop_end_ns
+        wall = (time.monotonic_ns() if end is None else end) - t0
+        idle, blocked = self.idle_ns, self.backpressure_ns
+        return max(0, wall - idle - blocked), idle, blocked
+
+    @property
+    def busy_ns(self) -> int:
+        return self.loop_times_ns()[0]
+
+    @property
+    def cpu_ns(self) -> int:
+        """CPU time the task thread has used, read from outside it while
+        it runs (its POSIX CPU clock: nothing on the hot path pays for
+        it).  CPU over busy + idle + backpressure is ``cpu_ratio`` in
+        ``job_status()``: a busy thread well under 1 is waiting — for the
+        GIL, a lock or the device."""
+        final = self._cpu_final_ns
+        if final is not None:
+            return final
+        return tracing.thread_cpu_ns(self._thread) or 0
+
+    def thread_cpu_ns(self) -> Dict[str, int]:
+        """``{thread name: CPU ns}`` of the task thread and the threads
+        that work for it alone: the device-health dispatch lane of each,
+        and the operators' own (the hot-stage pipeline's worker)."""
+        from flink_tpu.runtime import device_health
+
+        if self._thread is None:
+            return {}
+        out = {self._thread.name: self.cpu_ns}
+        owned = [t for op in self._chain()
+                 for t in getattr(op, "owned_threads", list)()]
+        mon = device_health.get_monitor(create=False)
+        if mon is not None:
+            owned += [mon.lane_thread(t) for t in [self._thread] + owned]
+        for thread in owned:
+            ns = tracing.thread_cpu_ns(thread)
+            if ns is not None:
+                out[thread.name] = ns
+        return out
+
+    def _chain(self) -> list:
+        return getattr(self.operator, "operators", None) or [self.operator]
+
+    @property
+    def chain_stats(self) -> Dict[str, Dict[str, int]]:
+        """``{span: {batches, rows, ns, cpu_ns}}``: one counter set per
+        chained operator, kept by the chain around each member's
+        ``process_batch`` under the member's span (``chain.<name>``, or
+        the span the member opens itself: ``sql.pre_project``,
+        ``window_agg.process_batch``, ``sql.project``, ``sink.invoke``);
+        one entry for an operator that is no chain."""
+        meters = ([self._meter] if self._meter is not None
+                  else self.operator.meters)
+        return merge_member_stats(meters)
+
+    def _process_batch(self, batch: RecordBatch) -> List[StreamElement]:
+        with tracing.span("task.process_batch", cat="task",
+                          records=len(batch)):
+            if self._meter is not None:
+                return self._meter.process_batch(batch)
+            return self.operator.process_batch(batch)
 
     @property
     def key_group_records(self) -> Dict[str, int]:
@@ -151,7 +243,7 @@ class SubtaskBase:
         (``computed``), and records its key-by operators found keyed for
         their own key with the key groups there (``carried``) or named the
         key of without deriving anything (``unread``)."""
-        chain = getattr(self.operator, "operators", None) or [self.operator]
+        chain = self._chain()
         return {
             "computed": sum(getattr(out, "key_groups_computed", 0)
                             for out in self.outputs),
@@ -159,22 +251,6 @@ class SubtaskBase:
                            for op in chain),
             "unread": sum(getattr(op, "key_groups_unread", 0)
                           for op in chain)}
-
-    @property
-    def sql_projections(self) -> Dict[str, Dict[str, int]]:
-        """``{span: {batches, rows, ns}}`` of the SQL planner's projection
-        maps chained into this task (``sql.pre_project``, ``sql.project``),
-        summed where a plan chains more than one of a kind; empty for a
-        task that runs none."""
-        chain = getattr(self.operator, "operators", None) or [self.operator]
-        out: Dict[str, Dict[str, int]] = {}
-        for op in chain:
-            stats = getattr(op, "projection_stats", None)
-            if stats is not None:
-                into = out.setdefault(op.span, dict.fromkeys(stats, 0))
-                for key, value in stats.items():
-                    into[key] += value
-        return out
 
     def _transition(self, state: str, error: Optional[str] = None) -> None:
         self.state = state
@@ -240,6 +316,7 @@ class SubtaskBase:
             self._open_and_restore()
             self._transition(TaskStates.RUNNING)
             self._wait_deploy_gate()
+            self._loop_t0_ns = time.monotonic_ns()
             self._invoke()
             # FLIP-147 (checkpoints after tasks finish): capture the FINAL
             # state so checkpoints completing after this task ends still
@@ -258,6 +335,8 @@ class SubtaskBase:
             traceback.print_exc()
             self._transition(TaskStates.FAILED, f"{type(e).__name__}: {e}")
         finally:
+            self._loop_end_ns = time.monotonic_ns()
+            self._cpu_final_ns = time.thread_time_ns()
             # FAILED/CANCELED tasks must still release operator resources
             # (managed-memory reservations, spill files, sockets): the slot's
             # MemoryManager pool is reused across pipelined-region restarts,
@@ -368,7 +447,9 @@ class SourceSubtask(SubtaskBase):
                     if cur is None:
                         if done:
                             break
+                        t0 = time.monotonic_ns()
                         time.sleep(0.01)   # nothing yet: poll again
+                        self.idle_ns += time.monotonic_ns() - t0
                         continue
                     skip = 0
                 self._current_split = cur
@@ -397,7 +478,9 @@ class SourceSubtask(SubtaskBase):
             self._drain_commands()
             self._tick_processing_time()
             if self._paused.is_set():
+                t0 = time.monotonic_ns()
                 time.sleep(0.002)  # paused: commands/cancel only
+                self.idle_ns += time.monotonic_ns() - t0
                 continue
             try:
                 with tracing.span("source.next", cat="source"):
@@ -420,12 +503,7 @@ class SourceSubtask(SubtaskBase):
                     self._emit([LatencyMarker(clock.now_ms_f() / 1000.0,
                                               subtask_index=self.subtask_index,
                                               source=self.vertex_uid)])
-                t0 = time.monotonic_ns()
-                with tracing.span("task.process_batch", cat="task",
-                                  records=len(el)):
-                    out = self.operator.process_batch(el)
-                self.busy_ns += time.monotonic_ns() - t0
-                self._emit(out)
+                self._emit(self._process_batch(el))
             elif isinstance(el, Watermark):
                 self._emit(self.operator.process_watermark(el))
                 if self.operator.forwards_watermarks:
@@ -693,11 +771,9 @@ class Subtask(SubtaskBase):
                     if not self._ended[i] and not self._is_blocked(i):
                         with tracing.span("task.input_wait", cat="task"):
                             el = ch.poll(timeout_s=0.01)
+                        self.idle_ns += time.monotonic_ns() - t0
                         if el is not None:
-                            self.idle_ns += time.monotonic_ns() - t0
                             self._handle(i, el)
-                        else:
-                            self.idle_ns += time.monotonic_ns() - t0
                         break
         self._emit(self.operator.end_input())
         self._emit([EndOfInput()])
@@ -1095,15 +1171,13 @@ class Subtask(SubtaskBase):
                            subtask=self.subtask_index)
                 self._emit_status_change(self._valve.record_activity(i))
                 self.records_in += len(el)
-                t0 = time.monotonic_ns()
-                with tracing.span("task.process_batch", cat="task",
-                                  records=len(el)):
-                    if getattr(self.operator, "is_two_input", False):
+                if getattr(self.operator, "is_two_input", False):
+                    with tracing.span("task.process_batch", cat="task",
+                                      records=len(el)):
                         out = self.operator.process_batch2(
                             el, self.input_logical[i])
-                    else:
-                        out = self.operator.process_batch(el)
-                self.busy_ns += time.monotonic_ns() - t0
+                else:
+                    out = self._process_batch(el)
                 self._emit(out)
         elif isinstance(el, LatencyMarker):
             # LatencyMarker flows around user functions; sinks record it.

@@ -207,11 +207,14 @@ class MetricOptions:
         "every span the runtime emits, exported as Chrome trace-event JSON "
         "(REST /jobs/<id>/trace, Perfetto-viewable): source.next, "
         "exchange.partition / .put_wait, task.input_wait / .process_batch, "
-        "window_agg.probe / .probe_mirror / .mirror / .stage / .device_step, "
+        "chain.<operator>, window_agg.process_batch with .probe / "
+        ".probe_mirror / .mirror / .stage / .device_step (of it "
+        "device.handoff_wait, window_agg.exchange_route, window_agg.launch, "
+        "device.return_wait), "
         "window_agg.fire with .fire_dispatch / .fire_d2h / .fire_assemble, "
         "checkpoint.trigger / .barrier / .align / .alignment / .snapshot / "
         ".ack with window_agg.snapshot / .snapshot_d2h / .snapshot_assemble, "
-        "sink.invoke, and the device_health.*, paging.*, mesh.exchange, "
+        "sink.invoke, and the device_health.*, paging.*, "
         "cep.vectorized_drain, rescale.* and queryable.* events.  The same "
         "spans reach a running jax.profiler session whether or not this key "
         "is set.")

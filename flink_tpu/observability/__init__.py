@@ -6,8 +6,9 @@ Two cooperating layers (ISSUE-10), both cheap enough to leave on:
   **span journal** (begin/end/instant events through the injectable clock
   seam, bounded memory, drop counter) with instrumentation at the
   runtime's load-bearing sites: hot-stage phases, the checkpoint
-  lifecycle, device-health transitions, pager traffic, mesh exchange
-  dispatch and CEP vectorized drains.  Exports Chrome trace-event JSON
+  lifecycle, the device dispatch's thread hand-offs, device-health
+  transitions, pager traffic and CEP vectorized drains.  Exports Chrome
+  trace-event JSON
   (Perfetto-viewable); :mod:`flink_tpu.observability.assembly` merges
   per-worker journals into ONE job timeline with clock-offset estimation.
 - :mod:`flink_tpu.observability.latency` — Dapper-style always-on

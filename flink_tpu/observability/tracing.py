@@ -6,14 +6,22 @@ span goes to two sinks.
   (TraceMe) of the same name, its keyword arguments the event's stats, so
   it lands on a ``/host:`` line of the ``.xplane.pb`` on the clock of
   ``XLA Ops`` whenever a profiler session is running in the process —
-  whoever started it.  TraceMe is inert without a session (well under a
-  microsecond), so the hot paths afford unconditional instrumentation and
-  there is nothing to switch on.
+  whoever started it.  With no session (and no journal) ``span`` hands
+  out one shared no-op, so the hot paths afford unconditional
+  instrumentation and there is nothing to switch on.
 - **The span journal**, a lock-free per-process ring, when one is
   installed with :func:`install` (``metrics.tracing.enabled``): the
   operator's own view, served as Chrome trace JSON by the REST API and
   merged across worker processes.  With no journal installed the emit
   helpers allocate no journal entry.
+
+Beside the spans, the same module keeps **a thread's account of its own
+time**: :class:`PhaseTimer` is a span that adds its wall time AND the
+running thread's CPU time to a :class:`TimeAccount` (the window
+operator's ``phase_ns``), and :func:`thread_cpu_ns` reads a thread's CPU
+clock from outside it (``Task.cpu_ns``).  Wall minus CPU is what a span
+alone cannot show: the time a thread waited for the GIL, a lock or the
+device.
 
 Design points:
 
@@ -51,7 +59,8 @@ from jax.profiler import TraceAnnotation
 from flink_tpu.utils import clock
 
 __all__ = ["SpanJournal", "install", "uninstall", "active", "enabled",
-           "span", "instant", "complete", "to_chrome",
+           "span", "instant", "complete", "CpuShare", "TimeAccount",
+           "PhaseTimer", "thread_cpu_ns", "to_chrome",
            "acquire_for_execution", "release_after_execution"]
 
 #: default ring capacity — ~8k spans cover minutes of checkpoint/phase
@@ -274,14 +283,35 @@ class _SpanCtx:
         return False
 
 
+class _NoSpan:
+    """What :func:`span` hands out while neither sink is open."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
 def span(name: str, cat: str = "runtime", **args):
     """Begin/end span context manager: a profiler annotation ``name`` with
     ``args`` as its stats and, with a journal installed, a ``ph: "X"``
     journal entry under ``cat``.  Spans on one thread nest; a span that
     outlives its block (``checkpoint.align``) is entered and exited by
-    hand, on one thread, outside any span opened after it."""
+    hand, on one thread, outside any span opened after it.  With no
+    journal installed and no profiler session running the answer is one
+    shared no-op (a quarter of the cost of an inert annotation, and the
+    task threads share one GIL: PERF.md section 6, PR 37); a span that is
+    open when a session starts is not in its trace."""
     if _JOURNAL is None:
-        return TraceAnnotation(name, **args)    # inert without a session
+        if not TraceAnnotation.is_enabled():
+            return _NO_SPAN
+        return TraceAnnotation(name, **args)
     return _SpanCtx(name, cat, args)
 
 
@@ -310,6 +340,119 @@ def complete(name: str, start_ns: int, end_ns: int,
     if TraceAnnotation.is_enabled():
         with TraceAnnotation(name, dur_ns=dur, **args):
             pass
+
+
+#: a thread's CPU clock is read at most this often per account and key.
+#: ``time.thread_time_ns`` is a system call (no vDSO path): 0.36 us on a
+#: plain Linux host, but 5.75 us on the TPU host's sealed VM, with the GIL
+#: held — read on every entry of every phase it cost cell 1 a tenth of its
+#: records per second, and ten times a second a phase still a part of one
+#: per cent (chip, PR 37; PERF.md section 6)
+CPU_READ_EVERY_NS = 1_000_000_000
+
+
+class CpuShare:
+    """When a region's next reading of the CPU clock is due, and the CPU
+    share of its wall time that the last reading found.
+
+    The clock is read for one entry of a region every
+    ``CPU_READ_EVERY_NS`` (always for one entered more rarely: a fire, a
+    cut); an entry in between is given the share of the last entry that
+    was read, of its own wall time.  So a region's CPU time is exact where
+    it is rare and an estimate from one reading a second where it runs per
+    batch.  Where the thread's clock is exact (a plain Linux host) CPU <=
+    wall.  Where it advances in scheduler ticks (the TPU host's VM: 10 ms)
+    one reading of a short region is nothing or a whole tick: right on
+    average, to be trusted only over some hundreds of readings, and a
+    thread's total better from :func:`thread_cpu_ns`."""
+
+    __slots__ = ("due", "share")
+
+    def __init__(self):
+        self.due = 0
+        self.share = 1.0
+
+    def settle(self, t0: int, wall: int, cpu: Optional[int]) -> int:
+        """CPU ns of an entry begun at ``t0`` that took ``wall``: ``cpu``
+        where the clock was read (``t0 >= due``), else the estimate."""
+        if cpu is None:
+            return int(wall * self.share)
+        self.due = t0 + CPU_READ_EVERY_NS
+        self.share = cpu / wall if wall else 1.0
+        return cpu
+
+
+class TimeAccount(dict):
+    """``{key: wall ns, key + "_cpu": CPU ns}``, filled by
+    :class:`PhaseTimer`: a plain dict to every reader (the window
+    operator's ``phase_ns``).  Beside the entries it keeps each key's
+    :class:`CpuShare`."""
+
+    __slots__ = ("cpu_shares",)
+
+    def __init__(self):
+        super().__init__()
+        self.cpu_shares: Dict[str, CpuShare] = {}
+
+
+class PhaseTimer:
+    """A span that also keeps the account of its thread's time: on exit
+    ``acc[key]`` grows by the wall time (``perf_counter_ns``) and
+    ``acc[key + "_cpu"]`` by the CPU time the running thread used
+    (``thread_time_ns``, as often as :class:`CpuShare` says).  Wall minus
+    CPU is time the thread was off the CPU: waiting for the GIL, the
+    scheduler, a lock, or the device.  Whoever reads ``phase_ns["probe"]``
+    reads ``phase_ns["probe_cpu"]`` the same way.  ``name`` None opens no
+    span."""
+
+    __slots__ = ("_acc", "_key", "_span", "_t0", "_c0", "_share")
+
+    def __init__(self, acc: TimeAccount, key: str, name: Optional[str],
+                 cat: str = "runtime", args: Optional[Dict[str, Any]] = None):
+        self._acc = acc
+        self._key = key
+        if name is None:
+            self._span = _NO_SPAN
+        elif args:
+            self._span = span(name, cat, **args)
+        else:
+            self._span = span(name, cat)
+
+    def __enter__(self):
+        self._span.__enter__()
+        share = self._acc.cpu_shares.get(self._key)
+        if share is None:
+            share = self._acc.cpu_shares[self._key] = CpuShare()
+        self._share = share
+        # the CPU reading inside the wall reading: cpu <= wall holds
+        self._t0 = t0 = time.perf_counter_ns()
+        self._c0 = time.thread_time_ns() if t0 >= share.due else None
+        return self
+
+    def __exit__(self, *exc):
+        c0 = self._c0
+        cpu = None if c0 is None else time.thread_time_ns() - c0
+        t0 = self._t0
+        wall = time.perf_counter_ns() - t0
+        acc, key = self._acc, self._key
+        acc[key] = acc.get(key, 0) + wall
+        key += "_cpu"
+        acc[key] = acc.get(key, 0) + self._share.settle(t0, wall, cpu)
+        self._span.__exit__(*exc)
+        return False
+
+
+def thread_cpu_ns(thread: Optional[threading.Thread]) -> Optional[int]:
+    """CPU time ``thread`` has used so far, read from OUTSIDE it (its
+    POSIX CPU clock): costs the watched thread nothing.  None for a thread
+    that is not running (its clock is gone) or where the platform has no
+    such clock."""
+    if thread is None or thread.ident is None or not thread.is_alive():
+        return None
+    try:
+        return time.clock_gettime_ns(time.pthread_getcpuclockid(thread.ident))
+    except (AttributeError, OSError):
+        return None
 
 
 # ---------------------------------------------------------------------------

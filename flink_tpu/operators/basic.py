@@ -310,6 +310,9 @@ class SideOutputOperator(StreamOperator):
 class SinkOperator(StreamOperator):
     """Terminal operator wrapping a sink function (``StreamSink`` analog)."""
 
+    #: the span ``process_batch`` opens (a chain counts it under that name)
+    span = "sink.invoke"
+
     def __init__(self, sink, name: str = "sink"):
         import copy as _copy
 
@@ -331,7 +334,7 @@ class SinkOperator(StreamOperator):
             self.sink.open(ctx)
 
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
-        with tracing.span("sink.invoke", cat="sink", records=len(batch),
+        with tracing.span(self.span, cat="sink", records=len(batch),
                           **fire_cause(batch)):
             self.sink.write_batch(batch)
         return []

@@ -10,11 +10,76 @@ control element itself — the same ordering the reference's
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List
 
 from flink_tpu.core.batch import RecordBatch, StreamElement, Watermark
 from flink_tpu.core.functions import RuntimeContext
+from flink_tpu.observability import tracing
 from flink_tpu.operators.base import StreamOperator
+
+
+class MemberMeter:
+    """One operator's ``process_batch`` as its driver (a chain, or a task
+    whose operator is no chain) sees it: a span named by the operator and
+    one counter set, ``{batches, rows, ns, cpu_ns}`` — batches and records
+    IN, wall time, and the CPU time of the calling thread (read as often
+    as ``tracing.CpuShare`` says).  An operator that opens a span around
+    its own ``process_batch`` says so with a ``span`` attribute
+    (``sink.invoke``, ``sql.project``, ``window_agg.process_batch``): it
+    is counted under that name and no second span is opened; one that
+    times that span too says so with ``span_time_ns()`` and is not timed
+    twice.  Any other operator is spanned here as ``chain.<op.name>`` with
+    ``records=``."""
+
+    __slots__ = ("op", "span", "_own_span", "_own_time", "batches", "rows",
+                 "ns", "cpu_ns", "_share")
+
+    def __init__(self, op: StreamOperator):
+        self.op = op
+        own = getattr(op, "span", None)
+        self._own_span = own is not None
+        self._own_time = getattr(op, "span_time_ns", None)
+        self.span = own if own is not None else f"chain.{op.name}"
+        self.batches = self.rows = self.ns = self.cpu_ns = 0
+        self._share = tracing.CpuShare()
+
+    def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
+        self.batches += 1
+        self.rows += len(batch)
+        if self._own_time is not None:
+            return self.op.process_batch(batch)
+        t0 = time.perf_counter_ns()
+        c0 = time.thread_time_ns() if t0 >= self._share.due else None
+        if self._own_span:
+            out = self.op.process_batch(batch)
+        else:
+            with tracing.span(self.span, cat="chain", records=len(batch)):
+                out = self.op.process_batch(batch)
+        cpu = None if c0 is None else time.thread_time_ns() - c0
+        wall = time.perf_counter_ns() - t0
+        self.ns += wall
+        self.cpu_ns += self._share.settle(t0, wall, cpu)
+        return out
+
+    def stats(self) -> Dict[str, int]:
+        ns, cpu_ns = (self._own_time() if self._own_time is not None
+                      else (self.ns, self.cpu_ns))
+        return {"batches": self.batches, "rows": self.rows, "ns": ns,
+                "cpu_ns": cpu_ns}
+
+
+def merge_member_stats(meters) -> Dict[str, Dict[str, int]]:
+    """``{span: {batches, rows, ns, cpu_ns}}`` over ``meters``, summed
+    where two members share a span (a plan that chains two maps of one
+    kind)."""
+    out: Dict[str, Dict[str, int]] = {}
+    for meter in meters:
+        into = out.setdefault(meter.span, dict.fromkeys(
+            ("batches", "rows", "ns", "cpu_ns"), 0))
+        for key, value in meter.stats().items():
+            into[key] += value
+    return out
 
 
 class ChainedOperator(StreamOperator):
@@ -23,6 +88,8 @@ class ChainedOperator(StreamOperator):
         self.name = name
         self.is_stateless = all(op.is_stateless for op in operators)
         self.forwards_watermarks = all(op.forwards_watermarks for op in operators)
+        #: one span and one counter set per member (``Task.chain_stats``)
+        self.meters = [MemberMeter(op) for op in operators]
 
     def open(self, ctx: RuntimeContext) -> None:
         super().open(ctx)
@@ -31,11 +98,12 @@ class ChainedOperator(StreamOperator):
 
     def _feed(self, start: int, elements: List[StreamElement]) -> List[StreamElement]:
         """Push elements through chain members [start:]; returns chain output."""
-        for op in self.operators[start:]:
+        for meter in self.meters[start:]:
+            op = meter.op
             nxt: List[StreamElement] = []
             for el in elements:
                 if isinstance(el, RecordBatch):
-                    nxt.extend(op.process_batch(el))
+                    nxt.extend(meter.process_batch(el))
                 elif isinstance(el, Watermark):
                     nxt.extend(op.process_watermark(el))
                     if op.forwards_watermarks:

@@ -1365,27 +1365,19 @@ class SqlProjectionOperator(MapOperator):
     batch (after a group aggregate, every fired batch, on the window task's
     thread) into the select list.  A plain chained map with a span of its
     own (``sql.pre_project`` / ``sql.project``, with the fire's
-    ``window_end`` where the rows carry one) and three counters, so a trace
-    and ``job_status()`` tell the plan's host work from the rest of the
-    chain."""
+    ``window_end`` where the rows carry one), under which the chain keeps
+    its counters (``Task.chain_stats``), so a trace and ``job_status()``
+    tell the plan's host work from the rest of the chain."""
 
     def __init__(self, fn: Callable[[Dict[str, Any]], Dict[str, Any]],
                  name: str, span: str):
         super().__init__(fn, name)
         self.span = span
-        #: batches and rows through the map, and the time spent in it
-        self.projection_stats = {"batches": 0, "rows": 0, "ns": 0}
 
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
-        t0 = time.perf_counter_ns()
         with tracing.span(self.span, cat="sql", records=len(batch),
                           **fire_cause(batch)):
-            out = super().process_batch(batch)
-        stats = self.projection_stats
-        stats["batches"] += 1
-        stats["rows"] += len(batch)
-        stats["ns"] += time.perf_counter_ns() - t0
-        return out
+            return super().process_batch(batch)
 
 
 class MiniBatchOperator(StreamOperator):

@@ -284,34 +284,6 @@ def phase_span_name(phase: str) -> str:
     return "window_agg." + phase
 
 
-class _PhaseTimer:
-    """Accumulates wall time into a dict entry (``phase_ns``: the bench
-    phase breakdown, tests/test_bench_gate scrapes the vocabulary) and
-    emits the same region as a ``hot_stage`` span through
-    :func:`tracing.span`.  ``args`` name what caused the work
-    (``window_end`` for a fire, ``checkpoint`` for a cut)."""
-
-    __slots__ = ("_d", "_k", "_t0", "_span")
-
-    def __init__(self, d: Dict[str, int], key: str,
-                 args: Optional[Dict[str, Any]] = None):
-        self._d = d
-        self._k = key
-        self._span = tracing.span(phase_span_name(key), cat="hot_stage",
-                                  **(args or {}))
-
-    def __enter__(self):
-        self._span.__enter__()
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        self._d[self._k] = self._d.get(self._k, 0) + t1 - self._t0
-        self._span.__exit__(*exc)
-        return False
-
-
 class WindowAggOperator(StreamOperator):
     """Keyed window aggregation: ``key_by(key_col).window(assigner).aggregate(agg)``."""
 
@@ -552,7 +524,7 @@ class WindowAggOperator(StreamOperator):
         #: per-phase time/byte accounting (bench transparency, VERDICT r2
         #: weak #1): probe/mirror/device_dispatch/fire/snapshot ns, h2d/d2h
         #: bytes
-        self.phase_ns: Dict[str, int] = {}
+        self.phase_ns = tracing.TimeAccount()
         self.phase_bytes: Dict[str, int] = {}
         #: what caused the work the phases now time (``window_end`` of a
         #: fire, ``checkpoint`` of a cut): an argument of their spans
@@ -826,7 +798,7 @@ class WindowAggOperator(StreamOperator):
         self.watermark = LONG_MIN
         self.late_dropped = 0
         self._proc_time = LONG_MIN
-        self.phase_ns = {}
+        self.phase_ns = tracing.TimeAccount()
         self.phase_bytes = {}
         self.phase_shard_ns = {}
         self._hot_dispatches = 0
@@ -903,8 +875,14 @@ class WindowAggOperator(StreamOperator):
 
     # ---------------------------------------------------- host value mirror
     def _phase(self, name: str):
-        """Accumulating timer and span: ``with self._phase("mirror"): ...``."""
-        return _PhaseTimer(self.phase_ns, name, self._span_args)
+        """Accumulating timer and span: ``with self._phase("mirror"): ...``
+        adds the region's wall time to ``phase_ns[name]`` and the thread's
+        CPU time in it to ``phase_ns[name + "_cpu"]`` (``tests/
+        test_bench_gate`` scrapes the vocabulary), and emits it as a
+        ``hot_stage`` span; ``_span_args`` name what caused the work
+        (``window_end`` for a fire, ``checkpoint`` for a cut)."""
+        return tracing.PhaseTimer(self.phase_ns, name, phase_span_name(name),
+                                  "hot_stage", self._span_args)
 
     @contextlib.contextmanager
     def _caused_by(self, **args):
@@ -974,6 +952,13 @@ class WindowAggOperator(StreamOperator):
 
     def _pipe_pending(self) -> bool:
         return self._pipe is not None and self._pipe.pending()
+
+    def owned_threads(self) -> list:
+        """Threads this operator started itself (the hot-stage pipeline's
+        worker): their CPU time belongs to the task that runs the
+        operator (``Task.thread_cpu_ns``)."""
+        worker = self._pipe._t if self._pipe is not None else None
+        return [worker] if worker is not None else []
 
     def flush_pipeline(self) -> List[StreamElement]:
         """Pipeline barrier: complete every in-flight hot stage.  Called
@@ -1559,7 +1544,22 @@ class WindowAggOperator(StreamOperator):
         return new_leaves, layout.where_rows(counts, key_mask, 0)
 
     # --------------------------------------------------------------- batching
+    #: the span this operator opens around its own ``process_batch``, and
+    #: its time (a chain counts it under that name and times nothing)
+    span = "window_agg.process_batch"
+
+    def span_time_ns(self):
+        return (self.phase_ns.get("process_batch", 0),
+                self.phase_ns.get("process_batch_cpu", 0))
+
     def process_batch(self, batch: RecordBatch) -> List[StreamElement]:
+        """The operator's whole entry, phase ``process_batch``: less the
+        phases inside it, that is the untimed front (columns, panes, the
+        lateness gate, the re-fire check)."""
+        with self._phase("process_batch"):
+            return self._process_batch(batch)
+
+    def _process_batch(self, batch: RecordBatch) -> List[StreamElement]:
         pending = self.drain_pending_fires() if self.async_fire else []
         if len(batch) == 0:
             return pending
@@ -1570,7 +1570,7 @@ class WindowAggOperator(StreamOperator):
             # against the clamped step keeps K_cap=1 from recursing forever)
             out = list(pending)
             for lo in range(0, len(batch), step):
-                out.extend(self.process_batch(
+                out.extend(self._process_batch(
                     batch.take(np.arange(lo, min(lo + step, len(batch))))))
             return out
         cols = batch.columns
@@ -1847,8 +1847,9 @@ class WindowAggOperator(StreamOperator):
                                       jax.tree_util.tree_leaves(values_p)))
             try:
                 # the phase's span is `window_agg.device_step`: the
-                # hand-off to the lane thread, the jitted call, and its
-                # wait for room in the device's queue
+                # hand-off to the lane thread (`dispatch_handoff`), the
+                # thunk there (`launch`), and the way back
+                # (`dispatch_return`)
                 with self._phase("device_dispatch"):
                     res = self._guarded_update(flat_p, values_p, mb / 1e6)
             except DeviceQuarantinedError as err:
@@ -1936,14 +1937,26 @@ class WindowAggOperator(StreamOperator):
         fresh_geom = geom != getattr(self, "_last_dispatch_geom", None)
         self._last_dispatch_geom = geom
         self._hot_dispatches += 1
+        # `hops`: the dispatch's two thread crossings go into `phase_ns`
+        # (`dispatch_handoff`, `dispatch_return`) beside the phases the
+        # thunk times on the lane thread (`launch`, on a mesh
+        # `exchange_route` before it): the parts of `device_dispatch`
         return device_health.guarded_dispatch(
-            lambda: self._update_step(self._layout, self._leaves,
-                                      self._counts, flat_p, values_p),
+            lambda: self._launch_update(flat_p, values_p),
             mb=mb,
             on_oom=(self._forced_page_out if self._pager is not None
                     else None),
             label=f"{self.name}.update_step",
-            compile_grace=fresh_geom)
+            compile_grace=fresh_geom, hops=self.phase_ns)
+
+    def _launch_update(self, flat_p, values_p):
+        """What a guarded update runs, on the dispatch lane's thread: the
+        jitted call alone, phase ``launch`` (it holds the wait for room
+        in the device's queue).  The mesh operator routes the batch to
+        its shards first."""
+        with self._phase("launch"):
+            return self._update_step(self._layout, self._leaves,
+                                     self._counts, flat_p, values_p)
 
     def _enter_degraded(self, err: BaseException) -> None:
         """Quarantine migration: leave the device tier MID-JOB.  Host-tier

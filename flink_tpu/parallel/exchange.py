@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from flink_tpu.observability import tracing
 from flink_tpu.parallel.mesh import KG_AXIS
 
 
@@ -157,9 +156,7 @@ class ResizingExchange:
         """-> (rx_leaves, rx_valid, cap_used).  Every input row is delivered
         exactly once; raises only if ``max_cap`` cannot hold the skew."""
         while True:
-            with tracing.span("mesh.exchange", cat="exchange",
-                              cap=self.cap, rows=int(dest.shape[0])):
-                rx, valid, overflow = self._fn(dest, *leaves)
+            rx, valid, overflow = self._fn(dest, *leaves)
             if int(jnp.max(overflow)) == 0:
                 return rx, valid, self.cap
             if self.cap >= self.max_cap:

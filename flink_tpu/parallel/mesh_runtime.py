@@ -275,7 +275,8 @@ class MeshWindowAggOperator(WindowAggOperator):
         the id — slot, pane, destination shard — is derived on the
         device.  Returns ``(batch, cap)`` for the ``_mesh_update_step``
         dispatch.  Timed as phase ``exchange_route`` (inside
-        ``device_dispatch``).  ``phase_bytes`` counts what the exchange
+        ``device_dispatch``, on the dispatch lane's thread).
+        ``phase_bytes`` counts what the exchange
         then moves (``exchange_sent``, ``exchange_live``: a row is its
         flat id and each value leaf at the width it has ON THE DEVICE;
         ``exchange_value_leaves``: the value leaves handed to the step,
@@ -337,15 +338,17 @@ class MeshWindowAggOperator(WindowAggOperator):
             self.phase_bytes[key] = self.phase_bytes.get(key, 0) + n
         return batch, cap
 
-    def _update_step(self, layout, leaves, counts, flat_ids, values):  # type: ignore[override]
+    def _launch_update(self, flat_ids, values):
         """Intercept the base class's device dispatch (the rest of the host
         front — key probe, lateness, pane bookkeeping, growth, staging —
         is reused verbatim from ``WindowAggOperator``): the staged flat
         ids and value leaves ride the all_to_all data plane to their
-        owning shard."""
+        owning shard.  Runs on the dispatch lane's thread: phase
+        ``exchange_route``, then the jitted call alone as ``launch``."""
         batch, cap = self._route_batch(flat_ids, values)
-        self._leaves, self._counts = self._mesh_update_step(
-            layout, (self._leaves, self._counts), batch, cap)
+        with self._phase("launch"):
+            self._leaves, self._counts = self._mesh_update_step(
+                self._layout, (self._leaves, self._counts), batch, cap)
         return self._leaves, self._counts
 
     def _round_key_capacity(self, needed: int) -> int:

@@ -197,10 +197,15 @@ class WatchdogConfig:
 
 class _Attempt:
     __slots__ = ("fn", "done", "result", "error", "abandoned",
-                 "fire_chaos")
+                 "fire_chaos", "t_submit", "t_start", "t_end")
 
     def __init__(self, fn, fire_chaos: bool = True):
         self.fn = fn
+        #: ``perf_counter_ns`` at submit, at the thunk's start and at its
+        #: end on the lane thread: the two thread crossings of a dispatch
+        #: are submit -> start and end -> the waiter running again
+        self.t_submit = time.perf_counter_ns()
+        self.t_start = self.t_end = 0
         self.done = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
@@ -237,10 +242,14 @@ class _Lane:
                 if att.fire_chaos:
                     chaos.fire("device.dispatch")
                 if not att.abandoned:
+                    att.t_start = time.perf_counter_ns()
+                    tracing.complete("device.handoff_wait", att.t_submit,
+                                     att.t_start, cat="device_health")
                     att.result = att.fn()
             except BaseException as e:  # noqa: BLE001 — handed to the waiter
                 att.error = e
             finally:
+                att.t_end = time.perf_counter_ns()
                 att.done.set()
             if self._dead:
                 return
@@ -346,13 +355,30 @@ class DeviceHealthMonitor:
         if ent is not None:
             ent[1].die()
 
+    def lane_thread(self, owner: threading.Thread
+                    ) -> Optional[threading.Thread]:
+        """The dispatch lane thread serving ``owner``'s guarded calls, if
+        it has made any (a task reads its CPU clock beside its own)."""
+        with self._lock:
+            ent = self._lanes.get(owner.ident)
+        return ent[1]._t if ent is not None else None
+
     def run_guarded(self, fn: Callable[[], Any], mb: float = 0.0,
                     on_oom: Optional[Callable[[], None]] = None,
                     label: str = "dispatch",
-                    compile_grace: bool = False) -> Any:
+                    compile_grace: bool = False,
+                    hops: Optional[Dict[str, int]] = None) -> Any:
         """Run one device dispatch under the watchdog.  Returns ``fn()``'s
         result; raises :class:`DeviceQuarantinedError` when the tier is
         (or becomes) quarantined; re-raises FATAL errors unchanged.
+
+        ``hops``: the caller's time account (a window operator's
+        ``phase_ns``), to which the dispatch's two thread crossings are
+        added, ns per attempt that ran: ``dispatch_handoff`` (submit to
+        the thunk's start on the lane thread; span
+        ``device.handoff_wait``, emitted there) and ``dispatch_return``
+        (the thunk's end to this thread running again; span
+        ``device.return_wait``, emitted here).
 
         ``compile_grace``: the caller knows this dispatch will (re)compile
         — array geometry changed (state growth, a new operator's first
@@ -390,6 +416,16 @@ class DeviceHealthMonitor:
                 raise DeviceQuarantinedError(
                     f"device tier quarantined ({self.last_failure})")
             elapsed = time.monotonic() - t0
+            if att.t_start:
+                woke = time.perf_counter_ns()
+                tracing.complete("device.return_wait", att.t_end, woke,
+                                 cat="device_health", label=label)
+                if hops is not None:
+                    hops["dispatch_handoff"] = (
+                        hops.get("dispatch_handoff", 0)
+                        + att.t_start - att.t_submit)
+                    hops["dispatch_return"] = (
+                        hops.get("dispatch_return", 0) + woke - att.t_end)
             if att.error is None:
                 if elapsed > deadline * self.config.near_miss_frac:
                     with self._lock:
@@ -550,10 +586,13 @@ def reset_monitor() -> None:
 def guarded_dispatch(fn: Callable[[], Any], mb: float = 0.0,
                      on_oom: Optional[Callable[[], None]] = None,
                      label: str = "dispatch",
-                     compile_grace: bool = False) -> Any:
+                     compile_grace: bool = False,
+                     hops: Optional[Dict[str, int]] = None) -> Any:
     """Run ``fn`` under the process-wide monitor — a queue handoff to the
-    caller's lane thread plus an Event wait per dispatch (tens of µs;
-    negligible next to any real device step).  With the watchdog disabled
+    caller's lane thread plus an Event wait per dispatch: two thread
+    crossings, measured into ``hops`` (see :meth:`DeviceHealthMonitor.
+    run_guarded`; each is a wait for the GIL where other threads want it,
+    PERF.md section 5).  With the watchdog disabled
     (``FLINK_TPU_DEVICE_WATCHDOG=off``) the thunk runs inline and
     UNGUARDED, but the chaos fault point still fires — disabling the
     watchdog must not silently disarm an injected schedule."""
@@ -562,7 +601,7 @@ def guarded_dispatch(fn: Callable[[], Any], mb: float = 0.0,
         chaos.fire("device.dispatch")
         return fn()
     return mon.run_guarded(fn, mb=mb, on_oom=on_oom, label=label,
-                           compile_grace=compile_grace)
+                           compile_grace=compile_grace, hops=hops)
 
 
 def status_snapshot() -> Dict[str, Any]:

@@ -484,8 +484,10 @@ def _operator_phase_names():
 
 def test_profile_artifact_produced_and_keys_match(tmp_path):
     """bench.py --profile writes the per-phase JSON artifact (VERDICT #10)
-    and its phase keys are exactly the operator's ``_phase`` names (plus
-    the bench-level snapshot_total rollup)."""
+    and its phase keys are exactly the operator's ``_phase`` names, each
+    with its ``<phase>_cpu`` beside it, plus the dispatch's two thread
+    hand-offs (kept outside ``_phase``: they cross threads) and the
+    bench-level snapshot_total rollup."""
     out = tmp_path / "profile.json"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
@@ -497,11 +499,18 @@ def test_profile_artifact_produced_and_keys_match(tmp_path):
     assert out.exists(), "--profile did not write the artifact"
     with open(out) as f:
         prof = json.load(f)
-    allowed = _operator_phase_names() | {"snapshot_total"}
+    names = _operator_phase_names()
+    handoffs = {"dispatch_handoff", "dispatch_return"}
+    allowed = (names | {n + "_cpu" for n in names} | handoffs
+               | {"snapshot_total"})
     for section in ("phase_ns", "phases_ms"):
         keys = set(prof[section])
         assert keys <= allowed, f"unknown phase keys: {keys - allowed}"
         assert "probe_mirror" in keys or "probe" in keys
+        assert {"process_batch", "process_batch_cpu"} <= keys
+        if "device_dispatch" in keys:      # a lane that dispatches
+            assert {"launch", "launch_cpu"} | handoffs <= keys
+        assert all(k + "_cpu" in keys for k in keys & names)
     assert prof["phase_ns"].get("probe_mirror", 0) > 0 or \
         prof["phase_ns"].get("probe", 0) > 0
     assert prof["trace_annotation"] == "window_agg.device_step"

@@ -386,8 +386,7 @@ def test_update_step_takes_any_staged_length(D, n):
         op.process_batch(warm)
         assert op._P == ops[0]._P
         before = _route_counters(op)
-        res = op._update_step(op._layout, op._leaves, op._counts,
-                              ids.copy(), vals.copy())
+        res = op._launch_update(ids.copy(), vals.copy())
         op._leaves, op._counts = res[0], res[1]
         fired.append(_digests(op.process_watermark(Watermark(WINDOW_MS))))
     assert fired[0] == fired[1] and len(fired[0]) == 1

@@ -272,15 +272,18 @@ def the_plan_ships_five_value_leaves(bench, ran, window):
     assert counted["exchange_route_batches"] >= N_BATCHES
     assert counted["exchange_value_leaves"] \
         == 5 * counted["exchange_route_batches"]
-    # the planner's two maps count what they projected, per task
+    # the chain counts what the planner's two maps projected, per task,
+    # beside every other member of the plan's chains
     seen = {}
     for vertex in out.status["vertices"]:
         for subtask in vertex["subtasks"]:
-            seen.update(subtask["sql_projections"])
+            seen.update(subtask["chain_stats"])
     assert seen["sql.pre_project"]["rows"] == N_BATCHES * BATCH
     assert seen["sql.pre_project"]["batches"] == N_BATCHES
     assert seen["sql.project"]["rows"] == len(out.rows)
-    assert all(s["ns"] > 0 for s in seen.values())
+    assert seen["window_agg.process_batch"]["rows"] == N_BATCHES * BATCH
+    assert seen["sink.invoke"]["rows"] == len(out.rows)
+    assert all(s["ns"] >= s["cpu_ns"] > 0 for s in seen.values())
 
 
 def bfloat16_values_fail_the_comparison(bench, ran, window):

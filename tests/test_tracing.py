@@ -698,9 +698,21 @@ SPAN_TABLE = {
     "exchange.partition": (None, None),
     "task.input_wait": (None, None),
     "task.process_batch": (None, None),
-    "window_agg.probe": ("task.process_batch", None),
-    "window_agg.stage": ("task.process_batch", None),
-    "window_agg.device_step": ("task.process_batch", None),
+    # one span per chained operator: `chain.<name>` opened by the chain,
+    # or the member's own (`window_agg.process_batch`, `sink.invoke`)
+    "chain.timestamps": ("task.process_batch", None),
+    "chain.key-by": ("task.process_batch", None),
+    "window_agg.process_batch": ("task.process_batch", None),
+    "window_agg.probe": ("window_agg.process_batch", None),
+    "window_agg.stage": ("window_agg.process_batch", None),
+    "window_agg.device_step": ("window_agg.process_batch", None),
+    # the dispatch in its parts: the hand-off and the thunk on the lane
+    # thread (on a mesh the routing before the launch: `_mesh_batches`),
+    # the way back on the thread that waited
+    "device.handoff_wait": (None, None),
+    "window_agg.exchange_route": (None, None),
+    "window_agg.launch": (None, None),
+    "device.return_wait": ("window_agg.device_step", None),
     "window_agg.mirror": (None, None),          # host tier, below
     "window_agg.probe_mirror": (None, None),    # host tier, native mirror
     "window_agg.fire": (None, "window_end"),
@@ -745,6 +757,49 @@ def _host_tier_batches(native):
     return set(op.phase_ns)
 
 
+def _device_tier_op(mesh_devices=None):
+    """A device-tier operator on the calling thread: the one-chip one, or
+    the mesh one over ``mesh_devices`` of the forced host devices."""
+    import jax.numpy as jnp
+
+    from flink_tpu.core.functions import RuntimeContext, SumAggregator
+    from flink_tpu.operators.window_agg import WindowAggOperator
+    from flink_tpu.windowing.assigners import TumblingEventTimeWindows
+
+    kw = dict(agg=SumAggregator(jnp.float32), key_column="k",
+              value_column="v", emit_tier="device")
+    assigner = TumblingEventTimeWindows.of(250)
+    if mesh_devices is None:
+        op = WindowAggOperator(assigner, **kw)
+    else:
+        from flink_tpu.parallel.mesh import make_mesh
+        from flink_tpu.parallel.mesh_runtime import MeshWindowAggOperator
+
+        op = MeshWindowAggOperator(assigner, mesh=make_mesh(mesh_devices),
+                                   **kw)
+    op.open(RuntimeContext())
+    return op
+
+
+def _fold_batches(op, n=4):
+    from flink_tpu.core.batch import RecordBatch
+
+    for i in range(n):
+        op.process_batch(RecordBatch(
+            {"k": np.arange(256, dtype=np.int64),
+             "v": np.ones(256, np.float32)},
+            timestamps=np.full(256, 10 * i, np.int64)))
+
+
+def _mesh_batches():
+    """Four batches through a four-device mesh operator on the calling
+    thread: `exchange_route` and `launch` on its dispatch lane."""
+    op = _device_tier_op(4)
+    _fold_batches(op)
+    op.close()
+    return set(op.phase_ns)
+
+
 @pytest.fixture(scope="module")
 def profiled_job(tmp_path_factory):
     """The job under a profiler session somebody else might have started
@@ -762,7 +817,8 @@ def profiled_job(tmp_path_factory):
     jax.profiler.start_trace(out, profiler_options=options)
     try:
         _run_keyed_window_job()
-        phases = _host_tier_batches(False) | _host_tier_batches(True)
+        phases = (_host_tier_batches(False) | _host_tier_batches(True)
+                  | _mesh_batches())
         sql_cluster = _run_sql_window_job()
     finally:
         jax.profiler.stop_trace()
@@ -878,8 +934,9 @@ def test_a_sql_jobs_projections_are_spans_with_counters_beside_them(
     counted = {}
     for vertex in profiled_job["sql_cluster"].job_status()["vertices"]:
         for subtask in vertex["subtasks"]:
-            counted.update(subtask["sql_projections"])
-    assert {k: (v["batches"], v["rows"]) for k, v in counted.items()} == {
+            counted.update(subtask["chain_stats"])
+    assert {k: (v["batches"], v["rows"]) for k, v in counted.items()
+            if k.startswith("sql.")} == {
         "sql.pre_project": (8, 512), "sql.project": (4, 256)}
     for name, spans in by_name.items():
         # the counter's clock starts before the span and stops after it
@@ -945,6 +1002,11 @@ SPAN_CATEGORIES = {
     "checkpoint.trigger": "checkpoint", "checkpoint.ack": "checkpoint",
     "checkpoint": "checkpoint", "checkpoint.complete": "checkpoint",
     "checkpoint.store": "checkpoint", "sink.invoke": "sink",
+    "chain.timestamps": "chain", "chain.key-by": "chain",
+    "window_agg.process_batch": "hot_stage",
+    "window_agg.launch": "hot_stage",
+    "device.handoff_wait": "device_health",
+    "device.return_wait": "device_health",
 }
 
 
@@ -979,3 +1041,216 @@ def test_cluster_exposes_its_running_tasks():
     assert [type(t) for t in tasks] == [SourceSubtask] * 2 + [Subtask] * 2
     assert tasks is not cluster.tasks() and tasks == cluster.tasks()
     assert sum(t.records_in for t in tasks[2:]) == 24_000
+
+
+# ---------------------------------------------------------------------------
+# one account of a thread's time (ISSUE-37): CPU beside wall in every
+# phase, the dispatch in its parts, one counter set per chained operator
+# ---------------------------------------------------------------------------
+
+#: what a guarded update is made of, inside `device_dispatch`
+DISPATCH_PARTS = ("dispatch_handoff", "exchange_route", "launch",
+                  "dispatch_return")
+
+
+@pytest.fixture(scope="module")
+def keyed_job():
+    """The keyed window job once, no journal, no session: (cluster, the
+    wall ns its tasks' loops can have taken at most)."""
+    tracing.uninstall()
+    t0 = time.monotonic_ns()
+    cluster, _ = _run_keyed_window_job()
+    return cluster, time.monotonic_ns() - t0
+
+
+def test_every_phase_keeps_its_cpu_time_beside_its_wall_time(keyed_job):
+    """`phase_ns["<phase>_cpu"]` is the thread's CPU time inside the
+    phase: never above the wall time, above zero where the phase is numpy
+    work; the two hand-offs are waits and keep no CPU time."""
+    for op in _window_ops(keyed_job[0]):
+        ns = op.phase_ns
+        phases = [k for k in ns if not k.endswith("_cpu")]
+        assert {"process_batch", "probe", "stage", "device_dispatch",
+                "launch", "fire", "snapshot"} <= set(phases)
+        for key in phases:
+            if key in ("dispatch_handoff", "dispatch_return"):
+                assert key + "_cpu" not in ns
+                continue
+            assert 0 <= ns[key + "_cpu"] <= ns[key], key
+        assert ns["stage_cpu"] > 0 and ns["probe_cpu"] > 0
+        # the operator's whole entry holds the phases of its batches
+        assert ns["process_batch"] >= (ns["probe"] + ns["stage"]
+                                       + ns["device_dispatch"])
+
+
+def test_a_phase_that_sleeps_shows_wall_far_above_cpu():
+    acc = tracing.TimeAccount()
+    with tracing.PhaseTimer(acc, "wait", "test.wait"):
+        time.sleep(0.05)
+    with tracing.PhaseTimer(acc, "spin", "test.spin"):
+        end = time.perf_counter() + 0.05
+        while time.perf_counter() < end:
+            pass
+    assert acc["wait"] >= 50_000_000 and acc["wait_cpu"] < 5_000_000
+    assert acc["spin_cpu"] <= acc["spin"]
+    # a busy loop is on the CPU unless the machine took it away
+    assert acc["spin_cpu"] > acc["spin"] // 4
+
+
+def test_the_cpu_clock_is_read_once_an_interval_and_a_key(monkeypatch):
+    """A reading of the thread's CPU clock is a system call, so a phase
+    gets one per `CPU_READ_EVERY_NS`; an entry in between takes the CPU
+    share the last reading found, of its own wall time.  With the
+    interval at zero every entry is read."""
+    reads = []
+    real = time.thread_time_ns
+
+    def counted():
+        reads.append(1)
+        return real()
+
+    monkeypatch.setattr(tracing.time, "thread_time_ns", counted)
+
+    def spin_then_sleep(acc):
+        with tracing.PhaseTimer(acc, "x", None):
+            end = time.perf_counter() + 0.02
+            while time.perf_counter() < end:
+                pass
+        first = dict(acc)
+        with tracing.PhaseTimer(acc, "x", None):
+            time.sleep(0.02)
+        return first, {k: acc[k] - first[k] for k in first}
+
+    monkeypatch.setattr(tracing, "CPU_READ_EVERY_NS", 10**12)
+    first, second = spin_then_sleep(tracing.TimeAccount())
+    assert len(reads) == 2                   # the first entry alone
+    share = first["x_cpu"] / first["x"]
+    assert second["x_cpu"] == pytest.approx(share * second["x"], rel=1e-3)
+    assert second["x_cpu"] <= second["x"]
+    del reads[:]
+    monkeypatch.setattr(tracing, "CPU_READ_EVERY_NS", 0)
+    first, second = spin_then_sleep(tracing.TimeAccount())
+    assert len(reads) == 4
+    assert second["x_cpu"] < 5_000_000 <= 20_000_000 <= second["x"]
+
+
+@pytest.mark.parametrize("mesh_devices", [None, 4],
+                         ids=["one-chip", "mesh-4"])
+def test_a_dispatch_is_accounted_in_its_parts(mesh_devices):
+    """`device_dispatch` >= hand-off + (`exchange_route` on a mesh) +
+    `launch` + the way back; the thunk's phases are spans on the lane
+    thread, the way back a span on the thread that waited."""
+    j = tracing.install(SpanJournal(1 << 12))
+    op = _device_tier_op(mesh_devices)
+    _fold_batches(op)
+    ns = dict(op.phase_ns)
+    dispatches = op.fused_stats()["hot_dispatches"]
+    op.close()
+    assert dispatches == 4
+    parts = [p for p in DISPATCH_PARTS
+             if mesh_devices is not None or p != "exchange_route"]
+    assert all(ns[p] > 0 for p in parts), ns
+    assert ("exchange_route" in ns) == (mesh_devices is not None)
+    assert ns["device_dispatch"] >= sum(ns[p] for p in parts)
+    assert ns["launch_cpu"] <= ns["launch"]
+    me = threading.current_thread().name
+    by_name = {}
+    for _ph, _ts, dur, name, _cat, tid, _args in j.spans():
+        by_name.setdefault(name, []).append((tid, dur))
+    lane_spans = ["device.handoff_wait", "window_agg.launch"] + (
+        ["window_agg.exchange_route"] if mesh_devices is not None else [])
+    for name in lane_spans:
+        assert len(by_name[name]) == dispatches, name
+        assert {tid for tid, _ in by_name[name]} != {me}
+        assert all(tid.startswith("device-lane") for tid, _ in by_name[name])
+    assert [tid for tid, _ in by_name["device.return_wait"]] \
+        == [me] * dispatches
+    # the spans that cross threads carry what the counters hold
+    assert sum(d for _, d in by_name["device.handoff_wait"]) \
+        == ns["dispatch_handoff"]
+    assert sum(d for _, d in by_name["device.return_wait"]) \
+        == ns["dispatch_return"]
+
+
+def _chain_stats(cluster):
+    """[(task, its chain_stats)] of the cluster's tasks."""
+    return [(t, t.chain_stats) for t in cluster.tasks()]
+
+
+def test_a_chain_keeps_one_counter_set_per_member(keyed_job):
+    """key-by -> window -> sink on the window tasks, the source's own
+    chain on the source tasks: a span name and {batches, rows, ns, cpu_ns}
+    per member, rows = records in, the sum of ns inside `busy_ns`;
+    `job_status()` shows the same per subtask."""
+    cluster = keyed_job[0]
+    source_chain = ["chain.gated", "chain.timestamps"]
+    window_chain = ["chain.key-by", "window_agg.process_batch",
+                    "sink.invoke"]
+    for task, stats in _chain_stats(cluster):
+        assert list(stats) in (source_chain, window_chain)
+        first = stats[list(stats)[0]]
+        assert first["rows"] == task.records_in > 0
+        for span, counted in stats.items():
+            assert set(counted) == {"batches", "rows", "ns", "cpu_ns"}
+            assert counted["batches"] > 0 and counted["rows"] > 0
+            assert 0 < counted["cpu_ns"] <= counted["ns"], span
+        assert sum(c["ns"] for c in stats.values()) <= task.busy_ns
+        if list(stats) == window_chain:
+            assert stats["window_agg.process_batch"]["rows"] \
+                == task.records_in
+            assert stats["sink.invoke"]["batches"] == 4      # one a fire
+            # the operator times its own entry: the chain reads that
+            op, = _window_ops_of(task)
+            assert stats["window_agg.process_batch"]["ns"] \
+                == op.phase_ns["process_batch"]
+    status = [sub["chain_stats"] for v in cluster.job_status()["vertices"]
+              for sub in v["subtasks"]]
+    assert sorted(map(list, status)) == sorted(
+        [source_chain] * 2 + [window_chain] * 2)
+
+
+def test_job_status_gives_cpu_beside_busy(keyed_job):
+    """`cpu_ratio` = the task thread's CPU time over busy + idle +
+    backpressure, per subtask and per vertex, beside the three ratios
+    that sum to one."""
+    cluster, wall_ns = keyed_job
+    vertices = cluster.job_status()["vertices"]
+    assert len(vertices) == 2
+    for vertex in vertices:
+        assert 0 < vertex["cpu_ratio"] <= 1.0
+        for sub in vertex["subtasks"]:
+            assert 0 < sub["cpu_ratio"] <= 1.0
+            assert sub["busy_ratio"] + sub["idle_ratio"] \
+                + sub["backpressure_ratio"] == pytest.approx(1.0)
+            task_thread, = [n for n in sub["thread_cpu_ns"]
+                            if n.startswith("task-")]
+            assert 0 < sub["thread_cpu_ns"][task_thread] <= wall_ns
+    source, window = vertices
+    for sub in source["subtasks"]:
+        # channels this deep never filled: the sources were busy throughout
+        assert sub["backpressure_ratio"] == 0.0 == sub["idle_ratio"]
+    for task in cluster.tasks():
+        assert task.busy_ns + task.idle_ns + task.backpressure_ns \
+            <= wall_ns
+
+
+def _window_ops_of(task):
+    from flink_tpu.operators.window_agg import WindowAggOperator
+
+    return [m for m in getattr(task.operator, "operators", [task.operator])
+            if isinstance(m, WindowAggOperator)]
+
+
+def test_the_sql_plans_chains_are_counted_member_by_member(profiled_job):
+    cluster = profiled_job["sql_cluster"]
+    (source, source_stats), (window, window_stats) = _chain_stats(cluster)
+    assert list(source_stats) == ["chain.table:lineitem",
+                                  "chain.sql-rowtime", "sql.pre_project"]
+    assert list(window_stats) == ["chain.key-by", "window_agg.process_batch",
+                                  "sql.project", "sink.invoke"]
+    for task, stats in ((source, source_stats), (window, window_stats)):
+        assert stats[list(stats)[0]]["rows"] == task.records_in == 512
+        assert sum(c["ns"] for c in stats.values()) <= task.busy_ns
+    # every fired row goes through the projection and then the sink
+    assert window_stats["sql.project"]["rows"] \
+        == window_stats["sink.invoke"]["rows"] == 256
