@@ -19,7 +19,8 @@ Design notes (TPU-first):
   capacity is KNOWN before dispatch; the exchange compiles at a quantized
   capacity that always fits.  The destination itself is derived on the
   device, where the id already is: the host ships the staged flat ids and
-  value columns as they are.  The general case with capacity renegotiation
+  value columns packed into one array of 4-byte words, one transfer a
+  device.  The general case with capacity renegotiation
   lives in ``parallel/exchange.py`` (``ResizingExchange``).
 - **One jitted step per micro-batch**: bucket → ``all_to_all`` (ICI) →
   local scatter-combine, all inside one ``shard_map`` — XLA overlaps the
@@ -34,6 +35,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -44,7 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flink_tpu.operators.session_window import SessionWindowOperator
 from flink_tpu.operators.window_agg import (WindowAggOperator, _PAD_ID,
-                                            _next_pow2)
+                                            _Staging, _next_pow2)
 from flink_tpu.ops.pane_layout import ShardRing
 from flink_tpu.parallel.mesh import KG_AXIS, make_mesh, state_sharding
 
@@ -55,6 +57,21 @@ from flink_tpu.ops.shapes import quantize_pow2
 def _quantize(n: int, floor: int = 16) -> int:
     """pow2/4-step rounding: bounded compile count, <=25% padding."""
     return quantize_pow2(n, floor=floor, steps=4)
+
+
+class _PackedBatch(_Staging):
+    """One reusable packed upload buffer of the mesh operator: ``words``
+    is ``[D, W, block]`` 4-byte words, device ``d``'s share (its block of
+    the flat ids, then of each packed leaf) contiguous at ``words[d]``.
+    Reuse is gated by ``_Staging``'s rule: ``token`` is an output of the
+    step that consumed the buffer, so a backend that aliases host memory
+    into the transfer never sees the next batch's rows."""
+
+    __slots__ = ("words",)
+
+    def __init__(self, shape):
+        self.words = np.empty(shape, np.int32)
+        self.token = None
 
 
 class MeshWindowAggOperator(WindowAggOperator):
@@ -99,10 +116,13 @@ class MeshWindowAggOperator(WindowAggOperator):
     Chained dispatches stay pre-partitioned end-to-end: state flows out of
     the ``shard_map`` step with ``out_specs == in_specs`` (a device's ring
     block on ``KG_AXIS``), a batch is the base class's staged ``(flat_ids,
-    *values)`` ``device_put`` row-split onto the same axis in one call (the
-    flat id and the value columns ride the exchange; slot, pane and
-    destination are derived from the id on the device), and nothing in
-    between reshards — one XLA compile per
+    *values)`` packed into ONE 1-D array of 4-byte words split onto the
+    same axis (a device's share: its block of the ids, then of each
+    leaf; a leaf that is not 4 bytes wide on the device rides beside it),
+    so a batch costs one transfer a device whatever the aggregate's
+    column count (the flat id and the value columns ride the exchange;
+    slot, pane and destination are derived from the id on the device),
+    and nothing in between reshards — one XLA compile per
     (mesh size, K_cap, batch geometry), asserted by the tier-1 smoke via
     :meth:`mesh_step_cache_size`.
     """
@@ -126,6 +146,8 @@ class MeshWindowAggOperator(WindowAggOperator):
         self._shard_ns_buf = np.zeros(self.n_shards, np.int64)
         #: sticky high-water of the exchange's bucket capacity
         self._exchange_cap_hw = 0
+        #: reusable packed upload buffers by shape (``_route_batch``)
+        self._packed_pool = {}
 
     # ---------------------------------------------------------------- layout
     @property
@@ -180,28 +202,40 @@ class MeshWindowAggOperator(WindowAggOperator):
         return snap
 
     # ------------------------------------------------------------- device op
-    @partial(jax.jit, static_argnums=(0, 1, 4), donate_argnums=(2,))
-    def _mesh_update_step(self, layout, leaves_counts, batch, cap: int):
-        """One sharded micro-batch into the state: per-device bucket by
-        destination → ``all_to_all`` over ICI → scatter-combine into the
-        local block.  ``batch`` = (flat_ids, *values), each row-split over
-        the mesh: the base class's staged ``slot * P + pane`` ids as they
-        are, padding rows (any id at or past ``K * P``) included.  The
+    @partial(jax.jit, static_argnums=(0, 1, 4, 5), donate_argnums=(2,))
+    def _mesh_update_step(self, layout, leaves_counts, batch, cap: int,
+                          cols: tuple):
+        """One sharded micro-batch into the state: unpack this device's
+        columns → bucket by destination → ``all_to_all`` over ICI →
+        scatter-combine into the local block.  ``batch`` = (packed,
+        *beside), each split over the mesh on axis 0.  ``packed`` is ONE
+        1-D array of 4-byte words: a device's share is its block of the
+        flat ids, then its block of each packed value leaf, so a column
+        is a static slice and a ``bitcast_convert_type`` to the leaf's
+        dtype (exact: the words are the leaf's own bits).  ``cols`` names
+        each value leaf in tree order: the dtype it has in ``packed``, or
+        None for a leaf that rides ``beside`` as an array of its own.  The
+        ids are the base class's staged ``slot * P + pane`` as they are,
+        padding rows (any id at or past ``K * P``) included.  The
         destination shard is derived HERE from the id — key slots are
         owned in contiguous blocks, so it is one division — and padding
         rows are spread over the shards by their row index.  ``cap`` =
         per-(src, dest) bucket capacity (host-known upper bound, so the
         exchange can never overflow; :meth:`_pair_counts` is this rule's
         host twin).  One id column and the value columns ride the
-        exchange.  The named scopes are each stage's name in the program's
-        HLO (every operation's ``op_name``), so a device trace's
-        operations can be told apart by stage."""
+        exchange, one collective each.  Returns the state and a completion
+        token (one count cell a device: ready when THIS step has run, the
+        gate for reusing the host buffers it read).  The named scopes are
+        each stage's name in the program's HLO (every operation's
+        ``op_name``), so a device trace's operations can be told apart by
+        stage."""
         leaves, counts = leaves_counts
         D = self.n_shards
         K, Pn = layout.K, layout.P
         span = (K // D) * Pn          # flat ids one shard owns
+        width = 1 + sum(c is not None for c in cols)
 
-        def step(leaves, counts, ids, *values):
+        def step(leaves, counts, packed, *beside):
             from flink_tpu.parallel.exchange import (all_to_all_rows,
                                                      bucket_plan,
                                                      bucket_rows)
@@ -209,7 +243,14 @@ class MeshWindowAggOperator(WindowAggOperator):
             # plan keeps each key's records in batch order through the
             # exchange (bit-identical per-cell accumulation at any D)
             with jax.named_scope("exchange_bucket"):
-                row = jnp.arange(ids.shape[0], dtype=jnp.int32)
+                block = packed.shape[0] // width
+                words = (packed[w * block:(w + 1) * block]
+                         for w in range(width))
+                ids, own = next(words), iter(beside)
+                values = [next(own) if c is None else
+                          jax.lax.bitcast_convert_type(next(words), c)
+                          for c in cols]
+                row = jnp.arange(block, dtype=jnp.int32)
                 dest = jnp.where(ids < K * Pn, ids // span, row % D)
                 flat, _valid = bucket_plan(dest, D, cap)
                 bucket = lambda a, fill: bucket_rows(a, flat, D, cap,  # noqa: E731
@@ -232,15 +273,17 @@ class MeshWindowAggOperator(WindowAggOperator):
                 lflat = jnp.where(ok, local, span)
                 lifted = tuple(jax.tree_util.tree_leaves(
                     self.agg.lift(self._values_tree(rx_vals))))
-                return layout.local.fold(leaves, counts, lflat, lifted,
-                                         self.kinds,
-                                         self.agg.combine_leaves)
+                new_leaves, new_counts = layout.local.fold(
+                    leaves, counts, lflat, lifted, self.kinds,
+                    self.agg.combine_leaves)
+            with jax.named_scope("completion_token"):
+                return new_leaves, new_counts, new_counts[:1]
 
         rows = P(KG_AXIS)
         state_specs = ((rows,) * len(leaves), rows)
         fn = jax.shard_map(step, mesh=self.mesh,
                            in_specs=state_specs + (rows,) * len(batch),
-                           out_specs=state_specs, check_vma=False)
+                           out_specs=state_specs + (rows,), check_vma=False)
         return fn(leaves, counts, *batch)
 
     def _values_tree(self, flat_values):
@@ -266,16 +309,40 @@ class MeshWindowAggOperator(WindowAggOperator):
         dest += (np.arange(D, dtype=np.int32) * D)[:, None]
         return np.bincount(dest.ravel(), minlength=D * D).reshape(D, D)
 
+    def _packed_acquire(self, shape) -> _PackedBatch:
+        """A packed buffer no step still reads (``_staging_acquire``'s
+        rule and bound)."""
+        pool = self._packed_pool.setdefault(shape, [])
+        for pk in pool:
+            if pk.ready():
+                pk.token = None
+                return pk
+        pk = _PackedBatch(shape)
+        if len(pool) < 4:
+            pool.append(pk)
+        return pk
+
     def _route_batch(self, flat_ids, values):
-        """The exchange's host routing: hand the staged ``(flat_ids,
-        *values)`` to the mesh row-split, AS THEY ARE wherever the staged
-        length already divides by D (else one padded copy, read off the
-        buffer's shape), pick the STICKY bucket capacity, and
-        ``device_put`` the tuple once.  Everything that is a function of
-        the id — slot, pane, destination shard — is derived on the
-        device.  Returns ``(batch, cap)`` for the ``_mesh_update_step``
-        dispatch.  Timed as phase ``exchange_route`` (inside
-        ``device_dispatch``, on the dispatch lane's thread).
+        """The exchange's host routing: PACK the staged flat ids and every
+        value leaf that can ride as 4-byte words into one reused host
+        buffer ``[D, W, block]`` (W = 1 + packed leaves, block = staged
+        length / D rounded up: where the length does not divide by D the
+        pack is the padded copy), pick the STICKY bucket capacity, and
+        hand the mesh that buffer as ONE 1-D array split on axis 0: four
+        transfers a batch whatever the aggregate's column count, where a
+        tuple of columns cost one transfer a column and chip.  A leaf
+        packs when it has one value a row and its device dtype
+        (``canonicalize_dtype``: int64 is int32 with x64 off, the cast
+        ``device_put`` would apply) is 4 bytes wide; any other leaf (a
+        vector a row, bool, 8- or 16-bit) rides beside the packed array
+        as an array of its own, in the same ``device_put``.  Everything
+        that is a function of the id — slot, pane, destination shard — is
+        derived on the device.  Returns ``(batch, cap, cols, packed)``:
+        the first three are the ``_mesh_update_step`` dispatch's
+        arguments, the last is the host buffer, to be given the step's
+        token.  Timed as phase ``exchange_route`` (inside
+        ``device_dispatch``, on the dispatch lane's thread), the pack's
+        copy included.
         ``phase_bytes`` counts what the exchange
         then moves (``exchange_sent``, ``exchange_live``: a row is its
         flat id and each value leaf at the width it has ON THE DEVICE;
@@ -283,28 +350,50 @@ class MeshWindowAggOperator(WindowAggOperator):
         summed over batches — a leaf ``agg.lift`` never reads is shipped
         to the devices all the same, and dropped from the compiled step
         as dead code) and, beside them, how the routing went:
-        ``exchange_route_batches``, of which ``exchange_route_copied``
-        needed the padded copy and ``exchange_cap_counts_skipped`` took
-        no capacity count."""
+        ``exchange_packed_leaves`` / ``exchange_unpacked_leaves`` (leaves
+        that rode in the packed array / beside it), ``exchange_h2d_arrays``
+        (arrays handed to ``device_put``: 1 a batch where every leaf
+        packs), ``exchange_route_batches``, of which
+        ``exchange_route_copied`` had a staged length that did not divide
+        by D and ``exchange_cap_counts_skipped`` took no capacity
+        count."""
         with self._phase("exchange_route"):
             D = self.n_shards
             ids = np.asarray(flat_ids)
             vleaves, self._values_treedef = jax.tree_util.tree_flatten(values)
             vleaves = [np.asarray(v) for v in vleaves]
+            dtypes = [jax.dtypes.canonicalize_dtype(v.dtype) for v in vleaves]
+            cols = tuple(dt if v.ndim == 1 and dt.itemsize == 4 else None
+                         for v, dt in zip(vleaves, dtypes))
             B = ids.shape[0]
             block = -(-B // D)
             copied = block * D != B
-            if copied:
-                # the staged length does not split over the mesh (D is no
-                # power of two): pad to the next multiple of D
+            full, rest = divmod(B, block)   # whole blocks, rows of the next
+            n_packed = len(cols) - cols.count(None)
+            pk = self._packed_acquire((D, 1 + n_packed, block))
 
-                def pad(a, fill):
-                    out = np.full((block * D,) + a.shape[1:], fill, a.dtype)
-                    out[:B] = a
-                    return out
+            def pack(w, a, dtype, fill):
+                # device d's rows of column w: a[d * block:(d + 1) * block]
+                col = pk.words[:, w].view(dtype)
+                col[:full] = a[:full * block].reshape(full, block)
+                if copied:
+                    col[full, :rest] = a[full * block:]
+                    col[full, rest:] = fill
+                    col[full + 1:] = fill
 
-                ids = pad(ids, _PAD_ID)
-                vleaves = [pad(v, 0) for v in vleaves]
+            def pad(a):
+                out = np.zeros((block * D,) + a.shape[1:], a.dtype)
+                out[:B] = a
+                return out
+
+            pack(0, ids, np.int32, _PAD_ID)
+            beside, word = [], iter(range(1, 1 + n_packed))
+            for v, dt in zip(vleaves, cols):
+                if dt is None:
+                    beside.append(pad(v) if copied else v)
+                else:
+                    pack(next(word), v, dt, 0)
+            ids = pk.words[:, 0]
             live = ids < self._K * self._P
             n_live = int(np.count_nonzero(live))
             # host-known capacity: max rows any (src block, dest) pair
@@ -316,27 +405,33 @@ class MeshWindowAggOperator(WindowAggOperator):
             # that ceiling nothing is left to count
             skipped = self._exchange_cap_hw >= block
             if not skipped:
-                per_pair = self._pair_counts(ids.reshape(D, block),
-                                             live.reshape(D, block))
+                per_pair = self._pair_counts(ids, live)
                 self._exchange_cap_hw = max(self._exchange_cap_hw,
                                             _quantize(int(per_pair.max())))
             cap = self._exchange_cap_hw
-            batch = jax.device_put((ids, *vleaves), self._row_sharding)
+            batch = jax.device_put((pk.words.reshape(-1), *beside),
+                                   self._row_sharding)
+            # until the step's own token replaces it: the transfer's end
+            pk.token = batch[0]
         # bytes through the all_to_all, padding included (every device
         # sends D buckets of cap rows), and of the rows that carry a
-        # record.  A row is counted as it is on the device: ``device_put``
-        # canonicalises a column's dtype (int64 to int32 with x64 off)
-        row_bytes = sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
-                        for a in batch)
+        # record.  A row is counted as it is on the device: its flat id
+        # and each leaf at its canonical width (int64 is int32 there with
+        # x64 off), packed or not
+        row_bytes = 4 + sum(dt.itemsize * math.prod(v.shape[1:])
+                            for v, dt in zip(vleaves, dtypes))
         for key, n in (
                 ("exchange_sent", D * D * cap * row_bytes),
                 ("exchange_live", n_live * row_bytes),
                 ("exchange_value_leaves", len(vleaves)),
+                ("exchange_packed_leaves", n_packed),
+                ("exchange_unpacked_leaves", len(beside)),
+                ("exchange_h2d_arrays", len(batch)),
                 ("exchange_route_batches", 1),
                 ("exchange_route_copied", int(copied)),
                 ("exchange_cap_counts_skipped", int(skipped))):
             self.phase_bytes[key] = self.phase_bytes.get(key, 0) + n
-        return batch, cap
+        return batch, cap, cols, pk
 
     def _launch_update(self, flat_ids, values):
         """Intercept the base class's device dispatch (the rest of the host
@@ -344,20 +439,20 @@ class MeshWindowAggOperator(WindowAggOperator):
         is reused verbatim from ``WindowAggOperator``): the staged flat
         ids and value leaves ride the all_to_all data plane to their
         owning shard.  Runs on the dispatch lane's thread: phase
-        ``exchange_route``, then the jitted call alone as ``launch``."""
-        batch, cap = self._route_batch(flat_ids, values)
+        ``exchange_route``, then the jitted call alone as ``launch``.
+        The step's token frees the packed buffer and, returned, the base
+        class's staging set."""
+        batch, cap, cols, packed = self._route_batch(flat_ids, values)
         with self._phase("launch"):
-            self._leaves, self._counts = self._mesh_update_step(
-                self._layout, (self._leaves, self._counts), batch, cap)
-        return self._leaves, self._counts
+            self._leaves, self._counts, packed.token = self._mesh_update_step(
+                self._layout, (self._leaves, self._counts), batch, cap, cols)
+        return self._leaves, self._counts, packed.token
 
     def _round_key_capacity(self, needed: int) -> int:
         """Key capacity must stay divisible by the shard count (even state
         blocks per device): round the pow2 up to the next multiple of D
         (lcm), which pow2 meshes hit for free.  Paged state never grows —
         K_cap is the pinned resident capacity (overflow pages out)."""
-        import math
-
         if self._pager is not None:
             return self._K
         newK = _next_pow2(max(needed, self.n_shards), self._K)
@@ -449,6 +544,10 @@ class MeshSessionWindowOperator(SessionWindowOperator):
 
     # ------------------------------------------------------------ host side
     def _sessionize(self, slots, ts, values, bounds=None):
+        """Still pads and ``device_put``s column by column, unlike
+        ``MeshWindowAggOperator._route_batch``'s one packed array: no
+        benchmark cell runs a mesh session job, so a change here could
+        not be measured."""
         if self.kinds is None:
             return super()._sessionize(slots, ts, values, bounds)  # host fold
         if self.distinct_column is not None and isinstance(values, dict):

@@ -16,6 +16,7 @@ import functools
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from flink_tpu.core.batch import RecordBatch, Watermark
@@ -282,17 +283,32 @@ def _route_counters(op):
 def _mk_routed(D, agg="sum", **kw):
     """Device-tier operator: the one-chip one (``D`` None) or the mesh one
     at ``D`` shards (1 included: a mesh of one device runs the exchange)."""
-    from flink_tpu.core.functions import (CountAggregator, MaxAggregator,
-                                          MinAggregator, TupleAggregator)
+    from flink_tpu.core.functions import (AvgAggregator, CountAggregator,
+                                          MaxAggregator, MinAggregator,
+                                          TupleAggregator)
     if agg == "sum":
         kw.update(agg=SumAggregator(jnp.float32), value_column="v")
-    else:                                  # four leaves: the whole row rides
-        kw.update(agg=TupleAggregator({
+    elif agg == "tuple4":                  # four leaves: the whole row rides
+        kw.update(agg=TupleAggregator({    # (k and ts int64, v f32)
             "total": ("v", SumAggregator(jnp.float32)),
             "n": ("v", CountAggregator()),
             "lo": ("v", MinAggregator(jnp.float32)),
             "hi": ("v", MaxAggregator(jnp.float32))}),
-            value_selector=lambda c: c)
+            value_selector=lambda c: {n: c[n] for n in ("k", "ts", "v")})
+    elif agg == "sql5":                    # the SQL plan's shape (cell 6):
+        need = ("__ones", "hi_in", "lo_in", "mean_in", "total_in")
+        kw.update(agg=TupleAggregator({    # a column a call + int32 ones
+            "total": ("total_in", SumAggregator(jnp.float32)),
+            "n": ("__ones", CountAggregator()),
+            "lo": ("lo_in", MinAggregator(jnp.float32)),
+            "hi": ("hi_in", MaxAggregator(jnp.float32)),
+            "mean": ("mean_in", AvgAggregator(jnp.float32))}),
+            value_selector=lambda c: {n: c[n] for n in need})
+    else:                                  # "mixed": an int8 leaf cannot
+        kw.update(agg=TupleAggregator({    # ride as a 4-byte word
+            "total": ("v", SumAggregator(jnp.float32)),
+            "mean": ("flag", AvgAggregator(jnp.float32))}),
+            value_selector=lambda c: {n: c[n] for n in ("flag", "v")})
     kw.update(key_column="k", emit_tier="device", snapshot_source="device")
     assigner = TumblingEventTimeWindows.of(WINDOW_MS)
     if D is None:
@@ -301,6 +317,21 @@ def _mk_routed(D, agg="sum", **kw):
         op = MeshWindowAggOperator(assigner, mesh=make_mesh(D), **kw)
     op.open(RuntimeContext())
     return op
+
+
+#: per aggregate of ``_mk_routed``: value leaves that ride in the packed
+#: array, and beside it
+ROUTED_LEAVES = {"sum": (1, 0), "tuple4": (3, 0), "sql5": (5, 0),
+                 "mixed": (1, 1)}
+
+
+def _routed_batch(k, v, ts):
+    """The columns every aggregate of ``_mk_routed`` selects from."""
+    cols = {"k": k, "v": v, "ts": ts,
+            "flag": (k % 7 - 3).astype(np.int8),
+            "__ones": np.ones(k.size, np.int32)}
+    cols.update({f"{a}_in": v for a in ("total", "lo", "hi", "mean")})
+    return RecordBatch(cols, timestamps=ts)
 
 
 def _all_digests(out):
@@ -330,7 +361,7 @@ def _drive_routed(op, sizes=(777, 1024, 1024, 300, 2048, 777), nk=900):
              rng.integers(0, 5, B))[i % 3].astype(np.int64)
         v = rng.random(B).astype(np.float32)
         ts = i * 400 + np.sort(rng.integers(0, 400, B)).astype(np.int64)
-        out += op.process_batch(RecordBatch({"k": k, "v": v}, timestamps=ts))
+        out += op.process_batch(_routed_batch(k, v, ts))
         out += op.process_watermark(Watermark(int(ts.max()) - 1))
         if i == 3:
             state = _state_bytes(op)
@@ -343,13 +374,15 @@ def _one_chip(agg):
     return _drive_routed(_mk_routed(None, agg))
 
 
-@pytest.mark.parametrize("agg", ["sum", "tuple4"])
+@pytest.mark.parametrize("agg", ["sum", "tuple4", "sql5", "mixed"])
 @pytest.mark.parametrize("D", [1, 2, 3, 4])
 def test_staged_batch_routes_bit_identically_to_one_chip(D, agg):
     """State and fired rows of the mesh fold equal the one-chip operator's
     to the byte at D = 1, 2, 4 and at D = 3 (where no staged power of two
     divides by D, so every batch takes the padded copy), with ``_PAD_ID``
-    rows in most batches."""
+    rows in most batches, whatever rides in the packed array (ISSUE 38):
+    one f32 leaf, int64 columns narrowed at the pack, the SQL plan's five
+    leaves, an int8 leaf that rides beside it."""
     ref_fired, ref_state = _one_chip(agg)
     op = _mk_routed(D, agg)
     fired, state = _drive_routed(op)
@@ -361,6 +394,109 @@ def test_staged_batch_routes_bit_identically_to_one_chip(D, agg):
     assert copied == (routed if D == 3 else 0)
     # D = 1: one block is the whole batch, its one pair sends all of it
     assert skipped <= routed - 1
+    # one array a batch where every leaf packs, one more for each that
+    # cannot; the counts are read off the leaves' dtype and shape
+    packed, beside = ROUTED_LEAVES[agg]
+    counted = op.phase_bytes
+    assert counted["exchange_packed_leaves"] == packed * routed
+    assert counted["exchange_unpacked_leaves"] == beside * routed
+    assert counted["exchange_value_leaves"] == (packed + beside) * routed
+    assert counted["exchange_h2d_arrays"] == (1 + beside) * routed
+    # one geometry, one program: the same batch again compiles nothing
+    if op.mesh_step_cache_size() >= 0:
+        rng = np.random.default_rng(D)
+        k = rng.integers(0, 900, 1024).astype(np.int64)
+        again = _routed_batch(k, rng.random(1024).astype(np.float32),
+                              np.full(1024, 9 * WINDOW_MS, np.int64))
+        op.process_batch(again)
+        size = op.mesh_step_cache_size()
+        for _ in range(3):
+            op.process_batch(again)
+        assert op.mesh_step_cache_size() == size
+
+
+def test_the_pack_narrows_a_wide_column_as_device_put_does():
+    """An int64 or f64 leaf is written into the packed words by numpy's
+    cast; that has to be, bit for bit, what ``device_put`` makes of the
+    column with x64 off (values past 32 bits included)."""
+    rng = np.random.default_rng(38)
+    wide = np.concatenate([rng.integers(-2**62, 2**62, 500),
+                           [2**31, -2**31 - 1, 2**32 + 5, -1, 0]])
+    for col in (wide.astype(np.int64), wide.astype(np.float64) / 3):
+        dt = jax.dtypes.canonicalize_dtype(col.dtype)
+        on_device = np.asarray(jax.device_put(col))
+        words = np.empty(col.size, np.int32)
+        words.view(dt)[...] = col
+        assert on_device.dtype == dt
+        assert words.tobytes() == on_device.tobytes()
+
+
+@pytest.mark.parametrize("agg", ["tuple4", "mixed"])
+@pytest.mark.parametrize("D", [2, 3])
+def test_a_packed_buffer_is_not_rewritten_under_a_running_step(D, agg):
+    """Twelve batches of distinct content back to back through one
+    operator, no read between them: the CPU backend may alias a host
+    buffer into the transfer, so a packed buffer (or a staging set)
+    handed out again before the step that read it has finished would fold
+    another batch's rows.  The answer is the one-chip operator's, and the
+    pool stays at its bound."""
+    nk, B = 600, 1024
+
+    def drive(op):
+        rng = np.random.default_rng(12)
+        for i in range(12):
+            k = rng.integers(0, nk, B).astype(np.int64)
+            v = (rng.random(B) * (i + 1)).astype(np.float32)
+            op.process_batch(_routed_batch(k, v, np.full(B, i, np.int64)))
+        return _state_bytes(op), _all_digests(
+            op.process_watermark(Watermark(WINDOW_MS)))
+
+    want = drive(_mk_routed(None, agg, initial_key_capacity=1024))
+    op = _mk_routed(D, agg, initial_key_capacity=1024)
+    assert drive(op) == want
+    pools = list(op._packed_pool.values())
+    assert pools and all(1 <= len(p) <= 4 for p in pools)
+    assert op.phase_bytes["exchange_route_batches"] == 12
+    # the gate is a step OUTPUT that no later step donates: it reads ready
+    # once the state is (the counts themselves are deleted by then, which
+    # reads as never ready), so buffers and staging sets do come back
+    jax.block_until_ready(op._counts)
+    assert all(pk.ready() for pool in pools for pk in pool)
+    assert all(st.ready() for pool in op._staging_pool.values()
+               for st in pool)
+
+
+def test_the_packed_pool_hands_out_only_finished_buffers():
+    """``_packed_acquire``: a buffer whose token is not ready (or was
+    deleted: unknowable) is passed over, a finished one comes back, and
+    past four in flight a buffer is made and not kept."""
+    class Token:
+        def __init__(self, done):
+            self.done = done
+
+        def is_ready(self):
+            if self.done is None:
+                raise RuntimeError("Array has been deleted.")
+            return self.done
+
+    op = _mk_routed(2)
+    shape = (2, 2, 512)
+    first = op._packed_acquire(shape)
+    assert first.words.shape == shape and first.words.dtype == np.int32
+    first.token = Token(False)
+    second = op._packed_acquire(shape)
+    assert second is not first
+    second.token = Token(None)
+    assert op._packed_acquire((2, 3, 512)).words.shape == (2, 3, 512)
+    first.token.done = True
+    assert op._packed_acquire(shape) is first and first.token is None
+    held = [first]
+    for _ in range(5):
+        first.token = Token(False)
+        held.append(op._packed_acquire(shape))
+        held[-1].token = Token(False)
+    assert len({id(pk) for pk in held}) == len(held)
+    assert len(op._packed_pool[shape]) == 4
 
 
 @pytest.mark.parametrize("D,n", [(4, 1022), (4, 1024), (3, 1024), (3, 1023),

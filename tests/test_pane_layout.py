@@ -132,12 +132,15 @@ def test_mesh_update_step_touches_the_state_with_scatters_only(make, P):
     values = np.ones(256, np.float32)
     if make is tuple_op:
         values = {"v": values}
-    batch, cap = op._route_batch(ids, values)
+    batch, cap, cols, _packed = op._route_batch(ids, values)
+    assert len(batch) == 1                # the whole batch is one array
     closed = jax.make_jaxpr(MeshWindowAggOperator._mesh_update_step,
-                            static_argnums=(0, 1, 4))(
-        op, op._layout, (op._leaves, op._counts), batch, cap)
+                            static_argnums=(0, 1, 4, 5))(
+        op, op._layout, (op._leaves, op._counts), batch, cap, cols)
     assert 4 * cap * 4 < K // 4 * P       # no exchange buffer is state-sized
-    _assert_scatters_only(closed, K // 4 * P, len(op._leaves) + 1)
+    # the completion token: one cell a device read off the new counts
+    _assert_scatters_only(closed, K // 4 * P, len(op._leaves) + 1,
+                          reads=[("slice", [1])])
 
 
 # --------------------------------------------------------------- equivalence
